@@ -51,10 +51,15 @@ echo "== step: Compile-cache tests (persistent cache, two runs warm/cold) =="
 # assertions: warm-process compile count drops (cache hits > 0), bucketed
 # ragged epoch adds 0 extra traces, unbucketed adds >= 1.
 CC_DIR=$(mktemp -d /tmp/dl4j-ci-compile-cache.XXXXXX)
-JAX_PLATFORMS=cpu DL4J_TPU_COMPILE_CACHE="$CC_DIR" \
+# The cache is placed from outside through JAX's own variables
+# (util/compile_cache.py: the program sets no directory in code).
+export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0
+export JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=-1
+JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$CC_DIR" \
     python -m pytest tests/test_compile_cache.py -q
-JAX_PLATFORMS=cpu DL4J_TPU_COMPILE_CACHE="$CC_DIR" \
+JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$CC_DIR" \
     python -m pytest tests/test_compile_cache.py -q
+unset JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES
 JAX_PLATFORMS=cpu python benchmarks/compile_cache_sweep.py --ci
 rm -rf "$CC_DIR"
 

@@ -18,7 +18,7 @@ ragged-tail epoch on the LeNet-5 bench model (flagship-independent, no
 BatchNorm — bucketing's bit-identity regime) and reports trace counts from
 the CompileWatcher, process-global backend compiles, persistent-cache hits,
 and launch-to-first-step wall. Wall cells are median-of-3 with the standard
-``noise`` field (BASELINE.md methodology).
+``noise`` field.
 
 Usage::
 
@@ -52,10 +52,11 @@ import json, sys, time
 T0 = time.perf_counter()
 import jax
 jax.config.update("jax_platforms", "cpu")
+import os
 cfg = json.loads(sys.argv[1])
-if cfg["cache_dir"]:
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):   # run_child places it
     from deeplearning4j_tpu.util.compile_cache import enable_persistent_cache
-    enable_persistent_cache(cfg["cache_dir"])
+    enable_persistent_cache()
 import numpy as np
 from deeplearning4j_tpu.data import ArrayDataSetIterator
 from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
@@ -109,13 +110,18 @@ print(json.dumps({
 
 
 def run_child(buckets, cache_dir, batch=8, n=20):
-    cfg = {"buckets": buckets, "cache_dir": cache_dir, "batch": batch, "n": n}
-    # scrub inherited DL4J_TPU_* knobs: an ambient DL4J_TPU_BUCKETS would
-    # bucket the "unbucketed" baseline, an ambient DL4J_TPU_COMPILE_CACHE
-    # would un-uncache the nocache cells — only cfg controls the A/B
+    cfg = {"buckets": buckets, "batch": batch, "n": n}
+    # scrub inherited knobs: an ambient DL4J_TPU_BUCKETS would bucket the
+    # "unbucketed" baseline, an ambient JAX_COMPILATION_CACHE_DIR would
+    # un-uncache the nocache cells — only the arguments control the A/B.
+    # The throwaway cache is placed from outside, through JAX's variable
+    # (util/compile_cache.py: the program sets no directory in code).
     env = {k: v for k, v in os.environ.items()
-           if not k.startswith("DL4J_TPU_")}
+           if not k.startswith("DL4J_TPU_")
+           and k != "JAX_COMPILATION_CACHE_DIR"}
     env["JAX_PLATFORMS"] = "cpu"
+    if cache_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     out = subprocess.run(
         [sys.executable, "-c", _CHILD, json.dumps(cfg)], env=env,
         capture_output=True, text=True, timeout=600,

@@ -20,10 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax.enable_x64 was promoted out of jax.experimental only in newer JAX
-# releases; take whichever this build has.
-_enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-
 
 DEFAULT_EPS = 1e-6
 DEFAULT_MAX_REL_ERROR = 1e-5
@@ -115,7 +111,7 @@ def check_gradients(
     elif isinstance(argnums, int):
         argnums = (argnums,)
 
-    with _enable_x64():
+    with jax.enable_x64():
         args64 = [
             jnp.asarray(a, dtype=jnp.float64) if i in argnums else a
             for i, a in enumerate(args)
@@ -160,7 +156,7 @@ def check_model_gradients(
     per-param finite difference); here the pytree stays structured. Defaults
     are looser than :func:`check_gradients` (deep compositions accumulate more
     truncation error)."""
-    with _enable_x64():
+    with jax.enable_x64():
         params64 = jax.tree_util.tree_map(
             lambda p: jnp.asarray(p, dtype=jnp.float64), params
         )

@@ -34,9 +34,6 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   docs/COMPILE_CACHE.md): ragged batches pad to a fixed bucket set so the
   jitted step compiles once per bucket. TPU-native; the closest reference
   knob is cudnnAlgoMode's compile-once-per-shape algo selection.
-- ``DL4J_TPU_COMPILE_CACHE`` — directory for the persistent on-disk XLA
-  compilation cache (util/compile_cache.py): a restarted process
-  deserializes executables instead of recompiling. Empty/unset = off.
 - ``DL4J_TPU_TELEMETRY`` — unified telemetry registry (util/telemetry.py,
   docs/OBSERVABILITY.md): counters/gauges/histograms, cross-process trace
   spans, /metrics + /healthz on the UI server. Default ON (span cost is
@@ -71,10 +68,12 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   utilization is not (no silent guesses about the hardware).
 - ``DL4J_TPU_KERNEL_IMPL`` — default hot-path kernel dispatch for new
   configs and direct op calls ("auto" | "exact" | "pallas" —
-  ops/kernels/, docs/KERNELS.md): ``auto`` engages the hand-tiled Pallas
-  conv/LSTM kernels only on the TPU backend, ``exact`` pins the XLA-HLO
-  reference path, ``pallas`` forces the kernels (Pallas interpreter on
-  CPU — the correctness-test mode).
+  ops/kernels/, docs/KERNELS.md): ``auto`` takes the XLA-HLO exact path
+  unless the tuning database holds a measured Pallas winner for the call
+  site on this backend, ``exact`` pins the exact path, ``pallas`` forces
+  the hand-tiled conv/LSTM kernels (Mosaic-compiled on TPU, where a
+  compiler refusal raises; the Pallas interpreter elsewhere — the
+  correctness-test mode).
 - ``DL4J_TPU_FUSED_UPDATE`` — default ``fused_update`` for new configs:
   the optimizer apply runs over dtype-grouped contiguous buffers in the
   donated train step instead of walking the param tree per leaf
@@ -86,8 +85,8 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   ``kernel_impl=auto`` dispatch (conv/LSTM impl + tile parameters) and by
   conf-time knob defaulting (an unset ``remat_policy`` takes the measured
   winner). Every entry is equivalence-gated before commit — the r6
-  honesty convention made executable. Empty/unset = off (auto keeps its
-  honest prior: compiled kernels only on the real chip).
+  honesty convention made executable. Empty/unset = off (auto then takes
+  the exact path everywhere).
 - ``DL4J_TPU_PIPE_STAGES`` — default ``pipe_stages`` for new configs
   (parallel/pipelined.py, docs/DISTRIBUTED.md#pipeline-parallelism):
   partition the net into N pipeline stages at its ``stage_boundary()``
@@ -102,6 +101,11 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   payload, dense decode before the update. The reference's
   EncodedGradientsAccumulator threshold/bitmap wire machinery, collapsed
   into the one jit-compiled GSPMD step.
+
+The persistent compilation cache has no knob of its own: JAX's
+``JAX_COMPILATION_CACHE_DIR`` places it, and entry points that call
+``util.compile_cache.enable_persistent_cache()`` fall back to
+``<checkout>/.jax_cache`` when it is unset (docs/COMPILE_CACHE.md).
 """
 
 from __future__ import annotations
@@ -165,8 +169,6 @@ class Environment:
         self.tuning_db_dir = os.environ.get("DL4J_TPU_TUNING_DB") or None
         self.etl_workers = _env_int("DL4J_TPU_ETL_WORKERS", 0, floor=0)
         self.default_buckets = os.environ.get("DL4J_TPU_BUCKETS") or None
-        self.compile_cache_dir = (
-            os.environ.get("DL4J_TPU_COMPILE_CACHE") or None)
         self.telemetry = _env_bool("DL4J_TPU_TELEMETRY", default=True)
         # request-trace head-sampling keep fraction (authoritative parse is
         # serving.scheduler.trace_sample_rate — memoized per raw string;
@@ -176,7 +178,6 @@ class Environment:
         # injector; surfaced here so crash dumps show the chaos config)
         self.fault_spec = os.environ.get("DL4J_TPU_FAULTS") or None
         self._profiler = None
-        self._compile_cache_applied = False
 
     @property
     def peak_flops(self):
@@ -236,15 +237,6 @@ class Environment:
         # profiling never install competing exec_op hooks; only touch its
         # config while the FLAGS own the hook — a user-started profiler's
         # settings are never clobbered by unrelated setter calls
-        # persistent compilation cache: wire jax_compilation_cache_dir once
-        # (idempotent; later enable_persistent_cache() calls can re-point it)
-        if self.compile_cache_dir and not self._compile_cache_applied:
-            from deeplearning4j_tpu.util.compile_cache import (
-                enable_persistent_cache)
-
-            enable_persistent_cache(self.compile_cache_dir)
-            self._compile_cache_applied = True
-
         # unified telemetry switch: the module reads DL4J_TPU_TELEMETRY
         # itself at singleton creation; the setter keeps them in sync at
         # runtime. Only push when THIS flag changed — an unrelated setter

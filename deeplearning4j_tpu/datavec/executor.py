@@ -30,6 +30,11 @@ CPython warns about it. It is a deliberate trade: ``forkserver``/``spawn``
 would have to pickle the transform closures the whole design exists to
 avoid, and the children only run pure-Python record functions — they never
 touch JAX, so the locks those warnings guard are never taken in the child.
+On a TPU host that is a hard rule, not a nicety: a chip belongs to one
+process at a time, the training parent holds it, and a forked child that
+initialised a backend would fail or hang. ``datavec/transform.py`` and
+``datavec/records.py`` import no JAX (tests/test_datavec.py pins that); a
+custom transform that calls into ``jax`` belongs on the serial path.
 If a child nonetheless wedges before reaching its queue put, ``timeout``
 converts the stall into :class:`TransformExecutionError` instead of a hang.
 """

@@ -10,6 +10,8 @@ framework, so the native path is an accelerator, not a dependency.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,38 +22,46 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "csrc", "dl4jtpu_native.cpp")
 _SRC_IMG = os.path.join(_HERE, "csrc", "dl4jtpu_image.cpp")
-_SO = os.path.join(_HERE, "_dl4jtpu_native.so")
+
+
+def _so_path() -> str:
+    """The artefact is named by a digest of the sources it is built from,
+    so a binary left behind by another revision, or copied in with the tree
+    from another machine, is never loaded: only the committed sources
+    decide what runs."""
+    h = hashlib.sha256()
+    for src in (_SRC, _SRC_IMG):
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"_dl4jtpu_native.{h.hexdigest()[:16]}.so")
+
 
 _lib = None
 _lock = threading.Lock()
 _build_error: Optional[str] = None
-_image_supported = False
 
 
-def _build() -> Optional[str]:
-    """Compile the native library if missing/stale. → error message or None."""
-    global _image_supported
+def _build(so: str) -> Optional[str]:
+    """Compile the native library to ``so`` unless this source digest's
+    build is already there. → error message or None."""
     try:
         srcs = [_SRC, _SRC_IMG]
-        if (os.path.exists(_SO)
-                and all(os.path.getmtime(_SO) >= os.path.getmtime(s)
-                        for s in srcs)):
-            _image_supported = True
+        if os.path.exists(so):
             return None
+        for stale in glob.glob(os.path.join(_HERE, "_dl4jtpu_native*.so")):
+            os.remove(stale)
         cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-               *srcs, "-o", _SO + ".tmp", "-ljpeg", "-lpng"]
+               *srcs, "-o", so + ".tmp", "-ljpeg", "-lpng"]
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if proc.returncode != 0:
             # image decode libs may be absent: fall back to the CSV-only core
             cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                   _SRC, "-o", _SO + ".tmp"]
+                   _SRC, "-o", so + ".tmp"]
             proc2 = subprocess.run(cmd, capture_output=True, text=True,
                                    timeout=300)
             if proc2.returncode != 0:
                 return proc.stderr[-2000:]
-        else:
-            _image_supported = True
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(so + ".tmp", so)
         return None
     except Exception as e:  # no compiler, read-only fs, ...
         return repr(e)
@@ -62,11 +72,12 @@ def _load():
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        err = _build()
+        so = _so_path()
+        err = _build(so)
         if err is not None:
             _build_error = err
             return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.arena_create.restype = ctypes.c_void_p
         lib.arena_create.argtypes = [ctypes.c_size_t]
         lib.arena_alloc.restype = ctypes.c_void_p

@@ -9,8 +9,8 @@ reference exists only as these single-device layers.
 
 TPU-native: sequences are [batch, time, features]; the attention core is
 ``ops.attention`` — exact einsum path or the Pallas flash kernel, picked
-automatically by the measured crossover (``flash="auto"``, the default:
-flash from 1024 tokens on TPU; see BASELINE.md). The reference cannot
+automatically (``flash="auto"``, the default: flash from 1024 tokens on
+TPU; see ``ops.attention.FLASH_MIN_SEQ``). The reference cannot
 handle long sequences at all.
 """
 

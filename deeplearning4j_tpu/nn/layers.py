@@ -270,8 +270,13 @@ class BatchNormalization(Layer):
         c = self.n_out or input_shape[-1]
         params = {}
         if not self.lock_gamma_beta:
-            params = {"gamma": jnp.full((c,), self.gamma_init),
-                      "beta": jnp.full((c,), self.beta_init)}
+            # explicit dtype: jnp.full with a Python float is WEAKLY typed,
+            # the first update hands back strong f32, and the train step
+            # retraces — at step 1 for the params and again at step 2 for
+            # the Adam moments built from them (three compiles of the
+            # flagship instead of one; seen on the chip in PR 21)
+            params = {"gamma": jnp.full((c,), self.gamma_init, jnp.float32),
+                      "beta": jnp.full((c,), self.beta_init, jnp.float32)}
         state = {"mean": jnp.zeros((c,)), "var": jnp.ones((c,))}
         return params, state
 

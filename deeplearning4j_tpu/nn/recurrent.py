@@ -154,12 +154,6 @@ class LSTM(BaseRecurrentLayer):
             op="lstm_cell",
             sig=_klstm.shape_signature(xp.shape[0], h),
             dtype=str(xp.dtype))
-        # tile-aware VMEM guard AFTER dispatch (the conv seam's rule): a
-        # tuned b_tile winner is admitted with the batch block it was
-        # validated with; oversized/stale tiles fall back to exact
-        if mode is not None and not _klstm.fits_vmem(
-                xp0, U, tuned.get("b_tile")):
-            mode = None
         if mode is not None:
             b_tile = tuned.get("b_tile")
 

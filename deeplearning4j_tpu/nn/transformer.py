@@ -9,8 +9,8 @@ natively in MultiLayerNetwork/ComputationGraph, with the TF-import path
 
 TPU-native: [B,T,H] layout; each block is two residual sublayers whose
 matmuls XLA tiles onto the MXU; attention picks the exact or Pallas flash
-path by the measured crossover (``flash="auto"``, the default — flash from
-1024 tokens on TPU, BASELINE.md). The Pallas path takes (B,T) padding
+path by sequence length (``flash="auto"``, the default — flash from
+1024 tokens on TPU, ops.attention.FLASH_MIN_SEQ). The Pallas path takes (B,T) padding
 masks since r14 (key blocks masked inside the kernel, masked-vs-exact
 equivalence pinned in tests/test_kernels.py); only full [B,1|H,Tq,Tk]
 attention masks still force the exact path.

@@ -141,8 +141,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *,
         k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG_BIG)
     if mask_ref is not None:
-        # (1, bk) per-key padding block, broadcast over the bq query rows
-        s = jnp.where(mask_ref[...] > 0.0, s, _NEG_BIG)
+        # (1, 1, bk) per-key padding block, broadcast over the bq query rows
+        s = jnp.where(mask_ref[0] > 0.0, s, _NEG_BIG)
 
     m_prev = m_s[:, 0]  # (bq,)
     m_cur = jnp.max(s, axis=-1)
@@ -197,16 +197,20 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
     if mask is None:
         kernel = base
     else:
-        # (B, Sk) padding mask, one (1, bk) key block per (batch, ki) —
-        # the head axis folds away in the index map (bh // h)
+        # (B, Sk) padding mask, one key block per (batch, ki) — the head
+        # axis folds away in the index map (bh // h). Passed rank-3,
+        # (B, 1, Sk) in (1, 1, bk) blocks: Mosaic wants a block's last two
+        # dims (8, 128)-divisible or equal to the array's, and a rank-2
+        # (1, bk) block of a (B, Sk) mask is neither once B > 1.
         def kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, acc, m_s,
                    l_s):
             base(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
                  mask_ref=m_ref)
 
         in_specs.append(
-            pl.BlockSpec((1, bk), lambda bh, qi, ki, h=h: (bh // h, ki)))
-        operands.append(mask.astype(jnp.float32))
+            pl.BlockSpec((1, 1, bk),
+                         lambda bh, qi, ki, h=h: (bh // h, 0, ki)))
+        operands.append(mask.astype(jnp.float32)[:, None, :])
     o, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq, nk),
@@ -378,11 +382,13 @@ def _flash_masked_vjp_bwd(scale, causal, block_q, block_k, use_pallas, res,
 _flash_masked.defvjp(_flash_masked_vjp_fwd, _flash_masked_vjp_bwd)
 
 
-# Measured crossover on the real chip (BASELINE.md round-3 table; fwd+bwd,
-# bf16, BERT-base head geometry, token count held constant): flash/naive
-# speedup by seq — 128: 1.00, 512: 0.70 (one 512-token block degenerates to
-# naive-with-overhead), 1024: 1.08, 2048: 1.29, 4096: 1.27. Flash earns its
-# keep from 1024 tokens; the jnp blockwise fallback never wins on CPU.
+# From 1024 tokens the S x S score matrix of the exact path is what the
+# online softmax exists to avoid. The only timing behind this threshold is
+# the r3 table (2026-07: blockwise/exact 1.08 at 1024, 1.29 at 2048, fwd+bwd,
+# bf16), taken on a backend whose name was not "tpu" — so by the tests in
+# this module it timed the jnp blockwise path, not the Pallas kernel. The
+# kernel first compiled on a chip in PR 21 and has not been ranked against
+# the exact path (PERF.md, open questions).
 FLASH_MIN_SEQ = 1024
 
 

@@ -7,22 +7,24 @@ dispatch seam, so the framework code never hard-codes a vendor path. The
 seam is the SAME exact-or-kernel pattern ``ops/attention.py`` established
 for flash attention, generalized:
 
-- ``kernel_impl``: ``"auto" | "exact" | "pallas"``. ``auto`` picks the
-  Pallas kernel only where it can win (TPU backend, supported
-  layout/dtype); ``exact`` always takes the XLA-HLO reference path;
-  ``pallas`` forces the kernel — on a non-TPU backend it runs the Pallas
-  INTERPRETER (bit-faithful to the kernel's block program), which is how
-  the correctness suite (tests/test_kernels.py) proves kernel==exact on
-  CPU containers.
+- ``kernel_impl``: ``"auto" | "exact" | "pallas"``. ``exact`` always takes
+  the XLA-HLO reference path. ``pallas`` forces the kernel: compiled by
+  Mosaic on the TPU backend (a geometry the compiler refuses raises the
+  compiler's own error — nothing falls back), the Pallas INTERPRETER on any
+  other backend, which is how tests/test_kernels.py proves kernel==exact
+  without a chip. ``auto`` takes the exact path unless the tuning database
+  holds a measured ``pallas`` winner for this (op, shape, dtype, backend):
+  a winner can only come from ``benchmarks/autotune.py`` run on that
+  backend, so ``auto`` never reaches a kernel the backend's compiler has
+  not already compiled and timed.
 - Resolution order: explicit ``impl_scope(...)`` context (the nets stamp
   their conf's ``kernel_impl`` here around every trace) > the
   ``DL4J_TPU_KERNEL_IMPL`` env knob > ``"auto"``.
 
 Every kernel is gated by equivalence proofs against the exact path
-(docs/KERNELS.md lists the tolerances); CPU containers cannot RANK the
-kernels against XLA:TPU's convs — they can only prove value/grad
-equivalence — so the flagship default stays ``auto`` until a real-chip
-sweep says otherwise (the r6 honesty convention).
+(docs/KERNELS.md lists the tolerances and what the v5e compiler said about
+each kernel). A CPU run proves value/grad equivalence; only a chip run
+ranks a kernel against XLA:TPU.
 """
 
 from __future__ import annotations
@@ -76,39 +78,31 @@ def resolve_impl() -> str:
 def dispatch(supported: bool, op: Optional[str] = None,
              sig: Optional[str] = None, dtype: Optional[str] = None):
     """The one dispatch rule. Returns ``(mode, params)``: ``mode`` is
-    ``None`` (take the exact path), ``"pallas"`` (compiled kernel), or
-    ``"interpret"`` (Pallas interpreter — the forced-``pallas`` path on
-    non-TPU backends, for correctness tests); ``params`` carries the
-    tuned kernel parameters (e.g. conv ``row_tile``) or ``{}``.
+    ``None`` (take the exact path), ``"pallas"`` (Mosaic-compiled kernel,
+    TPU backend), or ``"interpret"`` (Pallas interpreter, any other
+    backend); ``params`` carries the tuned kernel parameters (e.g. conv
+    ``row_tile``) or ``{}``.
 
     ``supported``: whether the call site's geometry/dtype has a kernel
     (callers compute this — e.g. conv requires NHWC + HWIO + f32/bf16).
 
-    ``auto`` resolution consults the tuning database (tuning/database.py,
-    docs/AUTOTUNE.md) when the call site passes its (op, shape-signature,
-    dtype) and ``DL4J_TPU_TUNING_DB`` is armed: a measured winner for the
-    current backend/topology decides impl AND parameters with committed
-    evidence — the cuDNN-style algorithm selection (arXiv:1410.0759)
-    subsumed by search. With no database or no entry, ``auto`` keeps the
-    honest prior: the compiled kernel only on the real chip."""
+    ``auto`` engages a kernel only on evidence: the call site passes its
+    (op, shape-signature, dtype), and a ``pallas`` winner in the tuning
+    database (tuning/database.py, ``DL4J_TPU_TUNING_DB``) for the current
+    backend/topology decides impl AND parameters. No database, no entry
+    or an ``exact`` winner all mean the exact path."""
     if not supported:
         return None, {}
     impl = resolve_impl()
     if impl == "exact":
         return None, {}
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "auto":
-        winner = _tuned_winner(op, sig, dtype)
-        if winner is not None:
-            if winner.get("impl") != "pallas":
-                return None, {}
-            params = dict(winner.get("params") or {})
-            return ("pallas" if on_tpu else "interpret"), params
-        # no measured evidence: CPU cannot rank the kernels
-        # (docs/KERNELS.md honesty note) — auto only ever engages the
-        # compiled kernel on the real chip
-        return ("pallas" if on_tpu else None), {}
-    return ("pallas" if on_tpu else "interpret"), {}
+    mode = "pallas" if jax.default_backend() == "tpu" else "interpret"
+    if impl == "pallas":
+        return mode, {}
+    winner = _tuned_winner(op, sig, dtype)
+    if winner is None or winner.get("impl") != "pallas":
+        return None, {}
+    return mode, dict(winner.get("params") or {})
 
 
 def _tuned_winner(op, sig, dtype):

@@ -25,10 +25,13 @@ reference; ``custom_vjp`` here is proven value- and grad-equivalent against
 it in tests/test_kernels.py (Pallas interpreter on CPU). Accumulation is
 fp32 regardless of input dtype (the MXU contract).
 
-VMEM sizing: the forward block working set is roughly
-``bytes(padded image group slice) + bytes(filter) + 4B * OH*OW*Cout_g``;
-:func:`fits_vmem` keeps ``auto`` dispatch honest — oversized feature maps
-stay on the exact path instead of faulting the chip.
+VMEM: one (image, group) program holds the padded image slice, the filter
+and an fp32 accumulator of ``row_tile`` (or all ``OH``) output rows. Whether
+that fits is the Mosaic compiler's call, made on the chip: nothing here
+guesses a budget. ``auto`` only reaches this kernel through a tuning-database
+winner, which ``benchmarks/autotune.py`` can only commit for a candidate that
+compiled and ran on that backend; a forced ``pallas`` call the compiler
+refuses raises its error (docs/KERNELS.md records what v5e accepted).
 
 Tile parameterization (the autotuner's first search space — ISSUE 11,
 docs/AUTOTUNE.md): ``row_tile`` splits the forward program's output rows
@@ -37,9 +40,9 @@ computes ``(row_tile*OW, Cg) x (Cg, Og)`` tap products instead of the whole
 ``(OH*OW, Cg)`` product, shrinking the fp32 accumulator and changing the
 MXU tile geometry (TVM's schedule knob, arXiv:1802.04799 §4). ``None``
 keeps the historical whole-OH block and is the REGISTERED DEFAULT;
-:func:`valid_row_tiles` + :func:`fits_vmem`'s per-candidate accounting are
-the validated-shape guard the measurement driver consults, so a candidate
-that cannot run (non-dividing tile, VMEM overflow) is never measured. Tile
+:func:`valid_row_tiles` is the shape guard the measurement driver consults,
+so a non-dividing tile is never measured; a candidate the backend's compiler
+refuses is recorded as rejected by the driver (tuning/measure.py). Tile
 winners come from ``benchmarks/autotune.py`` through the tuning database;
 CPU equivalence at non-default tiles is pinned in tests/test_kernels.py.
 """
@@ -54,10 +57,6 @@ import jax.numpy as jnp
 from jax import lax
 
 _F32 = jnp.float32
-# conservative per-core VMEM budget for the auto-dispatch guard (real v5e
-# VMEM is ~16 MB; leave headroom for double buffering + the output block)
-VMEM_BUDGET_BYTES = 10 * 1024 * 1024
-
 
 def _pair(v):
     return (v, v) if isinstance(v, int) else tuple(v)
@@ -85,33 +84,6 @@ def resolve_padding(padding, in_hw, k_hw, strides, dilation):
 def _out_size(in_size, pad, k, stride, dil):
     eff = (k - 1) * dil + 1
     return (in_size + pad[0] + pad[1] - eff) // stride + 1
-
-
-def fits_vmem(x_shape, w_shape, pads, groups, itemsize,
-              row_tile=None, strides=(1, 1), dilation=(1, 1)) -> bool:
-    """Whether one (image, group) forward block fits the VMEM budget.
-
-    ``row_tile`` is the candidate output-row tile (None = whole OH): the
-    padded image slice and filter stay resident either way, but the fp32
-    accumulator scales with the tile — the per-candidate half of the
-    validated-shape guard the autotuner consults before measuring."""
-    _, h, w, _ = x_shape
-    kh, kw, cg, cout = w_shape
-    hp = h + pads[0][0] + pads[0][1]
-    wp = w + pads[1][0] + pads[1][1]
-    og = cout // groups
-    x_bytes = hp * wp * cg * itemsize
-    w_bytes = kh * kw * cg * og * itemsize
-    if row_tile is None:
-        acc_rows = hp                      # upper bound on OH
-    else:
-        sh, dh = strides[0], dilation[0]
-        oh = _out_size(hp, (0, 0), kh, sh, dh)
-        if not valid_row_tile(oh, row_tile):
-            return False
-        acc_rows = row_tile
-    acc_bytes = 4 * acc_rows * wp * og     # fp32 accumulator block
-    return x_bytes + w_bytes + 2 * acc_bytes <= VMEM_BUDGET_BYTES
 
 
 def valid_row_tile(oh: int, row_tile) -> bool:

@@ -42,36 +42,10 @@ ORDER_IFOG: Tuple[str, ...] = ("i", "f", "o", "g")   # DL4J layer order
 ORDER_IOFG: Tuple[str, ...] = ("i", "o", "f", "g")   # ONNX lstm_layer order
 
 
-def fits_vmem(xp, u, b_tile=None) -> bool:
-    """Whether one cell program block fits the VMEM budget: xp (B,4H),
-    h, c (B,H) operands plus the fp32 z/gates working set, U (H,4H)
-    always whole (replicated across the batch grid) — same honesty guard
-    as conv's fits_vmem. ``b_tile`` is the candidate batch tile (None =
-    whole B): the batch-axis operands and working set scale with the
-    tile, so a tuned tiled winner is admitted with the block it was
-    validated with — oversized (or stale non-dividing) tiles stay on the
-    exact path instead of faulting the chip (H-blocked tiling is the
-    known next step if the real-chip sweep wants bigger cells)."""
-    from deeplearning4j_tpu.ops.kernels.conv import VMEM_BUDGET_BYTES
-
-    b, four_h = xp.shape
-    if b_tile is not None:
-        if not valid_b_tile(b, b_tile):
-            return False
-        b = b_tile
-    h = four_h // 4
-    itemsize = jnp.dtype(xp.dtype).itemsize
-    operands = (b * four_h + 2 * b * h + h * four_h) * itemsize
-    working = (b * four_h * 2 + 2 * b * h) * 4        # fp32 z, gates, c/h
-    return operands + working <= VMEM_BUDGET_BYTES
-
-
 def supports(xp, u, gate_activation: str, activation: str) -> bool:
     """Kernel GEOMETRY gate: default sigmoid/tanh cell, f32/bf16,
-    (B,4H)x(H,4H). The VMEM guard is separate (:func:`fits_vmem`) and
-    tile-aware — call sites apply it AFTER dispatch with the tuned
-    winner's ``b_tile``, so a committed tiled winner on a cell too large
-    for the whole-batch block stays reachable (the conv seam's rule)."""
+    (B,4H)x(H,4H). Whether a block fits VMEM is the Mosaic compiler's
+    verdict on the chip, not a guess made here (see conv.py)."""
     if gate_activation.lower() != "sigmoid" or activation.lower() != "tanh":
         return False
     if xp.dtype not in (jnp.float32, jnp.bfloat16) or u.dtype != xp.dtype:
@@ -176,8 +150,8 @@ def _cell_pallas(xp, h, c, u, order, interpret, b_tile=None):
 
 
 def _cell_exact(xp, h, c, u, order):
-    """Same math in plain jnp (fp32 accumulation) — the VJP recompute body
-    and the non-TPU fallback inside lstm_cell_fused."""
+    """Same math in plain jnp (fp32 accumulation) — the VJP recompute
+    body and the autotuner's exact candidate."""
     z = xp.astype(_F32) + h.astype(_F32) @ u.astype(_F32)
     zi, zf, zo, zg = _gates(z, h.shape[-1], order)
     i = jax.nn.sigmoid(zi)
@@ -200,12 +174,9 @@ def lstm_cell_fused(xp, h, c, u, order, mode, b_tile=None):
 
 
 def _cell_fwd_impl(xp, h, c, u, order, mode, b_tile=None):
-    if mode == "interpret":
-        return _cell_pallas(xp, h, c, u, order, True, b_tile)
-    if mode == "pallas" and jax.default_backend() == "tpu":
-        return _cell_pallas(xp, h, c, u, order, False, b_tile)
-    h_new, c_new, _ = _cell_exact(xp, h, c, u, order)
-    return h_new.astype(xp.dtype), c_new.astype(xp.dtype)
+    if mode not in ("pallas", "interpret"):
+        raise ValueError(f"mode must be 'pallas' or 'interpret', got {mode!r}")
+    return _cell_pallas(xp, h, c, u, order, mode == "interpret", b_tile)
 
 
 def _cell_vjp_fwd(xp, h, c, u, order, mode, b_tile=None):
@@ -235,6 +206,18 @@ def _cell_vjp_bwd(order, mode, b_tile, res, cts):
 
 
 lstm_cell_fused.defvjp(_cell_vjp_fwd, _cell_vjp_bwd)
+
+
+def lstm_sequence_exact(xp, h0, c0, u, order=ORDER_IFOG):
+    """The reference for :func:`lstm_sequence_fused`: the same scan with
+    :func:`_cell_exact` as its body. Returns ys (T, B, H)."""
+
+    def body(carry, xt):
+        h, c, _ = _cell_exact(xt, carry[0], carry[1], u, order)
+        h, c = h.astype(xp.dtype), c.astype(xp.dtype)
+        return (h, c), h
+
+    return lax.scan(body, (h0, c0), xp)[1]
 
 
 def lstm_sequence_fused(xp, h0, c0, u, order=ORDER_IFOG, mode="pallas",

@@ -86,9 +86,6 @@ def conv2d(
     if _kconv.supports(jnp.asarray(x), jnp.asarray(w), data_format,
                        feature_group_count, preferred_element_type):
         strides_p, dil_p = _pair(strides), _pair(dilation)
-        pads = _kconv.resolve_padding(
-            padding, (x.shape[1], x.shape[2]), (w.shape[0], w.shape[1]),
-            strides_p, dil_p)
         mode, tuned = _kern.dispatch(
             True,
             op="conv2d",
@@ -96,19 +93,10 @@ def conv2d(
                                        padding, dil_p,
                                        feature_group_count),
             dtype=str(x.dtype))
-        # the VMEM guard is tile-aware, AFTER dispatch: a tuned winner is
-        # admitted with the accumulator block it was validated with
-        # (row_tile), the untuned path with the whole-OH block — so a
-        # committed tiled winner on a feature map too large for the
-        # whole-block kernel is reachable, and an oversized (or stale
-        # non-dividing) tile still falls back to the exact path
-        if mode is not None and not _kconv.fits_vmem(
-                x.shape, w.shape, pads, feature_group_count,
-                jnp.dtype(x.dtype).itemsize,
-                row_tile=tuned.get("row_tile"),
-                strides=strides_p, dilation=dil_p):
-            mode = None
         if mode is not None:
+            pads = _kconv.resolve_padding(
+                padding, (x.shape[1], x.shape[2]), (w.shape[0], w.shape[1]),
+                strides_p, dil_p)
             out = _kconv.conv2d_pallas(x, w, strides_p, pads, dil_p,
                                        feature_group_count,
                                        mode == "interpret",

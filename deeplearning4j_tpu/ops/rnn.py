@@ -114,10 +114,6 @@ def lstm_layer(x, W, R, b=None, seq_lens=None, h0=None, c0=None, *,
             _klstm.supports(xp_probe, Rd_x, gate_activation, activation),
             op="lstm_cell", sig=_klstm.shape_signature(B, h),
             dtype=str(x.dtype))
-        # tile-aware VMEM guard AFTER dispatch (the conv seam's rule)
-        if mode is not None and not _klstm.fits_vmem(
-                xp_probe, Rd_x, tuned.get("b_tile")):
-            mode = None
         if mode is not None:
             xp_all = x @ jnp.asarray(Wd, x.dtype) + bias   # (T, B, 4H)
             b_tile = tuned.get("b_tile")
@@ -478,8 +474,8 @@ def lstm_block(seq_len_max, x, cs_prev, h_prev, W, wci, wcf, wco, b, *,
 # Reference signature: simple-RNN cell with Wx (I,H), Wh (H,H), b (H,).
 # "static" unrolls the loop in the graph, "dynamic" iterates — under XLA
 # both compile to one program; we keep BOTH shapes (unrolled HLO vs scan)
-# because compile time and fusion behaviour genuinely differ (BASELINE.md
-# round-4 LSTM A/B: same speed, 3.4x compile-time gap).
+# because compile time and fusion behaviour genuinely differ (r4 LSTM
+# A/B, 2026-07: same speed, 3.4x compile-time gap).
 # ---------------------------------------------------------------------------
 
 def _simple_rnn_scan(x, Wx, Wh, b, h0, seq_lens, unroll):

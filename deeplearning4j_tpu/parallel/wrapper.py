@@ -909,23 +909,18 @@ class ParallelWrapper:
         if not shards:
             return
         done_ns = [0] * len(shards)
-        if all(hasattr(sh.data, "is_ready") for sh in shards):
-            pending = set(range(len(shards)))
-            deadline = _time.monotonic() + 60.0
-            while pending and _time.monotonic() < deadline:
-                for i in list(pending):
-                    if shards[i].data.is_ready():
-                        done_ns[i] = _time.time_ns()
-                        pending.discard(i)
-                if pending:
-                    _time.sleep(5e-5)
-            for i in pending:  # deadline hit: block out the stragglers
-                jax.block_until_ready(shards[i].data)
-                done_ns[i] = _time.time_ns()
-        else:  # older jax: sequential fallback (index-order bias documented)
-            for i, sh in enumerate(shards):
-                jax.block_until_ready(sh.data)
-                done_ns[i] = _time.time_ns()
+        pending = set(range(len(shards)))
+        deadline = _time.monotonic() + 60.0
+        while pending and _time.monotonic() < deadline:
+            for i in list(pending):
+                if shards[i].data.is_ready():
+                    done_ns[i] = _time.time_ns()
+                    pending.discard(i)
+            if pending:
+                _time.sleep(5e-5)
+        for i in pending:  # deadline hit: block out the stragglers
+            jax.block_until_ready(shards[i].data)
+            done_ns[i] = _time.time_ns()
         tele = tm.get_telemetry()
         for i, (sh, t1) in enumerate(zip(shards, done_ns)):
             tele.event("parallel.replica_step", dispatch_t0_ns, t1,

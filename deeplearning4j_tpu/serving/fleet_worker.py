@@ -73,6 +73,11 @@ def main(argv=None) -> int:
         # them here too makes the module runnable by hand with the same
         # spec (setdefault: an explicit operator override wins)
         os.environ.setdefault(str(k), str(v))
+    # before the first compile: a respawned worker deserializes its
+    # warm-up executables instead of recompiling them
+    from deeplearning4j_tpu.util.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     router = build_router(spec)
     from deeplearning4j_tpu.serving.server import ModelServer
 

@@ -9,7 +9,7 @@ fastest admitted candidate, committed to the tuning database with the
 full measurement table as evidence. A candidate that computes the wrong
 thing can win nothing here — the gate runs before the stopwatch.
 
-Timing discipline is the repo's bench standard (BASELINE.md since r5):
+Timing discipline is the repo's bench standard since r5:
 **two-point fit** — time ``n`` calls and ``2n`` calls, per-call cost =
 (t2 − t1)/n, which cancels fixed dispatch/sync overhead — wrapped in
 **median-of-3** with the explicit ±spread/2 noise field. Call counts are
@@ -185,9 +185,24 @@ class MeasurementDriver:
                 row.update(admitted=False, reason=f"invalid: {reason}")
                 measured.append(row)
                 return None
+            # the backend's compiler is the VMEM/tiling guard: a kernel
+            # candidate it refuses (Mosaic lowering or compile error on the
+            # chip) is recorded with the compiler's words and can win
+            # nothing. The registered default must run — its failure is a
+            # bug and propagates.
+            try:
+                outputs = case.outputs(cand)
+            except Exception as e:
+                if cand.is_default:
+                    raise
+                row.update(admitted=False,
+                           reason=f"backend: {type(e).__name__}: "
+                                  f"{str(e)[:400]}")
+                measured.append(row)
+                _tm().counter("tuning.backend_rejects_total")
+                return None
             # the equivalence gate runs BEFORE the stopwatch: a candidate
             # that computes the wrong thing is never even timed
-            outputs = case.outputs(cand)
             if cand.label in corrupt:
                 outputs = corrupt[cand.label](outputs)
             err = _max_abs_diff(reference, outputs)

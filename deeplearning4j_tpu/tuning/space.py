@@ -183,8 +183,8 @@ class ConvTileSpace(SearchSpace):
         pads = kconv.resolve_padding(
             ctx.get("padding", "SAME"), x_shape[1:3], w_shape[:2], strides,
             dilation)
-        # kconv._out_size is the ONE output-size formula (shared with
-        # fits_vmem and the kernels) — no second inline copy to drift
+        # kconv._out_size is the ONE output-size formula (shared with the
+        # kernels) — no second inline copy to drift
         oh = kconv._out_size(x_shape[1], pads[0], w_shape[0], strides[0],
                              dilation[0])
         return x_shape, w_shape, strides, dilation, groups, pads, oh
@@ -211,20 +211,13 @@ class ConvTileSpace(SearchSpace):
 
     def validate(self, cand: Candidate, ctx: dict) -> Tuple[bool, str]:
         from deeplearning4j_tpu.ops.kernels import conv as kconv
-        import jax.numpy as jnp
 
         if cand.impl == "exact":
             return True, ""
-        x_shape, w_shape, strides, dilation, groups, pads, oh = \
-            self._geom(ctx)
+        oh = self._geom(ctx)[-1]
         rt = cand.params.get("row_tile")
         if not kconv.valid_row_tile(oh, rt):
             return False, f"row_tile {rt} does not divide OH={oh}"
-        itemsize = jnp.dtype(self.dtype(ctx)).itemsize
-        if not kconv.fits_vmem(x_shape, w_shape, pads, groups, itemsize,
-                               row_tile=rt, strides=strides,
-                               dilation=dilation):
-            return False, "VMEM budget exceeded"
         return True, ""
 
     def neighbors(self, cand: Candidate, ctx: dict) -> List[Candidate]:
@@ -347,23 +340,14 @@ class LstmTileSpace(SearchSpace):
         return out
 
     def validate(self, cand: Candidate, ctx: dict) -> Tuple[bool, str]:
-        import jax.numpy as jnp
-
         from deeplearning4j_tpu.ops.kernels import lstm as klstm
 
         if cand.impl == "exact":
             return True, ""
-        b, h = int(ctx["batch"]), int(ctx["hidden"])
+        b = int(ctx["batch"])
         bt = cand.params.get("b_tile")
         if not klstm.valid_b_tile(b, bt):
             return False, f"b_tile {bt} does not divide B={b}"
-        dtype = jnp.dtype(self.dtype(ctx))
-        xp = jnp.zeros((b, 4 * h), dtype)
-        u = jnp.zeros((h, 4 * h), dtype)
-        # the same tile-aware call the dispatch sites make — validate and
-        # trace-time admission can never disagree on a candidate
-        if not klstm.fits_vmem(xp, u, bt):
-            return False, "VMEM budget exceeded"
         return True, ""
 
     def neighbors(self, cand: Candidate, ctx: dict) -> List[Candidate]:
@@ -396,20 +380,8 @@ class LstmTileSpace(SearchSpace):
 
         def seq_for(cand: Candidate):
             if cand.impl == "exact":
-                def exact_seq(xp, u):
-                    from jax import lax
-
-                    def body(carry, xt):
-                        hp, cp = carry
-                        hn, cn, _ = klstm._cell_exact(
-                            xt, hp, cp, u, klstm.ORDER_IFOG)
-                        hn = hn.astype(xp.dtype)
-                        cn = cn.astype(xp.dtype)
-                        return (hn, cn), hn
-
-                    (hf, cf), ys = lax.scan(body, (h0, c0), xp)
-                    return ys
-                seq = exact_seq
+                def seq(xp, u):
+                    return klstm.lstm_sequence_exact(xp, h0, c0, u)
             else:
                 bt = cand.params.get("b_tile")
 

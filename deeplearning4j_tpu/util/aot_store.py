@@ -93,7 +93,6 @@ class AotStore:
 
         h = hashlib.sha256()
         for part in (tag, conf_json, repr(sig), jax.__version__,
-                     getattr(jax, "__version_info__", ""),
                      package_digest(), self._env_bits()):
             h.update(repr(part).encode())
         return h.hexdigest()

@@ -341,29 +341,6 @@ def xplane_device_ms(logdir: str, plane_substr: str = "/device:",
     return ms
 
 
-def xplane_event_ms(logdir: str, event_name: str,
-                    plane_substr: str = "/host:CPU") -> float:
-    """Total milliseconds of every event named exactly ``event_name`` across
-    ALL lines of matching planes under ``logdir``. The busiest-line heuristic
-    of :func:`xplane_device_ms` is right for device planes (one op stream per
-    line) but wrong for host planes, where the CPU backend spreads e.g.
-    ``ThunkExecutor::Execute`` (its compiled-module execution event) across
-    worker-thread lines — the sweep harness uses this as the CPU fallback
-    when no device plane exists."""
-    import glob as _glob
-
-    total_ps = 0
-    for p in _glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                        recursive=True):
-        for plane in parse_xplane(p):
-            if plane_substr not in plane["name"]:
-                continue
-            for line in plane["lines"]:
-                total_ps += sum(e[1] for e in line["events"]
-                                if e[0] == event_name)
-    return total_ps / 1e9
-
-
 def xplane_mapped_ms(logdir: str, resolve) -> Dict[Any, float]:
     """Group device/host-thread event time by ``resolve(event_name) -> key``
     (None = not counted) over every plane/line under ``logdir``, returning

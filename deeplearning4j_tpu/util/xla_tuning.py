@@ -1,6 +1,6 @@
 """Fusion-boundary engineering: selective rematerialization + XLA tuning.
 
-Why this module exists (BASELINE.md round-5): the flagship ResNet-50 step's
+Why this module exists (r5 trace, 2026-07): the flagship ResNet-50 step's
 device floor decomposes into ≈35.5 ms irreducible conv compute + ≈35.2 ms
 bandwidth-floor non-conv work + **≈36 ms fusion-context cost** — convs inside
 the fused train step run at roughly half their isolated efficiency. Whole-loss

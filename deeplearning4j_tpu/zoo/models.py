@@ -243,8 +243,8 @@ class ResNet50(ZooModel):
     always recorded in the config; a named policy selectively rematerializes
     each stage in the backward pass (save conv outputs, recompute the cheap
     BN/elementwise epilogue), barriers fence XLA fusion at the boundaries.
-    The default stays ``None`` per the measured record — see BASELINE.md's
-    fusion-sweep table before changing it."""
+    The default stays ``None``: no candidate has been timed on a chip yet
+    (ROADMAP Speed 2; docs/FUSION_TUNING.md)."""
 
     updater: object = None
     remat_policy: Optional[str] = None

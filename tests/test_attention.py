@@ -257,7 +257,7 @@ class TestRingAttention:
 
 
 class TestFlashAutoDispatch:
-    """Auto-dispatch by the measured crossover (BASELINE.md round-3 table)."""
+    """Auto-dispatch by sequence length (ops.attention.FLASH_MIN_SEQ)."""
 
     def test_resolve_flash_rules(self):
         rf = A.resolve_flash

@@ -93,14 +93,6 @@ class TestRegistry:
                                params={"row_tile": 3})
         ok, reason = sp.validate(bad, TINY_CONV)
         assert not ok and "does not divide" in reason
-        # ... and a VMEM-overflow candidate is rejected (giant imaginary
-        # feature map, whole-OH accumulator)
-        huge = dict(TINY_CONV, x_shape=(1, 4096, 4096, 64),
-                    w_shape=(3, 3, 64, 64))
-        ok, reason = sp.validate(
-            tuning.Candidate("pallas:rt=whole", impl="pallas",
-                             params={"row_tile": None}), huge)
-        assert not ok and "VMEM" in reason
 
     def test_lstm_candidates_guarded(self):
         sp = tuning.get_space("lstm_tiles")
@@ -190,6 +182,46 @@ class TestDriverGates:
         # only the admitted candidates were measured
         admitted = sum(1 for r in entry["measured"] if r["admitted"])
         assert _counter("tuning.measurements_total") == m0 + admitted
+
+    def test_backend_refusal_is_recorded_not_raised(self, db):
+        """The backend's compiler is the VMEM/tiling guard (no budget is
+        guessed on the host): a candidate whose program it refuses is
+        recorded with the compiler's words, is never timed and cannot win.
+        The registered default failing is a bug and propagates."""
+        class Refusing(tuning.SearchSpace):
+            name, op = "refusing_space", "refusing"
+
+            def signature(self, ctx):
+                return "sig"
+
+            def enumerate(self, ctx):
+                return [tuning.Candidate("exact", impl="exact",
+                                         is_default=True),
+                        tuning.Candidate("pallas:big", impl="pallas")]
+
+            def validate(self, cand, ctx):
+                return True, ""
+
+            def build(self, ctx):
+                def outputs(cand):
+                    if cand.label == ctx["refuse"]:
+                        raise RuntimeError("Mosaic: scoped vmem exceeded")
+                    return (np.zeros(2, np.float32),)
+                return tuning.space.MeasureCase(
+                    reference=lambda: (np.zeros(2, np.float32),),
+                    outputs=outputs, timer=lambda cand: (lambda: None),
+                    tolerance=1e-6)
+
+        r0 = _counter("tuning.backend_rejects_total")
+        entry = _driver(db).sweep(Refusing(), {"refuse": "pallas:big"})
+        rows = {r["label"]: r for r in entry["measured"]}
+        assert rows["pallas:big"]["admitted"] is False
+        assert "scoped vmem exceeded" in rows["pallas:big"]["reason"]
+        assert "ms" not in rows["pallas:big"]
+        assert entry["winner"]["label"] == "exact"
+        assert _counter("tuning.backend_rejects_total") == r0 + 1
+        with pytest.raises(RuntimeError, match="scoped vmem"):
+            _driver(db).sweep(Refusing(), {"refuse": "exact"}, force=True)
 
     def test_all_wrong_refuses_to_commit(self, db):
         """A space whose every candidate fails the gate is a bug, not a
@@ -400,8 +432,8 @@ class TestAutoDispatch:
         assert float(jnp.max(jnp.abs(out - exact))) < 2e-4
 
     def test_auto_miss_keeps_honest_prior(self, db, monkeypatch):
-        """No entry for the geometry -> auto keeps the r14 behaviour
-        (exact on CPU); an exact winner entry also resolves exact."""
+        """No entry for the geometry -> auto takes the exact path; an
+        exact winner entry also resolves exact."""
         monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
         sig = kconv.shape_signature((1, 4, 4, 2), (3, 3, 2, 2), (1, 1),
                                     "SAME", (1, 1), 1)
@@ -445,20 +477,16 @@ class TestAutoDispatch:
             out_exact, _ = lyr.apply_seq(params, x, carry)
         assert float(jnp.max(jnp.abs(out_tuned - out_exact))) < 1e-4
 
-    def test_tiled_winner_reachable_beyond_whole_block_vmem(
-            self, db, monkeypatch):
-        """The trace-time VMEM guard is tile-aware: a committed tiled
-        winner on a feature map whose WHOLE-block accumulator busts the
-        budget still engages the kernel with its own (validated) tile —
-        the shapes the harvest targets most. A stale non-dividing tile
-        degrades to the exact path instead of crashing."""
+    def test_tuned_tile_engages_and_stale_tile_raises(self, db, monkeypatch):
+        """A committed tiled winner engages the kernel with its tile — no
+        host-side VMEM guess stands between a measured winner and its call
+        site (the backend that measured it compiled it). A stale winner
+        naming a tile that cannot divide OH raises; it does not quietly
+        take another path."""
         monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
         from deeplearning4j_tpu.ops import nn as nnops
 
-        x_shape, w_shape = (1, 256, 16, 8), (3, 3, 8, 512)
-        pads = ((1, 1), (1, 1))
-        assert not kconv.fits_vmem(x_shape, w_shape, pads, 1, 4)
-        assert kconv.fits_vmem(x_shape, w_shape, pads, 1, 4, row_tile=2)
+        x_shape, w_shape = (1, 16, 8, 4), (3, 3, 4, 8)
         sig = kconv.shape_signature(x_shape, w_shape, (1, 1), "SAME",
                                     (1, 1), 1)
         db.commit(tdb.TuningKey.for_op("conv2d", sig, "float32"),
@@ -475,44 +503,12 @@ class TestAutoDispatch:
             exact = nnops.conv2d(x, w)
         scale = max(1.0, float(jnp.max(jnp.abs(exact))))
         assert float(jnp.max(jnp.abs(out - exact))) / scale < 1e-4
-        # stale winner naming a tile that no longer divides OH: the
-        # tile-aware guard rejects it and the call takes the exact path
         db.commit(tdb.TuningKey.for_op("conv2d", sig, "float32"),
                   {"winner": {"label": "pallas:rt=3", "impl": "pallas",
                               "params": {"row_tile": 3}, "ms": 1.0},
                    "candidates_digest": "t", "measured": []})
-        stale = nnops.conv2d(x, w)
-        assert float(jnp.max(jnp.abs(stale - exact))) == 0.0
-
-    def test_lstm_tiled_winner_reachable_beyond_whole_batch_vmem(
-            self, db, monkeypatch):
-        """Same tile-aware-guard contract on the LSTM seam: a committed
-        b_tile winner on a cell whose WHOLE-batch block busts the VMEM
-        budget engages the kernel with its validated batch tile."""
-        monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
-        from deeplearning4j_tpu.nn.recurrent import LSTM as LSTMLayer
-
-        b, h, t, n_in = 2048, 256, 2, 8
-        xp = jnp.zeros((b, 4 * h), jnp.float32)
-        u = jnp.zeros((h, 4 * h), jnp.float32)
-        assert not klstm.fits_vmem(xp, u)
-        assert klstm.fits_vmem(xp, u, 64)
-        sig = klstm.shape_signature(b, h)
-        db.commit(tdb.TuningKey.for_op("lstm_cell", sig, "float32"),
-                  {"winner": {"label": "pallas:bt=64", "impl": "pallas",
-                              "params": {"b_tile": 64}, "ms": 1.0},
-                   "candidates_digest": "t", "measured": []})
-        lyr = LSTMLayer(n_in=n_in, n_out=h)
-        params, _ = lyr.initialize(jax.random.PRNGKey(0), (b, t, n_in))
-        x = jnp.asarray(np.random.default_rng(4).normal(size=(b, t, n_in)),
-                        jnp.float32)
-        carry = lyr.init_carry(b)
-        h0 = _counter("tuning.hits_total")
-        out_tuned, _ = lyr.apply_seq(params, x, carry)
-        assert _counter("tuning.hits_total") > h0
-        with K.impl_scope("exact"):
-            out_exact, _ = lyr.apply_seq(params, x, carry)
-        assert float(jnp.max(jnp.abs(out_tuned - out_exact))) < 1e-4
+        with pytest.raises(ValueError, match="row_tile"):
+            nnops.conv2d(x, w)
 
 
 class TestConfDefaulting:

@@ -453,37 +453,71 @@ class TestSameDiffExecCache:
 # Persistent on-disk compilation cache
 # ---------------------------------------------------------------------------
 class TestPersistentCache:
-    def test_enable_disable_round_trip(self, tmp_path):
-        from deeplearning4j_tpu.util import (cache_entries,
+    """One rule (util/compile_cache.py): JAX_COMPILATION_CACHE_DIR set ->
+    JAX has it and the program sets no directory in code; unset -> one
+    fixed, git-ignored path inside the checkout."""
+
+    def test_unset_goes_to_the_fixed_path_in_the_checkout(
+            self, tmp_path, monkeypatch):
+        from deeplearning4j_tpu.util import (cache_entries, compile_cache,
                                              disable_persistent_cache,
                                              enable_persistent_cache)
 
-        d = str(tmp_path / "cc")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.FIXED_CACHE_DIR == os.path.join(
+            repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        # the rule is under test, not the checkout: keep this run's
+        # executables out of the real cache directory
+        d = str(tmp_path / "fixed")
+        monkeypatch.setattr(compile_cache, "FIXED_CACHE_DIR", d)
         try:
-            got = enable_persistent_cache(d)
-            assert got == os.path.abspath(d) and os.path.isdir(d)
-            assert jax.config.jax_compilation_cache_dir == got
+            assert enable_persistent_cache() == d and os.path.isdir(d)
+            assert jax.config.jax_compilation_cache_dir == d
 
             @jax.jit
             def f(a):
                 return a * 3 + 1
 
             f(np.ones(7, np.float32))
-            assert cache_entries(d) >= 1
+            assert cache_entries() >= 1
         finally:
             disable_persistent_cache()
         assert jax.config.jax_compilation_cache_dir is None
 
+    def test_set_variable_is_left_to_jax(self, tmp_path):
+        """A fresh process with the variable set: after enable, the
+        directory is the one JAX read from the environment, and the fixed
+        path was not created or pointed at."""
+        child = (
+            "import os, jax\n"
+            "from deeplearning4j_tpu.util import compile_cache as cc\n"
+            "got = cc.enable_persistent_cache()\n"
+            "assert got == os.environ['JAX_COMPILATION_CACHE_DIR'], got\n"
+            "assert jax.config.jax_compilation_cache_dir == got\n"
+            "assert got != cc.FIXED_CACHE_DIR\n"
+            "print('left-to-jax', got)\n"
+        )
+        d = str(tmp_path / "from-outside")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=d)
+        out = subprocess.run([sys.executable, "-c", child], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert f"left-to-jax {d}" in out.stdout
+
     @pytest.mark.slow
     def test_second_process_hits_cache(self, tmp_path):
         """Cross-process: a restarted process deserializes instead of
-        recompiling (the cold-start win bench_recompile_overhead measures)."""
+        recompiling (the cold-start win bench_recompile_overhead measures).
+        The parent places the throwaway cache from outside."""
         child = (
             "import sys, json, jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
             "from deeplearning4j_tpu.util import (enable_persistent_cache,"
             " get_watcher)\n"
-            "enable_persistent_cache(sys.argv[1])\n"
+            "enable_persistent_cache()\n"
             "import numpy as np\n"
             "w = get_watcher()\n"
             "f = jax.jit(lambda a: (a @ a.T).sum() * 2)\n"
@@ -493,9 +527,9 @@ class TestPersistentCache:
         d = str(tmp_path / "cc2")
 
         def run():
-            env = dict(os.environ)
-            env["JAX_PLATFORMS"] = "cpu"
-            out = subprocess.run([sys.executable, "-c", child, d], env=env,
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
+                       JAX_COMPILATION_CACHE_DIR=d)
+            out = subprocess.run([sys.executable, "-c", child], env=env,
                                  capture_output=True, text=True, timeout=300)
             assert out.returncode == 0, out.stderr[-2000:]
             return json.loads(out.stdout.strip().splitlines()[-1])

@@ -109,6 +109,15 @@ def test_residual_elementwise_add(rng):
         0,
     ) + np.asarray(x)
     np.testing.assert_allclose(np.asarray(acts["res"]), manual, rtol=1e-5)
+    # output() compiles a program that returns the output vertex alone (one
+    # that returns every vertex keeps every activation alive: ResNet-50
+    # B=256 exhausted a 16 GB chip, PR 21); feed_forward() returns them all
+    ins = {"in": jnp.asarray(x)}
+    out_acts, _ = jax.eval_shape(net._forward_train_jit, net.params,
+                                 net.states, ins)
+    assert set(out_acts) == {"out"} and set(acts) >= {"d1", "res", "out"}
+    np.testing.assert_allclose(np.asarray(net.output(x)),
+                               np.asarray(acts["out"]), rtol=1e-6)
     s0 = net.score(x=x, y=y)
     for _ in range(60):
         net.fit(x, y)
@@ -324,3 +333,31 @@ def test_graph_mask_threading_and_fit_dataset(rng):
     s0 = net.score(ds)
     net.fit(ds, epochs=12)
     assert net.score(ds) < s0
+
+
+def test_fit_compiles_the_train_step_once(rng):
+    """No leaf of a fresh network is weakly typed, so the step traced at
+    iteration 0 is the step that runs at iteration 1 and 2. BatchNorm's
+    gamma/beta used to be ``jnp.full(shape, python_float)`` — weak f32 — and
+    the flagship compiled its train step three times on the chip (PR 21)."""
+    from deeplearning4j_tpu.nn.layers import BatchNormalization
+    from deeplearning4j_tpu.util import get_watcher
+
+    conf = (
+        NeuralNetConfiguration.builder().seed(3).updater(Adam(1e-3))
+        .graph_builder().add_inputs("in")
+        .add_layer("d1", DenseLayer(n_in=5, n_out=5, activation="relu"), "in")
+        .add_layer("bn", BatchNormalization(), "d1")
+        .add_layer("out", OutputLayer(n_in=5, n_out=2), "bn")
+        .set_outputs("out").set_input_types(InputType.feed_forward(5))
+        .build()
+    )
+    net = ComputationGraph(conf).init()
+    leaves = jax.tree_util.tree_leaves(
+        (net.params, net.states, net.opt_states))
+    assert not [a for a in leaves if getattr(a, "weak_type", False)]
+    x, y = _toy_data(rng, n=8, n_in=5, n_out=2)
+    with get_watcher().scope() as s:
+        for _ in range(3):
+            net.fit(x, y)
+        assert s.traces_of("ComputationGraph.train_step") == 1

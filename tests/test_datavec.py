@@ -401,3 +401,22 @@ def test_fillna_covers_nan():
     tp = (TransformProcess.builder(schema)
           .replace_missing_value_with("v", 0.0).build())
     assert tp.execute([["a", float("nan")]])[0][1] == 0.0
+
+
+def test_etl_worker_code_imports_no_jax():
+    """MultiProcessTransformExecutor forks its workers from the training
+    process. On a TPU host that parent holds the chip, and a child that
+    initialised a JAX backend would fail or hang — so the record-function
+    modules the children run must not import JAX at all."""
+    import ast
+
+    from deeplearning4j_tpu.datavec import records, transform
+
+    for mod in (records, transform):
+        with open(mod.__file__) as f:
+            tree = ast.parse(f.read())
+        imported = {a.name.split(".")[0] for n in ast.walk(tree)
+                    if isinstance(n, ast.Import) for a in n.names}
+        imported |= {(n.module or "").split(".")[0] for n in ast.walk(tree)
+                     if isinstance(n, ast.ImportFrom)}
+        assert "jax" not in imported, mod.__name__

@@ -504,7 +504,7 @@ class TestCompressionAtScale:
         # Measured on this single-core host: shared ≈ 8.4x dense (13.8 s vs
         # 1.6 s) — the 8 virtual devices each encode a full 25M-element
         # gradient copy + carry an (8, 25M) residual, all on ONE core, so
-        # this measures host memory bandwidth, not the ICI design (numbers
-        # in BASELINE.md). The bound is a collapse detector (e.g. an
+        # this measures host memory bandwidth, not the ICI design. The
+        # bound is a collapse detector (e.g. an
         # accidental O(n^2) or per-element host loop), not a perf target.
         assert shared_dt < dense_dt * 20 + 10.0, (shared_dt, dense_dt)

@@ -65,13 +65,22 @@ class TestDispatch:
             assert K.resolve_impl() == "pallas"
         assert K.resolve_impl() == "exact"
 
-    def test_auto_is_exact_off_tpu(self, monkeypatch):
+    @pytest.mark.parametrize("backend,forced", [("cpu", "interpret"),
+                                                ("tpu", "pallas")])
+    def test_auto_without_evidence_is_exact_on_every_backend(
+            self, monkeypatch, backend, forced):
+        """No tuning-database winner -> auto takes the exact path, on a TPU
+        too (PR 21: the chip never ranked these kernels; the one conv it
+        timed lost). Forced pallas compiles for real on TPU and interprets
+        elsewhere."""
         monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
-        if jax.default_backend() == "tpu":
-            pytest.skip("auto engages the compiled kernel on TPU")
-        assert K.dispatch(True)[0] is None       # CPU cannot rank kernels
+        monkeypatch.delenv("DL4J_TPU_TUNING_DB", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert K.dispatch(True, op="conv2d", sig="s",
+                          dtype="bfloat16") == (None, {})
+        assert K.dispatch(True) == (None, {})
         with K.impl_scope("pallas"):
-            assert K.dispatch(True) == ("interpret", {})
+            assert K.dispatch(True) == (forced, {})
             assert K.dispatch(False)[0] is None  # unsupported geometry
 
     def test_bad_values_raise(self, monkeypatch):
@@ -232,7 +241,6 @@ class TestConvRowTiles:
         assert not kconv.valid_row_tile(8, 3)
         with pytest.raises(ValueError, match="row_tile"):
             kconv.conv2d_pallas(x, w, (1, 1), pads, (1, 1), 1, True, 3)
-        # per-candidate VMEM accounting scales with the tile
         assert None in kconv.valid_row_tiles(8)
         assert kconv.valid_row_tiles(8)[1:] == [1, 2, 4]
 
@@ -770,3 +778,112 @@ class TestPeakFlopsTable:
         rep2 = CostReport(rows=[CostRow(layer="0_conv")], totals={},
                           batch=8, params_total=1, source="xla")
         assert rep2.optimizer_update_share is None
+
+
+# ---------------------------------------------------------------------------
+# Pallas -> Mosaic cross-lowering (no chip needed)
+# ---------------------------------------------------------------------------
+
+
+def _lower_for_tpu(fn, *avals):
+    """StableHLO text of ``fn`` cross-lowered for the TPU platform with the
+    backend tests answering "tpu": exercises the installed JAX's
+    Pallas->Mosaic lowering (BlockSpec rules, vector-op support), not the
+    chip's compiler — what the chip's compiler said is in docs/KERNELS.md."""
+    from jax import export
+
+    return export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+
+
+def _aval(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _value_and_grads(fn, n):
+    def loss(*a):
+        return jnp.sum(fn(*a).astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=tuple(range(n)))
+
+
+class TestMosaicCrossLowering:
+    """Every Pallas kernel the seams can reach, at the r05 shapes, forward
+    and gradient: a lowering refusal is caught here from now on (PR 21 found
+    the stride-2 conv and the B>1 flash padding mask refused on first
+    contact with a TPU host)."""
+
+    @pytest.fixture(autouse=True)
+    def _tpu_backend(self, monkeypatch):
+        monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["unmasked", "padding-mask"])
+    def test_flash_attention_b4_seq2048(self, masked):
+        from deeplearning4j_tpu.ops.attention import flash_attention
+
+        qkv = _aval(4, 12, 2048, 64)
+        if masked:
+            fn = lambda q, k, v, m: flash_attention(  # noqa: E731
+                q, k, v, causal=True, mask=m)
+            avals = (qkv, qkv, qkv, _aval(4, 2048, dtype=jnp.float32))
+        else:
+            fn = lambda q, k, v: flash_attention(  # noqa: E731
+                q, k, v, causal=True)
+            avals = (qkv, qkv, qkv)
+        assert "tpu_custom_call" in _lower_for_tpu(
+            _value_and_grads(fn, 3), *avals)
+
+    @pytest.mark.parametrize("b_tile", [None, 32], ids=["whole", "bt32"])
+    def test_lstm_cell_b128_h512(self, b_tile):
+        h0 = jnp.zeros((128, 512), jnp.bfloat16)
+        fn = lambda xp, u: klstm.lstm_sequence_fused(  # noqa: E731
+            xp, h0, h0, u, klstm.ORDER_IFOG, "pallas", b_tile)[0]
+        assert "tpu_custom_call" in _lower_for_tpu(
+            _value_and_grads(fn, 2), _aval(4, 128, 2048), _aval(512, 2048))
+
+    @pytest.mark.parametrize("x_shape,w_shape", [
+        ((8, 56, 56, 64), (3, 3, 64, 64)),
+        ((8, 56, 56, 64), (1, 1, 64, 256)),
+        ((8, 28, 28, 128), (3, 3, 128, 128)),
+    ], ids=["3x3-56", "1x1-56", "3x3-28"])
+    def test_conv2d_stride1_through_the_seam(self, x_shape, w_shape):
+        from deeplearning4j_tpu.ops import nn as nnops
+
+        def fn(x, w):
+            with K.impl_scope("pallas"):
+                return nnops.conv2d(x, w)
+
+        assert "tpu_custom_call" in _lower_for_tpu(
+            _value_and_grads(fn, 2), _aval(*x_shape), _aval(*w_shape))
+
+    def test_conv2d_stride2_forced_pallas_raises(self):
+        """Mosaic (JAX 0.9.0) has no strided vector slice, so the strided
+        tap windows of conv.py cannot lower. Forced pallas says so with
+        the compiler's own error; auto never gets here (no database
+        winner can exist for a program that does not compile)."""
+        from deeplearning4j_tpu.ops import nn as nnops
+
+        def fn(x, w):
+            with K.impl_scope("pallas"):
+                return nnops.conv2d(x, w, strides=(2, 2))
+
+        with pytest.raises(Exception, match="strided_slice|stride"):
+            _lower_for_tpu(fn, _aval(8, 56, 56, 256), _aval(1, 1, 256, 128))
+
+    def test_resnet50_forward_reaches_no_kernel(self):
+        """The flagship at its default conf on a TPU host: 53 convolutions,
+        every one on the exact path — before PR 21, 51 of them routed to
+        conv2d_pallas and the first stride-2 one failed to lower, so
+        fit() could not even trace."""
+        from deeplearning4j_tpu.zoo import ResNet50
+
+        net = ResNet50(num_classes=1000, input_shape=(224, 224, 3),
+                       compute_dtype="bfloat16").init()
+        shapes = lambda t: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
+        text = _lower_for_tpu(net.make_forward_fn(), shapes(net.params),
+                              shapes(net.states),
+                              _aval(2, 224, 224, 3, dtype=jnp.float32))
+        assert text.count("stablehlo.convolution") == 53
+        assert "tpu_custom_call" not in text
