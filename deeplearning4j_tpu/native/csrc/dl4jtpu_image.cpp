@@ -187,7 +187,12 @@ struct ImgPipeline {
       ready.push_back(b);
       cv_pop.notify_one();
     }
-    done_workers.fetch_add(1);
+    {
+      // under the mutex: the consumer reads this in its wait's predicate,
+      // and a change between that read and its sleep is a lost wakeup
+      std::lock_guard<std::mutex> lk(mu);
+      done_workers.fetch_add(1);
+    }
     cv_pop.notify_all();
   }
 };
@@ -261,7 +266,10 @@ long img_pipe_next_batch(void* pipe, float* out, int* labels_out,
 
 void img_pipe_destroy(void* pipe) {
   ImgPipeline* p = static_cast<ImgPipeline*>(pipe);
-  p->stop.store(true);
+  {
+    std::lock_guard<std::mutex> lk(p->mu);  // as for done_workers
+    p->stop.store(true);
+  }
   p->cv_push.notify_all();
   p->cv_pop.notify_all();
   for (auto& t : p->workers) t.join();

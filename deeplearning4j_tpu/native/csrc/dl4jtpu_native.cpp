@@ -209,7 +209,12 @@ struct Pipeline {
         cv_pop.notify_one();
       }
     }
-    done_workers.fetch_add(1);
+    {
+      // under the mutex: the consumer reads this in its wait's predicate,
+      // and a change between that read and its sleep is a lost wakeup
+      std::lock_guard<std::mutex> lk(mu);
+      done_workers.fetch_add(1);
+    }
     cv_pop.notify_all();
   }
 };
@@ -237,7 +242,9 @@ long pipe_next(void* pipe, float** out_data, int* out_file_idx) {
   if (p->ready.empty()) return -3;
   Batch b = p->ready.front();
   p->ready.pop_front();
-  p->cv_push.notify_one();
+  // every waiter: the one woken may find its file already emitted and leave,
+  // and a notify_one spent on it strands the others with a free slot
+  p->cv_push.notify_all();
   *out_data = b.data;
   *out_file_idx = b.file_idx;
   return b.rows;
@@ -247,7 +254,10 @@ void pipe_free_batch(float* data) { free(data); }
 
 void pipe_destroy(void* pipe) {
   Pipeline* p = static_cast<Pipeline*>(pipe);
-  p->stop.store(true);
+  {
+    std::lock_guard<std::mutex> lk(p->mu);  // as for done_workers
+    p->stop.store(true);
+  }
   p->cv_push.notify_all();
   p->cv_pop.notify_all();
   for (auto& t : p->workers) t.join();

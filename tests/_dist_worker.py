@@ -16,6 +16,7 @@ the missed heartbeats, regroup to a smaller world, re-shard the batches,
 and finish."""
 
 import json
+import os
 import sys
 
 import jax
@@ -27,6 +28,14 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from deeplearning4j_tpu.parallel import distributed  # noqa: E402
+
+
+def _report(fields):
+    """The one line the parent test reads: the result, and the XLA_FLAGS
+    this process compiled under (the test checks that the suite's
+    optimisation level reached its children)."""
+    print(json.dumps(dict(fields, xla_flags=os.environ.get("XLA_FLAGS", ""))),
+          flush=True)
 
 
 def _local_dp(nproc, pid):
@@ -72,21 +81,19 @@ def _local_dp(nproc, pid):
     except Exception as e:  # noqa: BLE001
         global_step = f"unavailable ({type(e).__name__})"
 
-    print(json.dumps({
+    _report({
         "pid": pid,
         "coordinator": distributed.is_coordinator(),
         "n_devices_global": n_global,
         "n_devices_local": n_local,
         "local_dp_err": round(err, 6),
         "global_step": global_step,
-    }), flush=True)
+    })
     distributed.shutdown()
 
 
 def _elastic(shared_dir, pid, world, sigkill_at=None):
     """``--elastic`` mode: one member of a supervised elastic pod."""
-    import os
-
     from deeplearning4j_tpu.data import ArrayDataSetIterator
     from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
                                        NeuralNetConfiguration)
@@ -118,7 +125,7 @@ def _elastic(shared_dir, pid, world, sigkill_at=None):
         membership=membership, log_fn=None)
     trainer.fit(it, epochs=3)
     view = membership.view
-    print(json.dumps({
+    _report({
         "pid": pid,
         "state": trainer.state,
         "iteration": net.iteration,
@@ -127,7 +134,7 @@ def _elastic(shared_dir, pid, world, sigkill_at=None):
         "members_final": list(view.members) if view else None,
         "regroups": membership.regroups,
         "score_finite": bool(np.isfinite(float(net.score_value))),
-    }), flush=True)
+    })
 
 
 def _elastic_compress(shared_dir, pid, world, sigkill_at=None):
@@ -138,8 +145,6 @@ def _elastic_compress(shared_dir, pid, world, sigkill_at=None):
     (whose wrapper re-shards with its residual migrated in place), and the
     final checkpoint carries the residual EXACTLY (bit-compared against a
     fresh restore before reporting)."""
-    import os
-
     from deeplearning4j_tpu.data import ArrayDataSetIterator
     from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
                                        NeuralNetConfiguration)
@@ -192,7 +197,7 @@ def _elastic_compress(shared_dir, pid, world, sigkill_at=None):
 
     view = membership.view
     stats = pw.compression_stats()
-    print(json.dumps({
+    _report({
         "pid": pid,
         "state": trainer.state,
         "iteration": net.iteration,
@@ -204,7 +209,7 @@ def _elastic_compress(shared_dir, pid, world, sigkill_at=None):
         "residual_exact": bool(residual_exact),
         "wire_bytes": stats["wire_bytes"] if stats else None,
         "threshold": stats["threshold"] if stats else None,
-    }), flush=True)
+    })
 
 
 def _pipe(shared_dir, pid, world, sigkill_at=None):
@@ -217,8 +222,6 @@ def _pipe(shared_dir, pid, world, sigkill_at=None):
     checkpoint restores BIT-exactly at the boundary (the restored net's
     re-stacked pipeline state is bit-compared in-process against the live
     trainer's)."""
-    import os
-
     from deeplearning4j_tpu.data import ArrayDataSetIterator
     from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
                                        NeuralNetConfiguration)
@@ -284,7 +287,7 @@ def _pipe(shared_dir, pid, world, sigkill_at=None):
                 for a, b in zip(live, restored)))
 
     view = membership.view
-    print(json.dumps({
+    _report({
         "pid": pid,
         "state": trainer.state,
         "iteration": net.iteration,
@@ -296,7 +299,7 @@ def _pipe(shared_dir, pid, world, sigkill_at=None):
         "stacked_exact": bool(stacked_exact),
         "pipe_stages": pt.pipe_stages,
         "bubble_fraction": pt.bubble_fraction,
-    }), flush=True)
+    })
 
 
 def main():
@@ -382,13 +385,13 @@ def main():
         for _ in range(30):
             w = step(w, x, y)
         w_final = np.asarray(jax.device_get(w))
-    print(json.dumps({
+    _report({
         "pid": pid,
         "n_devices_global": n_dev,
         "data_plane": data_plane,
         "w": [round(float(v), 6) for v in w_final],
         "err": round(float(np.abs(w_final - w_true).max()), 6),
-    }), flush=True)
+    })
     distributed.shutdown()
 
 

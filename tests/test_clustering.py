@@ -193,7 +193,11 @@ class TestTsne:
             rng.normal(size=(12, 6)).astype(np.float32) + np.asarray(c,
                                                                      np.float32)
             for c in ((0,) * 6, (9,) * 6, (-9, 9) * 3)])
-        t = Tsne(perplexity=8, n_iter=250, seed=0).fit(x)
+        # past stop_lying_iteration (250): until there the steps descend the
+        # KL against 12 x P, not the one read here, and where iteration 250
+        # lands (0.64, 1.01 or 1.13) follows how the host's compiler rounds;
+        # by 500 every optimisation level reads 0.065-0.069
+        t = Tsne(perplexity=8, n_iter=500, seed=0).fit(x)
         assert np.isfinite(t.kl_divergence)
         # optimized KL must beat the KL of the random init by a wide margin
         t0 = Tsne(perplexity=8, n_iter=1, seed=0).fit(x)

@@ -361,7 +361,7 @@ class TestDistributedBootstrap:
         assert m.model == 2
         assert m.n_devices == len(jax.devices())
 
-    def test_multi_process_bootstrap_and_dp_step(self):
+    def test_multi_process_bootstrap_and_dp_step(self, child_env):
         """The reference tests its cluster path without a cluster (embedded
         MediaDriver / local[N] Spark — SURVEY.md §4); the equivalent here:
         two real OS processes, coordinator on localhost, global 4-device mesh
@@ -379,30 +379,28 @@ class TestDistributedBootstrap:
             port = s.getsockname()[1]
         coordinator = f"127.0.0.1:{port}"
         worker = os.path.join(os.path.dirname(__file__), "_dist_worker.py")
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-        env["JAX_PLATFORMS"] = "cpu"
-        repo_root = os.path.dirname(os.path.dirname(os.path.abspath(worker)))
-        env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
         procs = [
             subprocess.Popen(
                 [sys.executable, worker, coordinator, "2", str(pid)],
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-                env=env)
+                env=child_env(device_count=2))
             for pid in (0, 1)
         ]
         outs = []
-        for p in procs:
-            try:
-                out, err = p.communicate(timeout=420)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                raise
-            assert p.returncode == 0, err[-2000:]
-            outs.append(json.loads(
-                [l for l in out.splitlines() if l.startswith("{")][-1]))
+        try:
+            for p in procs:
+                out, err = p.communicate(timeout=240)
+                assert p.returncode == 0, err[-2000:]
+                outs.append(json.loads(
+                    [l for l in out.splitlines() if l.startswith("{")][-1]))
+        finally:
+            for p in procs:  # a hang fails this test, not the run
+                if p.poll() is None:
+                    p.kill()
+                    p.wait(timeout=30)
+        # the children compiled at the suite's level
+        assert all("xla_backend_optimization_level" in o["xla_flags"]
+                   for o in outs), outs
         assert all(o["n_devices_global"] == 4 for o in outs), outs
         assert outs[0]["w"] == outs[1]["w"], outs  # identical replicas
         assert outs[0]["err"] < 0.5, outs  # learning happened

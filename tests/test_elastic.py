@@ -266,9 +266,12 @@ class TestCheckpointer:
         ck = ShardedCheckpointer(str(tmp_path / "empty"), log_fn=None)
         assert ck.restore_latest_good(_mln()) is None
 
-    def test_async_save_commits_identically(self, tmp_path):
+    def test_async_save_commits_identically(self, tmp_path, wait_until):
         net, ck = self._fit_and_save(tmp_path)
         ck.save(net.iteration + 1, net, block=False)
+        # wait_until_finished() joins without a deadline: give it one
+        wait_until(lambda: not ck._pending.is_alive(), 60,
+                   "the async save's thread ended")
         ck.wait_until_finished()
         sync_net = MultiLayerNetwork(net.conf).init()
         ck.restore(sync_net, step=net.iteration + 1)
@@ -459,7 +462,8 @@ class TestFaultRecoveryPaths:
         assert tr.rollbacks == 2
         assert tr.state == "failed"
 
-    def test_drop_heartbeat_shrinks_world_at_regroup(self, tmp_path):
+    def test_drop_heartbeat_shrinks_world_at_regroup(self, tmp_path,
+                                                     wait_until):
         d = str(tmp_path / "members")
         # b gets a PRIVATE injector so drop_heartbeat hits exactly ITS beat
         # thread (both members live in this one test process)
@@ -483,17 +487,18 @@ class TestFaultRecoveryPaths:
             tb.start()
             views[0] = a.regroup(0)
             tb.join(timeout=20)
+            assert not tb.is_alive()
             assert views[0].world == 2 and views[1].world == 2
 
             # b's heartbeats drop (the fault fires in ITS beat thread);
             # after the miss threshold, a's next regroup evicts it
             before = _counter("elastic.heartbeats_dropped_total")
             b_injector.inject(DROP_HEARTBEAT, arg=1000)
-            deadline = time.monotonic() + 10
-            while (_counter("elastic.heartbeats_dropped_total") <= before
-                   and time.monotonic() < deadline):
-                time.sleep(0.02)
-            time.sleep(0.05 * 4)  # past the freshness window
+            wait_until(
+                lambda: _counter("elastic.heartbeats_dropped_total") > before,
+                10, "b's beat thread dropped a heartbeat")
+            wait_until(lambda: a.alive() == [0], 10,
+                       "b's last heartbeat left a's freshness window")
             view = a.regroup(1)
             assert view.world == 1 and view.members == (0,)
             assert a.regroups == 1
@@ -502,38 +507,15 @@ class TestFaultRecoveryPaths:
             a.stop()
             b.stop()
 
-    def test_sigkill_host_survivor_regroups_and_finishes(self, tmp_path):
+    def test_sigkill_host_survivor_regroups_and_finishes(self, tmp_path,
+                                                         child_env):
         """ISSUE acceptance: 2 OS processes, one SIGKILLed mid-epoch; the
         survivor notices the missed heartbeats, regroups to world 1,
         re-shards the batches, and finishes all epochs."""
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   PYTHONPATH=os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))))
-        env.pop("XLA_FLAGS", None)
-        worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "_dist_worker.py")
-        d = str(tmp_path / "pod")
-        procs = [subprocess.Popen(
-            [sys.executable, worker, "--elastic", d, str(pid), "2"]
-            + (["2"] if pid == 1 else []),  # pid 1 SIGKILLs itself at step 2
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            env=env) for pid in (0, 1)]
-        out0, err0 = procs[0].communicate(timeout=240)
-        out1, _ = procs[1].communicate(timeout=240)
-        assert procs[1].returncode == -signal.SIGKILL  # died hard, no JSON
-        assert not out1.strip()
-        assert procs[0].returncode == 0, err0[-1500:]
-        r = json.loads([l for l in out0.splitlines()
-                        if l.startswith("{")][-1])
-        assert r["state"] == "completed"
-        assert r["world_final"] == 1 and r["members_final"] == [0]
-        assert r["regroups"] >= 1
-        assert r["epoch"] == 3 and r["score_finite"]
-        # 8 batches/epoch: epoch 0 sharded 2 ways (4 steps), then re-sharded
-        # to all 8 for the remaining epochs
-        assert r["iteration"] == 4 + 8 + 8
+        _sigkill_pod("--elastic", tmp_path, child_env)
 
-    def test_sigkill_with_grad_compression_migrates_residual(self, tmp_path):
+    def test_sigkill_with_grad_compression_migrates_residual(self, tmp_path,
+                                                             child_env):
         """Elastic × compression (ISSUE 10 satellite): same 2-process
         SIGKILL scenario, but the data plane is the COMPRESSED
         ParallelWrapper step — the survivor regroups with its
@@ -541,37 +523,14 @@ class TestFaultRecoveryPaths:
         iteration trace proves it kept training), and the final checkpoint
         carries the residual EXACTLY (bit-compared in-process against a
         fresh restore)."""
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   PYTHONPATH=os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))))
-        env.pop("XLA_FLAGS", None)
-        worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "_dist_worker.py")
-        d = str(tmp_path / "pod")
-        procs = [subprocess.Popen(
-            [sys.executable, worker, "--elastic-compress", d, str(pid), "2"]
-            + (["2"] if pid == 1 else []),  # pid 1 SIGKILLs itself at step 2
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            env=env) for pid in (0, 1)]
-        out0, err0 = procs[0].communicate(timeout=240)
-        out1, _ = procs[1].communicate(timeout=240)
-        assert procs[1].returncode == -signal.SIGKILL
-        assert not out1.strip()
-        assert procs[0].returncode == 0, err0[-1500:]
-        r = json.loads([l for l in out0.splitlines()
-                        if l.startswith("{")][-1])
-        assert r["state"] == "completed"
-        assert r["world_final"] == 1 and r["members_final"] == [0]
-        assert r["regroups"] >= 1
-        assert r["epoch"] == 3 and r["score_finite"]
-        assert r["iteration"] == 4 + 8 + 8  # same trace as the plain leg
+        r = _sigkill_pod("--elastic-compress", tmp_path, child_env)
         assert r["residual_exact"], r  # checkpoint carried the residual
         assert r["wire_bytes"] and r["wire_bytes"] > 0
         assert r["threshold"] and r["threshold"] > 0
 
     @pytest.mark.slow
     def test_sigkill_with_pipelined_trainer_restores_stacked_state(
-            self, tmp_path):
+            self, tmp_path, child_env):
         """Elastic × pipeline (ISSUE 14 satellite): the 2-process SIGKILL
         scenario with the PIPELINED trainer as the data plane — stacked
         stage params/optimizer state, GPipe microbatch schedule, lane DP.
@@ -580,33 +539,47 @@ class TestFaultRecoveryPaths:
         through model layout bit-exactly), and the final checkpoint
         restores the STACKED stage state bit-exactly at the boundary
         (compared in-process against the live trainer's placed leaves)."""
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   PYTHONPATH=os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))))
-        env.pop("XLA_FLAGS", None)
-        worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "_dist_worker.py")
-        d = str(tmp_path / "pod")
-        procs = [subprocess.Popen(
-            [sys.executable, worker, "--pipe", d, str(pid), "2"]
-            + (["2"] if pid == 1 else []),  # pid 1 SIGKILLs itself at step 2
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            env=env) for pid in (0, 1)]
-        out0, err0 = procs[0].communicate(timeout=240)
-        out1, _ = procs[1].communicate(timeout=240)
-        assert procs[1].returncode == -signal.SIGKILL
-        assert not out1.strip()
-        assert procs[0].returncode == 0, err0[-1500:]
-        r = json.loads([l for l in out0.splitlines()
-                        if l.startswith("{")][-1])
-        assert r["state"] == "completed"
-        assert r["world_final"] == 1 and r["members_final"] == [0]
-        assert r["regroups"] >= 1
-        assert r["epoch"] == 3 and r["score_finite"]
-        assert r["iteration"] == 4 + 8 + 8  # same trace as the plain leg
+        r = _sigkill_pod("--pipe", tmp_path, child_env)
         assert r["stacked_exact"], r  # checkpoint carried the stacked state
         assert r["pipe_stages"] == 2
         assert 0 < r["bubble_fraction"] < 1
+
+
+def _sigkill_pod(mode, tmp_path, child_env):
+    """Two OS processes of ``_dist_worker.py <mode>`` over one shared
+    directory; pid 1 SIGKILLs itself at step 2. Asserts what every data
+    plane owes (the victim died hard, the survivor regrouped to world 1 and
+    finished the same 4+8+8 trace) and returns the survivor's report."""
+    worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "_dist_worker.py")
+    procs = [subprocess.Popen(
+        [sys.executable, worker, mode, str(tmp_path / "pod"), str(pid), "2"]
+        + (["2"] if pid == 1 else []),  # pid 1 SIGKILLs itself at step 2
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        env=child_env()) for pid in (0, 1)]
+    try:
+        out0, err0 = procs[0].communicate(timeout=240)
+        out1, _ = procs[1].communicate(timeout=60)
+    finally:
+        for p in procs:  # a hang fails this test, not the run
+            if p.poll() is None:
+                p.kill()
+                p.wait(timeout=30)
+    assert procs[1].returncode == -signal.SIGKILL  # died hard, no JSON
+    assert not out1.strip()
+    assert procs[0].returncode == 0, err0[-1500:]
+    r = json.loads([l for l in out0.splitlines() if l.startswith("{")][-1])
+    assert r["state"] == "completed"
+    assert r["world_final"] == 1 and r["members_final"] == [0]
+    assert r["regroups"] >= 1
+    assert r["epoch"] == 3 and r["score_finite"]
+    # 8 batches/epoch: epoch 0 sharded 2 ways (4 steps), then re-sharded
+    # to all 8 for the remaining epochs
+    assert r["iteration"] == 4 + 8 + 8
+    # the child compiled at the suite's level, on its one device
+    assert "xla_backend_optimization_level" in r["xla_flags"]
+    assert "device_count" not in r["xla_flags"]
+    return r
 
 
 def _slow_double(v):

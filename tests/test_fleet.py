@@ -183,13 +183,18 @@ class _StubWorker:
         self.httpd = ThreadingHTTPServer(("127.0.0.1", 0), Handler)
         self.port = self.httpd.server_address[1]
         self.url = f"http://127.0.0.1:{self.port}"
-        self.thread = threading.Thread(target=self.httpd.serve_forever,
-                                       daemon=True)
+        # shutdown() waits out one poll interval: the default, half a
+        # second, was most of every stub test's time
+        self.thread = threading.Thread(
+            target=self.httpd.serve_forever, kwargs={"poll_interval": 0.01},
+            daemon=True)
         self.thread.start()
 
     def stop(self):
         self.httpd.shutdown()
         self.httpd.server_close()
+        self.thread.join(timeout=10)
+        assert not self.thread.is_alive()
 
 
 def _post(port, path, body=None, headers=None, timeout=10):

@@ -50,6 +50,11 @@ from deeplearning4j_tpu.nn.layers import (
 )
 from deeplearning4j_tpu.nn.updaters import Adam
 
+# The deadline of every executor and prefetch worker a test starts here and
+# expects to finish: their own defaults (300 s, 120 s) would let one hang
+# take a fifth of the suite's limit.
+HANG_S = 30.0
+
 
 # --------------------------------------------------------------------------
 # multiprocess TransformProcess executor
@@ -95,7 +100,8 @@ def test_mp_executor_bit_identical_to_serial(iris_csv):
     serial = tp.execute(records)
     for workers in (2, 4):
         ex = MultiProcessTransformExecutor(
-            tp, num_workers=workers, min_records_per_worker=1)
+            tp, num_workers=workers, min_records_per_worker=1,
+            timeout=HANG_S)
         assert ex.execute(records) == serial  # exact, order included
 
 
@@ -103,7 +109,7 @@ def test_mp_executor_small_input_serial_path(iris_csv):
     # below 2*min_records_per_worker the serial path runs — still identical
     records = list(CSVRecordReader(iris_csv))[:10]
     tp = _iris_tp()
-    ex = MultiProcessTransformExecutor(tp, num_workers=4,
+    ex = MultiProcessTransformExecutor(tp, num_workers=4, timeout=HANG_S,
                                        min_records_per_worker=64)
     assert ex.execute(records) == tp.execute(records)
 
@@ -118,7 +124,7 @@ def test_mp_executor_worker_exception_propagates(iris_csv):
 
     tp = (TransformProcess.builder(_iris_schema())
           .double_column_transform("sl", boom).build())
-    ex = MultiProcessTransformExecutor(tp, num_workers=2,
+    ex = MultiProcessTransformExecutor(tp, num_workers=2, timeout=HANG_S,
                                        min_records_per_worker=1)
     with pytest.raises(TransformExecutionError, match="bad record in worker"):
         ex.execute(records)
@@ -133,7 +139,7 @@ def test_mp_executor_timeout_no_hang(iris_csv):
 
     tp = (TransformProcess.builder(_iris_schema())
           .double_column_transform("sl", wedge).build())
-    ex = MultiProcessTransformExecutor(tp, num_workers=2, timeout=1.0,
+    ex = MultiProcessTransformExecutor(tp, num_workers=2, timeout=0.3,
                                        min_records_per_worker=1)
     t0 = time.perf_counter()
     with pytest.raises(TransformExecutionError, match="timed out"):
@@ -147,7 +153,7 @@ def test_parallel_record_reader_bridges_to_iterator(iris_csv):
     tp = _iris_tp()
     base = TransformProcessRecordReader(CSVRecordReader(iris_csv), tp)
     par = ParallelTransformRecordReader(CSVRecordReader(iris_csv), tp,
-                                        num_workers=2)
+                                        num_workers=2, timeout=HANG_S)
     it_serial = RecordReaderDataSetIterator(base, 16, label_index=4,
                                             num_classes=3)
     it_par = RecordReaderDataSetIterator(par, 16, label_index=4,
@@ -199,7 +205,8 @@ def test_prefetch_batch_size_over_attribute_style_base(iris_csv):
 
 def test_prefetch_yields_all_batches_in_order():
     src = _batches(8)
-    out = list(AsyncDataSetIterator(_ListIterator(src), buffer_size=2))
+    out = list(AsyncDataSetIterator(_ListIterator(src), buffer_size=2,
+                                    timeout=HANG_S))
     assert len(out) == 8
     for a, b in zip(src, out):
         np.testing.assert_array_equal(np.asarray(a.features),
@@ -207,7 +214,8 @@ def test_prefetch_yields_all_batches_in_order():
 
 
 def test_prefetch_stages_on_device():
-    it = AsyncDataSetIterator(_ListIterator(_batches(3)), buffer_size=2)
+    it = AsyncDataSetIterator(_ListIterator(_batches(3)), buffer_size=2,
+                              timeout=HANG_S)
     for ds in it:
         # staged arrays are device-resident jax Arrays, not host numpy
         assert hasattr(ds.features, "devices")
@@ -219,7 +227,8 @@ def test_prefetch_donation_safety():
     batch k+1: hold every received batch, snapshot on receipt, let the
     worker run ahead, then verify all snapshots still match."""
     src = _batches(8)
-    it = AsyncDataSetIterator(_ListIterator(src), buffer_size=2)
+    it = AsyncDataSetIterator(_ListIterator(src), buffer_size=2,
+                              timeout=HANG_S)
     held, snaps = [], []
     for ds in it:
         held.append(ds)
@@ -252,7 +261,7 @@ class _BoomIterator(_ListIterator):
 
 def test_prefetch_worker_exception_reraises():
     it = AsyncDataSetIterator(_BoomIterator(_batches(6), fail_after=2),
-                              buffer_size=2)
+                              buffer_size=2, timeout=HANG_S)
     got = []
     with pytest.raises(RuntimeError, match="ETL worker exploded"):
         for ds in it:
@@ -266,7 +275,7 @@ def test_prefetch_worker_exception_propagates_to_fit():
     y = np.eye(10, dtype=np.float32)[[1, 2, 3, 4]]
     batches = [DataSet(x, y) for _ in range(5)]
     it = AsyncDataSetIterator(_BoomIterator(batches, fail_after=3),
-                              buffer_size=2)
+                              buffer_size=2, timeout=HANG_S)
     with pytest.raises(RuntimeError, match="ETL worker exploded"):
         net.fit(it, epochs=1)
 

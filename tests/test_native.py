@@ -96,6 +96,32 @@ class TestAsyncPipeline:
             np.testing.assert_allclose(arr, ref[idx], atol=1e-5)
         pipe.close()
 
+    def test_many_workers_one_slot_strands_no_waiter(self, tmp_path):
+        """Two lost wakeups that `next()` never returned from. Four workers
+        wait on one free slot and the one that is woken may find its file
+        already emitted and leave: waking one waiter per slot (as `pipe_next`
+        did) strands the rest, and the first pipeline of this shape hung,
+        every time. And the last worker counted itself done outside the
+        mutex, between the consumer's look at that count and its sleep: one
+        pipeline in some hundreds on a busy machine."""
+        import threading
+
+        paths = self._files(tmp_path, n=6, rows=5)
+        rounds = []
+
+        def drain():
+            for _ in range(200):
+                pipe = native.AsyncCSVPipeline(paths, cols=3, n_threads=4,
+                                               prefetch=1)
+                rounds.append([idx for idx, _arr in pipe])
+                pipe.close()
+
+        t = threading.Thread(target=drain, daemon=True)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive(), f"hung in pipeline {len(rounds) + 1} of 200"
+        assert rounds == [list(range(6))] * 200
+
     def test_unreadable_file_raises(self, tmp_path):
         paths = self._files(tmp_path, n=2)
         paths.insert(1, str(tmp_path / "missing.csv"))

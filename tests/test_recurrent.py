@@ -30,16 +30,7 @@ from deeplearning4j_tpu.nn.updaters import Adam
 B, T, F, H = 2, 5, 3, 4
 
 
-# tier-1 budget discipline (the r16 convention, extended r19 on a slow
-# host): GravesLSTM/GRU share the recurrent-gradcheck seam with the LSTM
-# and SimpleRnn variants that stay fast — the slow-marked pair still runs
-# in every full-CI pass
-@pytest.mark.parametrize("layer_cls", [
-    LSTM,
-    pytest.param(GravesLSTM, marks=pytest.mark.slow),
-    pytest.param(GRU, marks=pytest.mark.slow),
-    SimpleRnn,
-])
+@pytest.mark.parametrize("layer_cls", [LSTM, GravesLSTM, GRU, SimpleRnn])
 def test_recurrent_gradcheck(layer_cls, rng):
     lyr = layer_cls(n_in=F, n_out=H)
     params, state = lyr.initialize(jax.random.PRNGKey(0), (T, F))
@@ -50,16 +41,14 @@ def test_recurrent_gradcheck(layer_cls, rng):
                          training=True)
         return jnp.sum(y ** 2)
 
-    res = gradcheck.check_model_gradients(loss, params)
+    # jitted: the finite differences call the loss some 200 times, and an
+    # eager scan compiles its body anew on every call (35 s for the LSTM,
+    # which is why GravesLSTM, GRU and the bidirectional check below used to
+    # be marked slow)
+    res = gradcheck.check_model_gradients(jax.jit(loss), params)
     assert res.passed, res
 
 
-# tier-1 runtime guard (ISSUE 11 satellite): heaviest test in the suite
-# (~33s — fp64 gradcheck through a double-LSTM scan); the per-cell
-# gradchecks above and the cheap bidirectional wrapper tests below
-# (test_bidirectional_l2_in_network, test_graves_bidirectional_lstm_layer)
-# keep both seams in tier-1; the full-suite CI leg still runs this
-@pytest.mark.slow
 def test_bidirectional_gradcheck_and_shape(rng):
     lyr = Bidirectional(layer=LSTM(n_in=F, n_out=H))
     params, state = lyr.initialize(jax.random.PRNGKey(0), (T, F))
@@ -72,7 +61,7 @@ def test_bidirectional_gradcheck_and_shape(rng):
                            training=True)
         return jnp.sum(out ** 2)
 
-    res = gradcheck.check_model_gradients(loss, params)
+    res = gradcheck.check_model_gradients(jax.jit(loss), params)
     assert res.passed, res
 
 

@@ -165,7 +165,7 @@ class TestCircuitBreaker:
 
 
 class TestBreakerOnTraffic:
-    def test_compute_errors_open_then_half_open_closes(self):
+    def test_compute_errors_open_then_half_open_closes(self, wait_until):
         router, _net, _model, sched = _router_with("brk")
         try:
             sched.breaker.consecutive_errors = 2
@@ -179,13 +179,20 @@ class TestBreakerOnTraffic:
             with pytest.raises(CircuitOpenError):
                 router.submit("brk", X2)
             assert sched.counts["shed_circuit_open"] >= 1
-            time.sleep(0.4)  # cooldown -> half-open probe allowed through
-            out = np.asarray(router.submit("brk", X2).result(timeout=20))
-            assert out.shape == (2, 4)
-            deadline = time.time() + 5
-            while sched.breaker.state != "closed" and time.time() < deadline:
-                time.sleep(0.02)
-            assert sched.breaker.state == "closed"
+            # cooldown over -> the half-open probe is allowed through
+            probe = []
+
+            def admitted():
+                try:
+                    probe.append(router.submit("brk", X2))
+                except CircuitOpenError:
+                    return False
+                return True
+
+            wait_until(admitted, 5, "half-open probe after the cooldown")
+            assert np.asarray(probe[0].result(timeout=20)).shape == (2, 4)
+            wait_until(lambda: sched.breaker.state == "closed", 5,
+                       "breaker closed by the probe's success")
         finally:
             router.shutdown()
 
@@ -450,11 +457,10 @@ class TestBrownout:
             with pytest.raises(BrownoutShedError):
                 router.submit("bo2", X2, lane="batch")
             router.submit("bo2", X2, lane="interactive").result(timeout=20)
-            # budget recovery (bad traffic ages out of the 5s window)
-            deadline = time.time() + 20
-            while ctrl.active and time.time() < deadline:
-                time.sleep(0.25)
-                slo.get_engine().evaluate()
+            # budget recovery (bad traffic ages out of the 5s window): the
+            # engine takes the time as an argument, so nobody sits it out
+            engine = slo.get_engine()
+            engine.evaluate(now=engine.clock() + 6.0)
             assert not ctrl.active
             router.submit("bo2", X2, lane="batch").result(timeout=20)
         finally:
@@ -464,7 +470,7 @@ class TestBrownout:
 
 # ------------------------------------------------------------ slow batch
 class TestSlowBatchFault:
-    def test_deadline_sheds_behind_a_stalled_batch(self):
+    def test_deadline_sheds_behind_a_stalled_batch(self, wait_until):
         """serving_slow_batch wedges the worker on a real sleep; a request
         whose deadline expires while queued behind it is shed 429, not
         executed late — the contract holds under a wedged worker."""
@@ -472,7 +478,11 @@ class TestSlowBatchFault:
         try:
             get_injector().inject(fl.SERVING_SLOW_BATCH, arg=400.0)
             slow_fut = router.submit("slow", X2)  # eats the stall
-            time.sleep(0.05)  # let the worker open the stalled batch
+            # the fault has fired: the worker is inside the stalled batch,
+            # past the window in which a new request could still join it
+            wait_until(lambda: any(kind == fl.SERVING_SLOW_BATCH
+                                   for kind, _step in get_injector().log),
+                       5, "the worker opened the stalled batch")
             doomed = router.submit("slow", X2, deadline_ms=100.0)
             from deeplearning4j_tpu.serving import DeadlineExceededError
 
