@@ -259,31 +259,36 @@ class TransformerEncoderBlock(Layer):
     # ------------------------------------------------- paged KV-cache path
     # Serving substrate for the paged/block pool (serving/paged.py): the
     # K/V of EVERY stream live in one slot-flat pool per layer — shape
-    # (S, H, Dh) with S = num_blocks * block_size — and each stream's page
-    # table expands to per-position slot indices (``slots``, width
-    # max_length, sliced by the generator). Projections, sublayer math and
-    # the attention mask are the SAME code the contiguous path runs, and
-    # the gathered (B, H, max_length, Dh) layout matches the contiguous
-    # cache exactly, so paged decode is BIT-identical to contiguous decode
-    # (tests/test_paged_decode.py).
+    # (S, H*Dh) with S = num_blocks * block_size, a token's heads side by
+    # side in one row — and each stream's page table (``tables``,
+    # (B, max_blocks)) names the blocks behind its logical positions.
+    # Projections, sublayer math and the attention mask are the SAME code
+    # the contiguous path runs; the attention itself walks the table block
+    # chunk by block chunk as far as the longest stream reaches
+    # (ops/attention.paged_attention), so paged decode gives the
+    # contiguous decode's tokens, and its logits to the rounding of a
+    # float32 sum (tests/test_paged_decode.py).
 
     def init_pool(self, num_slots: int, dtype=jnp.float32):
-        """Empty slot-flat K/V pool for this layer: (S, H, Dh) each. Two
-        DISTINCT buffers — the pools are donated through the decode
-        executables, and aliased k/v would be the same buffer donated
-        twice."""
-        dh = self.hidden_size // self.n_heads
-        shape = (num_slots, self.n_heads, dh)
+        """Empty slot-flat K/V pool for this layer: (S, H*Dh) each. Rows
+        of the whole hidden width, because a TPU lays an (S, H, Dh) array
+        of Dh < 128 out with S on the lanes, and every program that
+        scatters or gathers slots then copies the whole pool to a
+        slot-major layout and back (PERF.md, PR 28). Two DISTINCT buffers
+        — the pools are donated through the decode executables, and
+        aliased k/v would be the same buffer donated twice."""
+        shape = (num_slots, self.hidden_size)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def _pool_write(self, pool, slots_flat, k, v):
-        """Scatter (N, H, Dh) K/V rows at flat slot indices (N,). Trash-
-        block collisions (padding writes) are garbage-on-garbage — every
-        read is position-masked before the softmax."""
-        return {
-            "k": pool["k"].at[slots_flat].set(k.astype(pool["k"].dtype)),
-            "v": pool["v"].at[slots_flat].set(v.astype(pool["v"].dtype)),
-        }
+        """Scatter the K/V of N tokens, (B, H, T, Dh) with B*T = N, as
+        (N, H*Dh) rows at flat slot indices (N,). Trash-block collisions
+        (padding writes) are garbage-on-garbage — every read is
+        position-masked before the softmax."""
+        rows = lambda y: jnp.transpose(y, (0, 2, 1, 3)).reshape(
+            -1, self.hidden_size).astype(pool["k"].dtype)
+        return {"k": pool["k"].at[slots_flat].set(rows(k)),
+                "v": pool["v"].at[slots_flat].set(rows(v))}
 
     def prefill_paged(self, params, x, pool, slots, mask=None):
         """Causal forward over the prompt (B,T,H), scattering each
@@ -294,56 +299,53 @@ class TransformerEncoderBlock(Layer):
         next-token logits) are bit-identical to the contiguous prefill."""
         if not self.causal:
             raise ValueError("prefill/decode_step need causal=True blocks")
-        b, t, _ = x.shape
         q, k, v = self._qkv(params, self._attn_input(params, x))
-        rows = jnp.transpose(k, (0, 2, 1, 3)).reshape(b * t, self.n_heads, -1)
-        vrows = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * t, self.n_heads, -1)
-        pool = self._pool_write(pool, slots.reshape(-1), rows, vrows)
+        pool = self._pool_write(pool, slots.reshape(-1), k, v)
         amask = None if mask is None else mask[:, None, None, :].astype(bool)
         o = attn_ops.dot_product_attention(q, k, v, mask=amask, causal=True)
         return self._finish(params, x, self._proj_out(params, o)), pool
 
-    def prefill_resume_paged(self, params, x_w, pool, slots, positions,
-                             limits=None):
+    def prefill_resume_paged(self, params, x_w, pool, tables, positions,
+                             block_size, limits=None):
         """Resume-from-position prefill (the shared-prefix KV path,
         serving/paged.py): prefill a prompt SUFFIX — ``x_w`` (B, W, H)
         at per-row absolute ``positions`` (B, W) starting wherever each
         stream's prefix-cache hit ends — against K/V the cached blocks
         already hold for the skipped head. Write-then-attend through the
         page table with every query masked to ``k_pos <= position`` is
-        exactly the windowed decode semantics, which is bit-identical to
-        the whole-prompt causal prefill (the verify-window contract), so
-        resumed prefill commits the same bytes and logits as recomputing
-        the prefix: a thin, documented delegation, kept as its own entry
-        point because the CALLING contract differs (positions resume
-        mid-prompt; ``limits`` is the last PROMPT position, trashing the
-        lockstep-chunk padding columns)."""
-        return self.decode_window_paged(params, x_w, pool, slots,
-                                        positions, limits=limits)
+        exactly the windowed decode semantics, which equals the
+        whole-prompt causal prefill (the verify-window contract), so
+        resumed prefill commits the same K/V and the same tokens as
+        recomputing the prefix: a thin, documented delegation, kept as
+        its own entry point because the CALLING contract differs
+        (positions resume mid-prompt; ``limits`` is the last PROMPT
+        position, trashing the lockstep-chunk padding columns)."""
+        return self.decode_window_paged(params, x_w, pool, tables,
+                                        positions, block_size,
+                                        limits=limits)
 
-    def decode_window_paged(self, params, x_w, pool, slots, positions,
-                            limits=None):
+    def decode_window_paged(self, params, x_w, pool, tables, positions,
+                            block_size, limits=None):
         """W autoregressive steps in ONE call: ``x_w`` (B, W, H) are the
-        window tokens' hidden states at per-row ``positions`` (B, W).
-        Writes the window's K/V at each token's slot, then attends every
-        window query over ``k_pos <= position`` through the page table —
-        W=1 is the plain paged decode step; W>1 is the speculative-decode
-        verify window (each query attends the window tokens before it plus
-        the whole committed prefix, exactly the sequential-step semantics).
-        ``limits`` (B,): each stream's last valid position — writes past it
-        (a finished row riding a still-decoding batch, or a verify window
-        overhanging a stream's final token) redirect to the trash block so
-        they can never clobber a live slot. Returns (out (B, W, H), pool)."""
-        b, w, _ = x_w.shape
+        window tokens' hidden states at per-row ``positions`` (B, W),
+        ``tables`` (B, max_blocks) the streams' page tables over blocks
+        of ``block_size`` slots. Writes the window's K/V at each token's
+        slot, then attends every window query over ``k_pos <= position``
+        through the page table — W=1 is the plain paged decode step; W>1
+        is the speculative-decode verify window (each query attends the
+        window tokens before it plus the whole committed prefix, exactly
+        the sequential-step semantics). ``limits`` (B,): each stream's
+        last valid position — writes past it (a finished row riding a
+        still-decoding batch, or a verify window overhanging a stream's
+        final token) redirect to the trash block so they can never
+        clobber a live slot. Returns (out (B, W, H), pool)."""
         q, k, v = self._qkv(params, self._attn_input(params, x_w))
-        wslots = jnp.take_along_axis(slots, positions, axis=1)  # (B, W)
+        wslots = attn_ops.paged_slots(tables, positions, block_size)
         if limits is not None:
             wslots = jnp.where(positions <= limits[:, None], wslots, 0)
-        rows = jnp.transpose(k, (0, 2, 1, 3)).reshape(b * w, self.n_heads, -1)
-        vrows = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * w, self.n_heads, -1)
-        pool = self._pool_write(pool, wslots.reshape(-1), rows, vrows)
-        o = attn_ops.paged_attention(q, pool["k"], pool["v"], slots,
-                                     positions)
+        pool = self._pool_write(pool, wslots.reshape(-1), k, v)
+        o = attn_ops.paged_attention(q, pool["k"], pool["v"], tables,
+                                     positions, block_size)
         return self._finish(params, x_w, self._proj_out(params, o)), pool
 
     def decode_step(self, params, x_t, cache, positions):

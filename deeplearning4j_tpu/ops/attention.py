@@ -35,18 +35,21 @@ _NEG_BIG = -1e30
 
 
 def online_softmax_update(q, k, v, m, l, acc, scale, q_pos=None, k_pos=None,
-                          kv_mask=None):
+                          kv_mask=None, qk="...qd,...kd->...qk",
+                          pv="...qk,...kd->...qd"):
     """One online-softmax block update (the flash-attention inner step).
 
     q: [..., sq, d]; k/v: [..., bk, d]; m/l: [..., sq] f32; acc: [..., sq, d]
     f32. If q_pos/k_pos are given, applies the causal mask k_pos <= q_pos.
     ``kv_mask``: optional per-key padding mask broadcastable to s's
-    [..., sq, bk] (1/True = attend). Shared by the blockwise-scan forward
-    and the ring-attention body so the numerically subtle m/l/acc
-    correction exists exactly once.
+    [..., sq, bk] (1/True = attend). ``qk``/``pv`` are the two contractions
+    as einsum specs, for a caller whose K/V block lies in another layout
+    (the paged pool's [b, k, h, d]) and must not be transposed to this
+    one; s, m, l and acc keep the layout above. Shared by the
+    blockwise-scan forward, the ring-attention body and the paged decode
+    pass so the numerically subtle m/l/acc correction exists exactly once.
     """
-    s = jnp.einsum("...qd,...kd->...qk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+    s = jnp.einsum(qk, q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     causal = q_pos is not None
     if causal:
         s = jnp.where(k_pos[None, :] <= q_pos[:, None], s, _NEG_BIG)
@@ -61,7 +64,7 @@ def online_softmax_update(q, k, v, m, l, acc, scale, q_pos=None, k_pos=None,
         p = jnp.where(s <= _NEG_BIG / 2, 0.0, p)
     l_new = corr * l + jnp.sum(p, axis=-1)
     acc_new = acc * corr[..., None] + jnp.einsum(
-        "...qk,...kd->...qd", p, v.astype(jnp.float32))
+        pv, p, v.astype(jnp.float32))
     return m_new, l_new, acc_new
 
 
@@ -508,43 +511,97 @@ def multi_head_dot_product_attention(
 # Paged KV-cache attention (serving/paged.py substrate)
 # ---------------------------------------------------------------------------
 
-
-def paged_kv_gather(pool, slots):
-    """Gather per-stream K or V rows out of a slot-flat block pool.
-
-    ``pool``: (S, H, Dh) — every block's token slots for ONE layer,
-    flattened to ``S = num_blocks * block_size`` rows (block b's tokens
-    live at slots ``[b*block_size, (b+1)*block_size)``). ``slots``:
-    (B, L) int32 — each stream's page table expanded to a flat slot index
-    per logical position (unallocated positions point into the reserved
-    trash block; the caller's position mask keeps them out of every
-    softmax). Returns (B, H, L, Dh) — the same logical [batch, heads,
-    positions, head_dim] layout a contiguous cache holds, so the exact
-    attention math downstream is IDENTICAL to the contiguous path
-    (the paged==contiguous token-identity contract, docs/SERVING.md)."""
-    return jnp.transpose(pool[slots], (0, 2, 1, 3))
+#: K/V rows (streams x positions) one turn of :func:`paged_attention`
+#: gathers, and the fewest positions a turn covers. Measured on a v5e
+#: (PERF.md, PR 28): a gathered row costs about 30 ns up to 8,192 rows a
+#: turn and twice that at 16,384; a turn costs some 10 us of its own; and a
+#: shorter chunk stops nearer the longest stream's end. 2,048 rows (64
+#: positions at 32 streams, 256 at 8) is within a tenth of the best chunk
+#: on chat and on document traffic at both batch sizes.
+PAGED_CHUNK_ROWS = 2048
+PAGED_CHUNK_MIN_POSITIONS = 64
 
 
-def paged_attention(q, k_pool, v_pool, slots, positions, scale=None):
-    """One decode/verify attention over a paged KV pool.
+def paged_chunk_blocks(batch: int, max_blocks: int, block_size: int) -> int:
+    """Whole blocks of the page table one turn of :func:`paged_attention`
+    reads per stream: about ``PAGED_CHUNK_ROWS`` gathered rows a turn over
+    the batch, at least ``PAGED_CHUNK_MIN_POSITIONS`` positions, at most the
+    table. From static shapes only, so it is the same number inside the
+    traced program and on the host that counts what a step read."""
+    positions = max(PAGED_CHUNK_ROWS // int(batch), PAGED_CHUNK_MIN_POSITIONS)
+    return max(1, min(positions // int(block_size), int(max_blocks)))
+
+
+def paged_slots(tables, positions, block_size: int):
+    """Flat pool slot of each logical position: ``tables`` (B, max_blocks)
+    int32 page tables, ``positions`` (B, W) int32 -> (B, W) int32,
+    ``tables[b, p // bs] * bs + p % bs``. A position past the table lands
+    in the reserved trash block 0."""
+    blk = jnp.take_along_axis(tables, positions // block_size, axis=1,
+                              mode="fill", fill_value=0)
+    return blk * block_size + positions % block_size
+
+
+def paged_attention(q, k_pool, v_pool, tables, positions, block_size: int,
+                    scale=None):
+    """One decode/verify attention over a paged KV pool, read as far as
+    the streams reach.
 
     ``q``: (B, H, W, Dh) — W query tokens per stream (1 for plain decode,
     the speculation window for verify, a prompt chunk for resumed /
-    chunked prefill). ``positions``: (B, W) int32 — the logical position
-    of each query token; key position ``p`` is attended iff
-    ``p <= positions[b, w]`` (the causal-over-cache rule, identical to
-    the contiguous ``decode_step``). Gathers via :func:`paged_kv_gather`
-    and runs the exact :func:`dot_product_attention` — softmax inputs for
-    every unmasked position are bit-identical to the contiguous path.
+    chunked prefill). ``k_pool``/``v_pool``: (S, H*Dh) slot-flat pools of
+    one layer, one token's heads side by side in a row, block ``n``'s
+    tokens at slots ``[n * bs, (n + 1) * bs)``.
+    ``tables``: (B, max_blocks) int32 page tables (unallocated entries
+    point at the trash block 0). ``positions``: (B, W) int32 — the logical
+    position of each query token; key position ``p`` is attended iff
+    ``p <= positions[b, w]`` (the causal-over-cache rule, identical to the
+    contiguous ``decode_step``).
 
-    Shared-prefix note (serving/paged.py): ``slots`` may map SEVERAL
-    streams' tables onto the same physical blocks (a refcounted prefix-
-    cache hit). The gather is read-only and position-masked per stream,
-    so sharing is invisible here — K/V rows at position ``p`` are a pure
-    function of the token prefix up to ``p``, which is exactly what made
-    the blocks shareable."""
-    kk = paged_kv_gather(k_pool, slots)
-    vv = paged_kv_gather(v_pool, slots)
-    amask = (jnp.arange(kk.shape[2])[None, None, :]
-             <= positions[:, :, None])[:, None]  # (B, 1, W, L)
-    return dot_product_attention(q, kk, vv, mask=amask, scale=scale)
+    The pass walks the table in chunks of :func:`paged_chunk_blocks` whole
+    blocks and stops after the chunk that holds the largest position of
+    the batch: a ``fori_loop`` whose trip count is data, so ONE program
+    serves every context length and reads what the longest stream holds,
+    not the declared maximum. Each turn gathers whole blocks, contracts
+    ``q`` against them in the pool's own [b, k, h, d] layout and folds
+    them into a float32 running (max, sum, accumulator) through
+    :func:`online_softmax_update`. Every live key is attended in float32;
+    against the dense softmax over all positions only the order of the
+    sums differs (tokens identical, logits to rounding: docs/SERVING.md).
+
+    Shared-prefix note (serving/paged.py): several rows of ``tables`` may
+    hold the same physical blocks (a refcounted prefix-cache hit). The
+    gather is read-only and position-masked per stream, so sharing is
+    invisible here — K/V rows at position ``p`` are a pure function of
+    the token prefix up to ``p``, which is what made the blocks
+    shareable."""
+    b, h, w, dh = q.shape
+    bs = int(block_size)
+    width = tables.shape[1]
+    cb = paged_chunk_blocks(b, width, bs)
+    chunk = cb * bs
+    n_chunks = -(-width // cb)
+    tables = jnp.pad(tables, ((0, 0), (0, n_chunks * cb - width)))  # trash
+    kb = jnp.asarray(k_pool).reshape(-1, bs, h * dh)  # whole blocks
+    vb = jnp.asarray(v_pool).reshape(-1, bs, h * dh)
+    if scale is None:
+        scale = 1.0 / (dh ** 0.5)
+    qf = jnp.asarray(q, jnp.float32)
+    turns = jnp.minimum(jnp.max(positions) // chunk + 1, n_chunks)
+
+    def turn(i, carry):
+        t = lax.dynamic_slice_in_dim(tables, i * cb, cb, axis=1)  # (B, cb)
+        kc = kb[t].reshape(b, chunk, h, dh)
+        vc = vb[t].reshape(b, chunk, h, dh)
+        k_pos = i * chunk + jnp.arange(chunk)
+        live = (k_pos[None, None, :] <= positions[:, :, None])[:, None]
+        return online_softmax_update(qf, kc, vc, *carry, scale, kv_mask=live,
+                                     qk="bhqd,bkhd->bhqk",
+                                     pv="bhqk,bkhd->bhqd")
+
+    m0 = jnp.full((b, h, w), _NEG_BIG, jnp.float32)
+    l0 = jnp.zeros((b, h, w), jnp.float32)
+    a0 = jnp.zeros((b, h, w, dh), jnp.float32)
+    _, l, acc = lax.fori_loop(0, turns, turn, (m0, l0, a0))
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    return (acc / safe_l[..., None]).astype(q.dtype)

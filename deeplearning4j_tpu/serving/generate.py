@@ -9,12 +9,13 @@ by compile-once executables:
 - **prefill** — one causal forward over the whole prompt. Prompt lengths
   round up to ``seq_buckets``; the prompt's K/V scatter into the paged
   block pool through each stream's page table (serving/paged.py).
-- **decode_step** — one token per call over the page table: gather the
-  stream's K/V rows out of the slot-flat pool, attend ``k_pos <=
-  position``, scatter the new token's K/V at its slot. The page table is
-  DATA, not shape, so ONE executable (per batch bucket) serves every mix
-  of context lengths with zero steady-state recompiles — and the pool is
-  shared, so memory scales with actual tokens, not ``streams ×
+- **decode_step** — one token per call over the page table: scatter the
+  new token's K/V at its slot, then attend ``k_pos <= position`` in one
+  block-chunked pass that stops where the batch's longest stream ends
+  (ops/attention.paged_attention). The page table and the trip count
+  are DATA, not shape, so ONE executable (per batch bucket) serves every
+  mix of context lengths with zero steady-state recompiles — and the
+  pool is shared, so memory scales with actual tokens, not ``streams ×
   max_length`` (the ``concurrent_streams_per_device`` headline).
 - **verify** — the speculative-decoding window: a small DRAFT net
   (``Bert(causal=True)`` tiny, loaded per-model via the router) proposes
@@ -62,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.data.bucketing import BucketingPolicy
+from deeplearning4j_tpu.ops import attention as attn_ops
 from deeplearning4j_tpu.serving.paged import (BlockPool, PoolExhaustedError,
                                               PrefixCache,
                                               default_pool_blocks)
@@ -185,6 +187,9 @@ class Generator:
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
         if self.prefill_chunk is not None and self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
+        #: KV positions the decode/verify steps read, and what they would
+        #: read at the declared max_length (pool_stats, _count_kv_read)
+        self._kv_read = self._kv_declared = 0
         #: nesting depth of generate() — > 1 while a chunk-yield runs a
         #: nested decode batch; nested runs never grow/reset the pool
         self._depth = 0
@@ -261,16 +266,6 @@ class Generator:
         logits = self.head._logits(params[-1], x[:, 0])
         return logits, new_caches
 
-    def _slots_of(self, tables):
-        """Page tables (B, max_blocks) → per-position flat slot indices
-        (B, max_length). Sliced to EXACTLY max_length so the gathered
-        layout — and therefore every attention reduction — has the same
-        shape as the contiguous cache (the bit-level identity argument,
-        ops/attention.paged_kv_gather)."""
-        bs = self.block_size
-        s = tables[:, :, None] * bs + jnp.arange(bs)[None, None, :]
-        return s.reshape(tables.shape[0], -1)[:, :self.max_length]
-
     def _prefill_paged(self, raw, pools, tokens, lengths, tables):
         """Paged prefill: same causal forward as ``_prefill`` (the prompt
         attention runs over in-register K/V, so the logits are identical),
@@ -281,7 +276,8 @@ class Generator:
         x, _ = self.emb.apply(params[0], {}, tokens)
         pad_mask = (jnp.arange(t)[None, :]
                     < lengths[:, None]).astype(x.dtype)
-        slots = self._slots_of(tables)[:, :t]
+        slots = attn_ops.paged_slots(
+            tables, jnp.broadcast_to(jnp.arange(t), (b, t)), self.block_size)
         new_pools = []
         for i, blk in enumerate(self.blocks):
             x, pool = blk.prefill_paged(params[i + 1], x, pools[i], slots,
@@ -299,12 +295,12 @@ class Generator:
         note_trace("serving.decode_step_paged", tokens, positions)
         params = self._params_of(raw)
         x = self.emb.embed_step(params[0], tokens, positions)[:, None, :]
-        slots = self._slots_of(tables)
         pos_w = positions[:, None]
         new_pools = []
         for i, blk in enumerate(self.blocks):
             x, pool = blk.decode_window_paged(params[i + 1], x, pools[i],
-                                              slots, pos_w, limits=limits)
+                                              tables, pos_w, self.block_size,
+                                              limits=limits)
             new_pools.append(pool)
         logits = self.head._logits(params[-1], x[:, 0])
         return logits, new_pools
@@ -320,11 +316,11 @@ class Generator:
         w = window.shape[1]
         pos_w = positions0[:, None] + jnp.arange(w)[None, :]
         x = self.emb.embed_window(params[0], window, pos_w)
-        slots = self._slots_of(tables)
         new_pools = []
         for i, blk in enumerate(self.blocks):
             x, pool = blk.decode_window_paged(params[i + 1], x, pools[i],
-                                              slots, pos_w, limits=limits)
+                                              tables, pos_w, self.block_size,
+                                              limits=limits)
             new_pools.append(pool)
         logits = self.head._logits(params[-1], x)
         return logits, new_pools
@@ -336,7 +332,8 @@ class Generator:
         each row starts at its own cache-resume point — write-then-attend
         through the page table (``nn/transformer.py``
         ``prefill_resume_paged``), exactly the verify-window semantics,
-        so chunked/resumed prefill is bit-identical to whole prefill.
+        so chunked/resumed prefill gives whole prefill's tokens (its logits
+        to the rounding of a float32 sum).
         ``limits`` (B,) = last prompt position (overrun/padding columns
         scatter to trash); ``last_idx`` (B,) selects each row's final-
         prompt-position column for the next-token logits (garbage for
@@ -351,11 +348,12 @@ class Generator:
         # write, never read back, but the gathers need in-range indices
         pos_w = jnp.minimum(positions, self.max_length - 1)
         x = self.emb.embed_window(params[0], window, pos_w)
-        slots = self._slots_of(tables)
         new_pools = []
         for i, blk in enumerate(self.blocks):
             x, pool = blk.prefill_resume_paged(params[i + 1], x, pools[i],
-                                               slots, pos_w, limits=limits)
+                                               tables, pos_w,
+                                               self.block_size,
+                                               limits=limits)
             new_pools.append(pool)
         b = window.shape[0]
         h_last = x[jnp.arange(b), last_idx]
@@ -738,6 +736,29 @@ class Generator:
         logits = jnp.stack([final[i] for i in range(batch)])
         return logits, n_chunks
 
+    def _kv_positions_read(self, batch: int, last_pos: int) -> int:
+        """KV positions one paged step reads per K and V pool of a layer
+        when its largest query position is ``last_pos``: batch x chunk x
+        turns, the trip count ``ops/attention.paged_attention`` takes from
+        the positions, worked out here from the same static shapes."""
+        bs = self.block_size
+        width = self.pool.max_blocks_per_stream
+        cb = attn_ops.paged_chunk_blocks(batch, width, bs)
+        turns = min(last_pos // (cb * bs) + 1, -(-width // cb))
+        return batch * cb * bs * turns
+
+    def _count_kv_read(self, batch: int, read: int, steps: int):
+        """One batch's decode/verify steps onto the counters: what they
+        read against ``steps`` x batch x max_length, what the gather of the
+        declared length read at every step."""
+        declared = steps * batch * self.max_length
+        self._kv_read += read
+        self._kv_declared += declared
+        tm.counter("serving.decode_kv_positions_read_total", read,
+                   model=self.model_id)
+        tm.counter("serving.decode_kv_positions_declared_total", declared,
+                   model=self.model_id)
+
     def _generate_paged(self, tokens, lengths, tables, b_real, lens,
                         max_new: int, *, temperature: float, key,
                         eos_id: Optional[int], trace: bool, stats=None,
@@ -758,6 +779,8 @@ class Generator:
                                    lens, starts, cow, pending, tele,
                                    stats, yield_hook)
         positions = lengths
+        longest = max(lens)  # the batch's largest position, step 0
+        kv_read = 0
         steps = []
         done = np.zeros(b_real, bool)
         key, sub = jax.random.split(key)
@@ -774,12 +797,14 @@ class Generator:
             logits, pools = self._decode_paged_jit(
                 raw, self.pool.pools, tables, cur, positions, limits)
             self.pool.pools = pools
+            kv_read += self._kv_positions_read(batch, longest + i)
             if tele:
                 tele.event_deferred("serving.generate.decode_token", t_dt,
                                     time.time_ns(), step=i + 1, batch=batch)
             positions = positions + 1
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
+        self._count_kv_read(batch, kv_read, len(steps) - 1)
         stacked = np.stack([np.asarray(s) for s in steps], axis=1)
         return self._trim(stacked, b_real, lens, max_new, eos_id)
 
@@ -821,7 +846,7 @@ class Generator:
             if eos_id is not None and int(host_cur[i]) == eos_id:
                 done[i] = True
 
-        rounds = 0
+        rounds = kv_read = 0
         while not done.all() and any(len(emitted[i]) < max_new
                                      for i in range(b_real)
                                      if not done[i]):
@@ -850,6 +875,8 @@ class Generator:
             glogits, pools = self._verify_paged_jit(
                 raw, self.pool.pools, tables, window, positions, limits)
             self.pool.pools = pools
+            kv_read += self._kv_positions_read(
+                batch, min(int(pos_np.max()), self.max_length - 1) + w - 1)
             g = np.asarray(jnp.argmax(glogits, axis=-1))  # (B, w) host
             win = np.asarray(window)
             # accept the longest prefix the draft got right: window[j] is
@@ -886,6 +913,7 @@ class Generator:
             cur = jnp.asarray(new_cur.astype(np.int32))
             prev = jnp.asarray(new_prev.astype(np.int32))
             pos_np = pos_np + m
+        self._count_kv_read(batch, kv_read, rounds)
         if stats is not None:
             rates = [
                 (float(accept_num[i] / accept_den[i])
@@ -1101,6 +1129,10 @@ class Generator:
         if self.pool is None:
             return None
         s = self.pool.stats()
+        # share of the declared max_length the decode steps read so far
+        s["decode_kv_read_share"] = (
+            round(self._kv_read / self._kv_declared, 4)
+            if self._kv_declared else None)
         if self.cache is not None:
             s["prefix_cache"] = self.cache.stats()
         if self.prefill_chunk is not None:

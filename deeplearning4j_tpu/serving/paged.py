@@ -6,7 +6,7 @@ memory no matter how short its context, and the ceiling on concurrent
 streams per device was ``pool_bytes / (max_length * per_token_bytes)``.
 This module replaces that with the vLLM-style paged layout:
 
-- **One slot-flat pool per layer** — ``(S, H, Dh)`` with
+- **One slot-flat pool per layer** — ``(S, H*Dh)`` with
   ``S = (num_blocks + 1) * block_size`` token slots. Block 0 is the
   RESERVED TRASH BLOCK: every position outside a stream's reservation
   (bucket padding, padded batch rows) scatters there and every read is
@@ -14,9 +14,10 @@ This module replaces that with the vLLM-style paged layout:
 - **A page table per stream** — the host-side list of physical block ids
   backing logical positions ``[0, ceil((len + max_new) / block_size) *
   block_size)``. The decode executable takes the table as data
-  ``(B, max_blocks)`` and expands it to per-position slot indices in-jit,
-  so ONE executable (per batch bucket) serves every mix of context
-  lengths with zero recompiles — context length is a value, not a shape.
+  ``(B, max_blocks)`` and walks it in chunks of whole blocks as far as
+  the batch's longest stream reaches, so ONE executable (per batch
+  bucket) serves every mix of context lengths with zero recompiles —
+  context length is a value, not a shape.
 - **All-or-nothing admission** — :meth:`BlockPool.reserve` either hands a
   batch every block its streams need for the WHOLE generation (prompt +
   ``max_new_tokens``, so a stream can never run out mid-decode) or raises
@@ -74,7 +75,7 @@ class BlockPool:
 
     ``num_blocks`` usable blocks of ``block_size`` token slots each; the
     device tensors carry one extra (trash) block at index 0. Device state
-    lives in ``self.pools`` — one ``{"k": (S,H,Dh), "v": (S,H,Dh)}`` per
+    lives in ``self.pools`` — one ``{"k": (S,H*Dh), "v": (S,H*Dh)}`` per
     transformer layer, created by the blocks' ``init_pool`` and donated
     through the decode executables (the generator threads the returned
     pools back). Allocation is REFCOUNTED: ``reserve`` hands out blocks

@@ -12,6 +12,7 @@ ONE decode executable serving mixed context lengths with 0 steady-state
 recompiles."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,8 @@ import pytest
 from deeplearning4j_tpu.serving import (BatchScheduler, Generator,
                                         INT8_LOGIT_TOL, ModelRouter,
                                         PoolExhaustedError, ServingModel)
+from deeplearning4j_tpu.ops import attention as attn_ops
+from deeplearning4j_tpu.util import telemetry as tm
 from deeplearning4j_tpu.util.compile_watcher import get_watcher
 from deeplearning4j_tpu.util.model_serializer import ModelSerializer
 from deeplearning4j_tpu.zoo.bert import Bert
@@ -117,6 +120,191 @@ class TestPagedIdentity:
         want = ref[0][:ref[0].index(eos) + 1]
         assert out[0] == want
         assert gen_paged.pool.free_blocks() == gen_paged.pool.num_blocks
+
+
+# --------------------------------------------------------------------------
+# The block-chunked pass itself (ISSUE 28): ops/attention.paged_attention
+# against the dense masked softmax over every declared position.
+
+A_B, A_H, A_DH, A_BS = 32, 2, 8, 16
+A_WIDTH = 18                      # table blocks: 288 positions
+A_CHUNK = A_BS * attn_ops.paged_chunk_blocks(A_B, A_WIDTH, A_BS)
+
+
+def _paged_case(w, max_pos, seed=0):
+    """Random logical K/V (B, L, H*Dh) scattered into a slot-flat pool
+    through page tables in shuffled physical order; rows 0 and 1 SHARE
+    their first three blocks (a prefix-cache hit). Query positions are
+    ragged below ``max_pos``, row 2 holds ``max_pos`` itself."""
+    rng = np.random.default_rng(seed)
+    length = A_WIDTH * A_BS
+    hd = A_H * A_DH
+    k = rng.normal(size=(A_B, length, hd)).astype(np.float32)
+    v = rng.normal(size=(A_B, length, hd)).astype(np.float32)
+    k[1, :3 * A_BS], v[1, :3 * A_BS] = k[0, :3 * A_BS], v[0, :3 * A_BS]
+    ids = rng.permutation(np.arange(1, A_B * A_WIDTH + 1))
+    tables = ids.reshape(A_B, A_WIDTH).astype(np.int32)
+    tables[1, :3] = tables[0, :3]
+    pool_k = np.zeros(((A_B * A_WIDTH + 1) * A_BS, hd), np.float32)
+    pool_v = np.zeros_like(pool_k)
+    for b in range(A_B):
+        for j in range(A_WIDTH):
+            rows = slice(tables[b, j] * A_BS, (tables[b, j] + 1) * A_BS)
+            pool_k[rows] = k[b, j * A_BS:(j + 1) * A_BS]
+            pool_v[rows] = v[b, j * A_BS:(j + 1) * A_BS]
+    last = rng.integers(w - 1, max_pos, size=A_B)
+    last[2] = max_pos
+    positions = (last[:, None] - (w - 1) + np.arange(w)[None, :]).astype(
+        np.int32)
+    q = rng.normal(size=(A_B, A_H, w, A_DH)).astype(np.float32)
+    return q, k, v, pool_k, pool_v, tables, positions
+
+
+def _dense_reference(q, k, v, positions):
+    """The parent's attention: every declared position gathered into
+    (B, H, L, Dh), one masked softmax over all of them."""
+    split = lambda y: jnp.transpose(
+        jnp.asarray(y).reshape(A_B, -1, A_H, A_DH), (0, 2, 1, 3))
+    amask = (jnp.arange(k.shape[1])[None, None, :]
+             <= positions[:, :, None])[:, None]
+    return attn_ops.dot_product_attention(jnp.asarray(q), split(k),
+                                          split(v), mask=amask)
+
+
+class TestChunkedPagedAttention:
+    def test_case_shape(self):
+        """What the cases below rely on: several turns, and a table that
+        is no multiple of the chunk (its padding is the trash block)."""
+        assert A_CHUNK == 64
+        assert (A_WIDTH * A_BS) % A_CHUNK != 0
+
+    @pytest.mark.parametrize("w", [1, 4])
+    @pytest.mark.parametrize("max_pos", [40, 150, 287])
+    def test_matches_dense_masked_reference(self, w, max_pos):
+        """Ragged positions, W = 1 (decode) and 4 (verify window), one to
+        five turns, two rows sharing blocks: within 1e-5 of the dense
+        masked softmax over every declared position."""
+        q, k, v, pk, pv, tables, pos = _paged_case(w, max_pos)
+        got = attn_ops.paged_attention(q, pk, pv, tables, pos, A_BS)
+        want = _dense_reference(q, k, v, pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("w", [1, 4])
+    def test_skipping_is_real(self, w):
+        """K/V past the last live chunk are never read: NaN there leaves
+        the output finite and EQUAL, where the dense pass turns NaN x 0
+        into NaN. A NaN inside a live position still shows."""
+        max_pos = 100                                   # two turns of 64
+        q, k, v, pk, pv, tables, pos = _paged_case(w, max_pos, seed=1)
+        clean = np.asarray(attn_ops.paged_attention(q, pk, pv, tables, pos,
+                                                    A_BS))
+        first_dead = 2 * A_CHUNK // A_BS                # block column 8
+        dead = tables[:, first_dead:].reshape(-1)
+        pk2, pv2 = pk.copy(), pv.copy()
+        for blk in dead:
+            pk2[blk * A_BS:(blk + 1) * A_BS] = np.nan
+            pv2[blk * A_BS:(blk + 1) * A_BS] = np.nan
+        got = np.asarray(attn_ops.paged_attention(q, pk2, pv2, tables, pos,
+                                                  A_BS))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, clean)
+        k2, v2 = k.copy(), v.copy()
+        k2[:, 2 * A_CHUNK:], v2[:, 2 * A_CHUNK:] = np.nan, np.nan
+        assert np.isnan(np.asarray(_dense_reference(q, k2, v2, pos))).any()
+        # a live position: row 2's key at position 5
+        slot = tables[2, 0] * A_BS + 5
+        pk3 = pk.copy()
+        pk3[slot] = np.nan
+        hit = np.asarray(attn_ops.paged_attention(q, pk3, pv, tables, pos,
+                                                  A_BS))
+        assert np.isnan(hit[2]).all()
+        assert np.isfinite(hit[3:]).all()
+
+    def test_slots_past_the_table_land_in_trash(self):
+        tables = jnp.asarray([[3, 7]], jnp.int32)
+        pos = jnp.asarray([[0, 5, 9, 40]], jnp.int32)
+        got = attn_ops.paged_slots(tables, pos, 4)
+        assert got.tolist() == [[12, 29, 1, 0]]         # 9 -> blk 2: trash
+
+
+# --------------------------------------------------------------------------
+# The same through the compiled decode step, at a size where the table is
+# four chunks wide.
+
+L_MAXLEN, L_BS, L_BATCH = 256, 16, 32
+
+
+@pytest.fixture(scope="module")
+def gen_long():
+    net = Bert.tiny(causal=True, task="mlm", vocab_size=VOCAB,
+                    max_length=L_MAXLEN, hidden_dropout=0.0).init()
+    return Generator(net, paged=True, block_size=L_BS,
+                     batch_buckets=(L_BATCH,), prefill_buckets=(8, 256),
+                     model_id="kv-read")
+
+
+def _kv_counters():
+    tele = tm.get_telemetry()
+    return [tele.counter_total(f"serving.decode_kv_positions_{n}_total",
+                               model="kv-read")
+            for n in ("read", "declared")]
+
+
+class TestDecodeReadsWhatStreamsHold:
+    def test_compiled_step_gathers_no_declared_length(self, gen_long):
+        """The compiled ``_decode_paged`` holds a loop and no array of
+        batch x max_length x H x Dh elements beside the pools it was
+        given: what a turn gathers is batch x chunk rows."""
+        b = L_BATCH
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+        tables = i32(b, gen_long.pool.max_blocks_per_stream)
+        text = gen_long._decode_paged_jit.lower(
+            gen_long._raw_params(), gen_long.pool.pools, tables, i32(b),
+            i32(b), i32(b)).compile().as_text()
+        assert re.search(r"\bwhile\(", text)
+        hidden = gen_long.blocks[0].hidden_size
+        pool_size = gen_long.pool.pools[0]["k"].size    # in any view
+        declared = b * L_MAXLEN * hidden
+        chunk = L_BS * attn_ops.paged_chunk_blocks(b, L_MAXLEN // L_BS, L_BS)
+        assert chunk == 64 < L_MAXLEN
+        sizes = []
+        for dims in re.findall(r"f32\[([0-9,]+)\]", text):
+            size = int(np.prod([int(d) for d in dims.split(",")]))
+            if size != pool_size:
+                sizes.append(size)
+        assert max(sizes) < declared
+        assert b * chunk * hidden in sizes          # one turn's gather
+
+    def test_counter_two_token_prompt_and_full_stream(self, gen_long):
+        """``decode_kv_read_share``: chunk / max_length for a two-token
+        prompt, 1.0 for a stream that ends at max_length."""
+        assert gen_long.pool_stats()["decode_kv_read_share"] is None
+        r0, d0 = _kv_counters()
+        gen_long.generate([[1, 2]], max_new_tokens=4)
+        r1, d1 = _kv_counters()
+        assert d1 - d0 == 3 * L_BATCH * L_MAXLEN         # 3 decode steps
+        assert (r1 - r0) / (d1 - d0) == 64 / L_MAXLEN
+        assert gen_long.pool_stats()["decode_kv_read_share"] == 0.25
+        long_prompt = [(i % (VOCAB - 1)) + 1 for i in range(L_MAXLEN - 6)]
+        out = gen_long.generate([long_prompt], max_new_tokens=6)
+        assert len(out[0]) == 6
+        r2, d2 = _kv_counters()
+        assert d2 - d1 == 5 * L_BATCH * L_MAXLEN
+        assert (r2 - r1) / (d2 - d1) == 1.0
+        share = gen_long.pool_stats()["decode_kv_read_share"]
+        assert share == round((3 * 64 + 5 * 256) / (8 * 256), 4)
+
+    def test_long_context_tokens_match_contiguous(self, gen_long):
+        """Four turns deep: the chunked pass still gives the contiguous
+        cache's tokens, ragged rows beside a long one."""
+        contiguous = Generator(gen_long.net, paged=False,
+                               batch_buckets=(4,),
+                               prefill_buckets=(8, 256))
+        prompts = [[(7 * i) % (VOCAB - 1) + 1 for i in range(200)],
+                   [3, 1, 4, 1, 5], [9] * 70]
+        ref = contiguous.generate(prompts, max_new_tokens=8)
+        assert gen_long.generate(prompts, max_new_tokens=8) == ref
 
 
 class TestSpeculative:
