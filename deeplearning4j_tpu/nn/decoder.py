@@ -1,0 +1,449 @@
+"""Pre-norm decoder layers for served language models: a token embedding
+without position tables, the residual block ``x += mixer(RMSNorm(x)); x +=
+ffn(RMSNorm(x))`` with a choice of mixers and feed-forwards, and a normed,
+untied logits head.
+
+Mixers (``mixer=``):
+
+- ``"kda"`` — Kimi Delta Attention (ops/kda.py): gated delta-rule linear
+  attention. Its cache is a STATE, one slot a stream: the (heads, dk, dv)
+  float32 matrix and the last ``conv_size - 1`` inputs of the short
+  convolutions. Whatever the context length, the state has one size.
+- ``"mla"`` — multi-head latent attention without positions: the cache is
+  one row ``[c_t | kr_t]`` a token for all heads (``kv_lora_rank +
+  qk_rope_dim`` numbers, in the parameters' type), behind the same page
+  tables as a per-head K/V cache. Prefill attends in the expanded form,
+  decode in the absorbed form over the paged rows
+  (ops/attention.latent_paged_attention).
+
+Feed-forwards (``ffn=``): ``"dense"`` gated SiLU, or ``"moe"``: a sigmoid
+router over ``n_experts`` with a selection bias, the weights of the chosen
+``top_k`` renormalised and scaled, one shared expert, and the routed experts
+HELD HERE (``n_local_experts`` from ``expert_offset``) through the grouped
+dispatch of nn/moe.py.
+
+Every block meets the serving block protocol of serving/generate.py
+(``cache_kind``, ``init_pool``, ``prefill_paged``, ``decode_window_paged``);
+``apply`` is the same mathematics without a cache. Numbers: parameters in
+their own type (bfloat16 when served), matrix products accumulate in
+float32, the residual stream, norms, softmax, router and KDA state are
+float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn import moe
+from deeplearning4j_tpu.nn.layers import Layer, register_layer
+from deeplearning4j_tpu.ops import attention as attn_ops
+from deeplearning4j_tpu.ops import kda
+
+F32 = jnp.float32
+
+
+def rms_norm(x, weight, eps: float):
+    """RMSNorm over the last axis in float32: x / rms(x) * weight."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * weight.astype(F32)
+
+
+def _mm(x, w):
+    """x @ w in the weights' type, accumulated and returned in float32."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=F32)
+
+
+def _normal(key, shape, std, dtype, mean=0.0):
+    return (mean + std * jax.random.normal(key, shape, F32)).astype(dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class TokenEmbeddingLayer(Layer):
+    """Token ids (B, T) -> float32 hidden states (B, T, H): a lookup, no
+    position or type tables (the mixers carry order)."""
+
+    vocab_size: int = 0
+    hidden_size: int = 0
+    init_range: float = 0.02
+    param_dtype: str = "float32"
+    #: what a served model may hold at most; 0 = no bound of its own
+    max_position: int = 0
+
+    def initialize(self, key, input_shape):
+        return {"word": _normal(key, (self.vocab_size, self.hidden_size),
+                                self.init_range, self.param_dtype)}, {}
+
+    def apply(self, params, state, x, *, training=False, key=None):
+        return self.embed_window(params, x, None), state
+
+    def embed_step(self, params, tokens, positions):
+        return self.embed_window(params, tokens, positions)
+
+    def embed_window(self, params, tokens, positions):
+        return jnp.take(params["word"], tokens.astype(jnp.int32),
+                        axis=0).astype(F32)
+
+    def output_shape(self, input_shape):
+        return (input_shape[0], self.hidden_size)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class NormedLogitsLayer(Layer):
+    """Final RMSNorm and an untied hidden x vocab head without bias; float32
+    logits (a bfloat16 logit near 8 has steps of 0.03)."""
+
+    n_in: int = 0
+    n_out: int = 0
+    eps: float = 1e-5
+    init_range: float = 0.02
+    param_dtype: str = "float32"
+
+    def initialize(self, key, input_shape):
+        return {"norm": jnp.ones((self.n_in,), self.param_dtype),
+                "W": _normal(key, (self.n_in, self.n_out), self.init_range,
+                             self.param_dtype)}, {}
+
+    def _logits(self, params, x):
+        return _mm(rms_norm(x, params["norm"], self.eps), params["W"])
+
+    def apply(self, params, state, x, *, training=False, key=None, mask=None):
+        return self._logits(params, x), state
+
+    def output_shape(self, input_shape):
+        return (input_shape[0], self.n_out)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class HybridDecoderBlock(Layer):
+    """One pre-norm residual block (module doc)."""
+
+    hidden_size: int = 0
+    mixer: str = "kda"            # "kda" | "mla"
+    ffn: str = "dense"            # "dense" | "moe"
+    n_heads: int = 1
+    eps: float = 1e-5
+    init_range: float = 0.02
+    param_dtype: str = "float32"
+    # kda
+    head_dim: int = 128           # dk = dv
+    conv_size: int = 4
+    gate_rank: int = 128          # the low-rank width of the two gates
+    # mla
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64         # carried unrotated (no positions)
+    v_head_dim: int = 128
+    # ffn
+    ffn_size: int = 0             # dense width, or one expert's
+    n_experts: int = 0            # the router's width
+    n_local_experts: int = 0      # held here (0 = all)
+    expert_offset: int = 0        # the first one held here
+    top_k: int = 1
+    routed_scale: float = 1.0
+    shared_size: int = 0          # the shared expert's width (0 = none)
+
+    causal = True
+
+    # ------------------------------------------------------------- protocol
+    @property
+    def cache_kind(self) -> str:
+        """``"state"``: one slot a stream; ``"tokens"``: one row a token
+        behind the page tables."""
+        return "state" if self.mixer == "kda" else "tokens"
+
+    @property
+    def _held(self) -> int:
+        return self.n_local_experts or self.n_experts
+
+    @property
+    def _inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def _row(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    # ----------------------------------------------------------- parameters
+    def initialize(self, key, input_shape):
+        hs, r, dt = self.hidden_size, self.init_range, self.param_dtype
+        ks = iter(jax.random.split(key, 32))
+        mat = lambda *shape: _normal(next(ks), shape, r, dt)
+        one = lambda n: _normal(next(ks), (n,), r, dt, mean=1.0)
+        p = {"norm1": one(hs), "norm2": one(hs)}
+        if self.mixer == "kda":
+            inner, h = self._inner, self.n_heads
+            for n in "qkv":
+                p["W" + n] = mat(hs, inner)
+                p["conv_" + n] = _normal(next(ks), (self.conv_size, inner),
+                                         self.conv_size ** -0.5, dt)
+            p.update(Wf1=mat(hs, self.gate_rank),
+                     Wf2=mat(self.gate_rank, inner),
+                     Wb=mat(hs, h), Wg1=mat(hs, self.gate_rank),
+                     Wg2=mat(self.gate_rank, inner),
+                     o_norm=one(self.head_dim), Wo=mat(inner, hs))
+            # decay rates 1..16 a head, step sizes 0.001..0.1 a channel
+            p["A_log"] = jnp.log(jax.random.uniform(
+                next(ks), (h,), F32, 1.0, 16.0)).astype(dt)
+            step = jnp.exp(jax.random.uniform(
+                next(ks), (inner,), F32, jnp.log(1e-3), jnp.log(1e-1)))
+            p["dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+        elif self.mixer == "mla":
+            h = self.n_heads
+            p.update(Wdkv=mat(hs, self._row), kv_norm=one(self.kv_lora_rank),
+                     Wukv=mat(self.kv_lora_rank,
+                              h * (self.qk_nope_dim + self.v_head_dim)),
+                     Wq=mat(hs, h * (self.qk_nope_dim + self.qk_rope_dim)),
+                     Wo=mat(h * self.v_head_dim, hs))
+        else:
+            raise ValueError(f"unknown mixer {self.mixer!r}")
+        f = self.ffn_size
+        if self.ffn == "dense":
+            p.update(Wgate=mat(hs, f), Wup=mat(hs, f), Wdown=mat(f, hs))
+        elif self.ffn == "moe":
+            e = self._held
+            p.update(router=mat(hs, self.n_experts),
+                     router_bias=_normal(next(ks), (self.n_experts,), 0.05,
+                                         dt),
+                     Egate=mat(e, hs, f), Eup=mat(e, hs, f),
+                     Edown=mat(e, f, hs))
+            if self.shared_size:
+                s = self.shared_size
+                p.update(Sgate=mat(hs, s), Sup=mat(hs, s), Sdown=mat(s, hs))
+        else:
+            raise ValueError(f"unknown ffn {self.ffn!r}")
+        return p, {}
+
+    # -------------------------------------------------------- feed-forwards
+    def _ffn(self, params, x, live=None):
+        """x (B, T, H) float32 -> (ffn(RMSNorm(x)), stats or None); ``live``
+        (B, T) bool marks the tokens a router may count and send."""
+        h = rms_norm(x, params["norm2"], self.eps)
+        gated = lambda g, u, d: _mm(jax.nn.silu(_mm(h, params[g]))
+                                    * _mm(h, params[u]), params[d])
+        if self.ffn == "dense":
+            return gated("Wgate", "Wup", "Wdown"), None
+        h2 = h.reshape(-1, self.hidden_size)
+        idx, w = moe.route_sigmoid_topk(h2, params["router"].astype(F32),
+                                        params["router_bias"], self.top_k,
+                                        self.routed_scale)
+        y, stats = moe.grouped_experts(
+            h2.astype(params["Egate"].dtype), idx, w, params["Egate"],
+            params["Eup"], params["Edown"], e_offset=self.expert_offset,
+            n_experts=self.n_experts,
+            live=None if live is None else live.reshape(-1))
+        y = y.reshape(x.shape)
+        if self.shared_size:
+            y = y + gated("Sgate", "Sup", "Sdown")
+        return y, stats
+
+    def _finish(self, params, x, a, pool, live=None, phase: int = 0):
+        """Both residual adds; a router's counts, and 1 for the call, go
+        onto row ``phase`` (0 prefill, 1 decode) of the pool's ``moe``
+        accumulator (wrapping int32: the host reads differences)."""
+        x = x + a
+        y, stats = self._ffn(params, x, live)
+        if stats is not None and "moe" in pool:
+            row = jnp.concatenate([stats, jnp.ones((1,), jnp.int32)])
+            pool = dict(pool, moe=pool["moe"].at[phase].add(row))
+        return x + y, pool
+
+    # ------------------------------------------------------------ KDA mixer
+    def _kda_inputs(self, params, h, tail):
+        """Normed input (B, T, H) and the convolutions' earlier inputs ->
+        q, k, v, g (B, T, heads, d), beta (B, T, heads), the output gate,
+        and the projections before the convolution (the next tail)."""
+        b, t, _ = h.shape
+        nh, d = self.n_heads, self.head_dim
+        raw = jnp.concatenate([_mm(h, params["W" + n]) for n in "qkv"], -1)
+        w = jnp.concatenate([params["conv_" + n] for n in "qkv"],
+                            -1).astype(F32)
+        q, k, v = jnp.split(jax.nn.silu(kda.causal_conv(raw, w, tail)), 3, -1)
+        heads = lambda a: a.reshape(b, t, nh, d)
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+        q, k, v = unit(heads(q)) * d ** -0.5, unit(heads(k)), heads(v)
+        f = _mm(_mm(h, params["Wf1"]), params["Wf2"]) \
+            + params["dt_bias"].astype(F32)
+        g = -jnp.exp(params["A_log"].astype(F32))[:, None] \
+            * heads(jax.nn.softplus(f))
+        beta = jax.nn.sigmoid(_mm(h, params["Wb"]))
+        gate = heads(jax.nn.sigmoid(_mm(_mm(h, params["Wg1"]),
+                                        params["Wg2"])))
+        return q, k, v, g, beta, gate, raw
+
+    def _kda_out(self, params, o, gate):
+        o = rms_norm(o, params["o_norm"], self.eps) * gate
+        return _mm(o.reshape(*o.shape[:2], self._inner), params["Wo"])
+
+    def _kda_prefill(self, params, x, mask):
+        """Whole prompts from an empty state -> (mixer output, final state,
+        the convolutions' tail at each row's length)."""
+        b, t, _ = x.shape
+        h = rms_norm(x, params["norm1"], self.eps)
+        q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, None)
+        live = mask.astype(F32)                     # padding moves no state
+        g, beta = g * live[..., None, None], beta * live[..., None]
+        s0 = jnp.zeros((b, self.n_heads, self.head_dim, self.head_dim), F32)
+        o, s = kda.kda_chunked(q, k, v, g, beta, s0)
+        lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
+        return (self._kda_out(params, o, gate), s,
+                kda.conv_tail(raw, lengths, self.conv_size - 1))
+
+    # ------------------------------------------------------------ MLA mixer
+    def _mla_rows(self, params, h):
+        """Normed input -> the cache rows [RMSNorm(c) | kr] (B, T, R)."""
+        ckr = _mm(h, params["Wdkv"])
+        c = rms_norm(ckr[..., :self.kv_lora_rank], params["kv_norm"],
+                     self.eps)
+        return jnp.concatenate([c, ckr[..., self.kv_lora_rank:]], -1)
+
+    def _mla_q(self, params, h):
+        b, t, _ = h.shape
+        return _mm(h, params["Wq"]).reshape(
+            b, t, self.n_heads, self.qk_nope_dim + self.qk_rope_dim)
+
+    @property
+    def _mla_scale(self) -> float:
+        return (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+
+    def _mla_expanded(self, params, h, mask, q_block: int = 256):
+        """Causal attention over a whole prompt in the expanded form, a
+        block of queries at a time (the score matrix of 16 x 1,024 x 32
+        heads would be 2 GB) -> (mixer output, cache rows)."""
+        b, t, _ = h.shape
+        nh, dn, dv = self.n_heads, self.qk_nope_dim, self.v_head_dim
+        rows = self._mla_rows(params, h)
+        c, kr = rows[..., :self.kv_lora_rank], rows[..., self.kv_lora_rank:]
+        kv = _mm(c, params["Wukv"]).reshape(b, t, nh, dn + dv)
+        dt = params["Wukv"].dtype
+        kc, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
+        q = self._mla_q(params, h).astype(dt)
+        kr = kr.astype(dt)
+        k_pos = jnp.arange(t)
+        keep = mask.astype(bool)[:, None, None, :]
+
+        def block(q0):
+            qb = jax.lax.dynamic_slice_in_dim(q, q0, q_block, axis=1)
+            s = jnp.einsum("bqhd,bkhd->bhqk", qb[..., :dn], kc,
+                           preferred_element_type=F32) \
+                + jnp.einsum("bqhd,bkd->bhqk", qb[..., dn:], kr,
+                             preferred_element_type=F32)
+            ok = (k_pos[None, :] <= (q0 + jnp.arange(q_block))[:, None]) & keep
+            p = jax.nn.softmax(jnp.where(ok, s * self._mla_scale, -1e30), -1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v,
+                              preferred_element_type=F32)
+
+        q_block = min(q_block, t)
+        pad = -t % q_block
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        o = jax.lax.map(block, jnp.arange(0, t + pad, q_block))
+        o = jnp.moveaxis(o, 0, 1).reshape(b, t + pad, nh * dv)[:, :t]
+        return _mm(o, params["Wo"]), rows
+
+    def _mla_absorbed(self, params, h, pool, tables, positions, block_size):
+        """Window queries against the paged rows in absorbed form: ``qc
+        W_uk^T`` meets ``c`` directly, and ``sum p c`` is expanded through
+        ``W_uv`` after the softmax."""
+        b, w, _ = h.shape
+        nh, dn, dv = self.n_heads, self.qk_nope_dim, self.v_head_dim
+        wukv = params["Wukv"].reshape(self.kv_lora_rank, nh, dn + dv)
+        q = self._mla_q(params, h)
+        q_abs = jnp.concatenate(
+            [jnp.einsum("bwhd,rhd->bwhr", q[..., :dn].astype(wukv.dtype),
+                        wukv[..., :dn], preferred_element_type=F32),
+             q[..., dn:]], axis=-1)                       # (B, W, heads, R)
+        ctx = attn_ops.latent_paged_attention(
+            jnp.moveaxis(q_abs, 1, 2), pool, tables, positions, block_size,
+            self.kv_lora_rank, self._mla_scale)           # (B, heads, W, r)
+        o = jnp.einsum("bhwr,rhd->bwhd", ctx.astype(wukv.dtype),
+                       wukv[..., dn:], preferred_element_type=F32)
+        return _mm(o.reshape(b, w, nh * dv), params["Wo"])
+
+    # ------------------------------------------------------------- no cache
+    def apply(self, params, state, x, *, training=False, key=None, mask=None):
+        if mask is None:
+            mask = jnp.ones(x.shape[:2], F32)
+        if self.mixer == "kda":
+            a, _, _ = self._kda_prefill(params, x, mask)
+        else:
+            a, _ = self._mla_expanded(
+                params, rms_norm(x, params["norm1"], self.eps), mask)
+        out, _ = self._finish(params, x.astype(F32), a, {},
+                              mask.astype(bool))
+        return out, state
+
+    # ---------------------------------------------------------- paged cache
+    def init_pool(self, num_slots: int):
+        """``cache_kind`` ``"state"``: ``num_slots`` stream slots of the KDA
+        state (float32) and the convolutions' tail; ``"tokens"``:
+        ``num_slots`` latent rows in the parameters' type. A routed
+        feed-forward adds its counters."""
+        if self.mixer == "kda":
+            pool = {"state": jnp.zeros((num_slots, self.n_heads,
+                                        self.head_dim, self.head_dim), F32),
+                    "conv": jnp.zeros((num_slots, self.conv_size - 1,
+                                       3 * self._inner), F32)}
+        else:
+            pool = {"rows": jnp.zeros((num_slots, self._row),
+                                      self.param_dtype)}
+        if self.ffn == "moe":
+            pool["moe"] = jnp.zeros((2, len(moe.MOE_STATS) + 1), jnp.int32)
+        return pool
+
+    def prefill_paged(self, params, x, pool, where, mask=None):
+        """Whole prompts (B, T, H). ``where`` is each stream's address in
+        this block's cache: flat token slots (B, T) for ``"tokens"``, the
+        stream's state slot (B,) for ``"state"`` (0 = the trash slot)."""
+        if self.mixer == "kda":
+            a, s, tail = self._kda_prefill(params, x, mask)
+            pool = dict(pool, state=pool["state"].at[where].set(s),
+                        conv=pool["conv"].at[where].set(tail))
+        else:
+            a, rows = self._mla_expanded(
+                params, rms_norm(x, params["norm1"], self.eps), mask)
+            pool = dict(pool, rows=pool["rows"].at[where.reshape(-1)].set(
+                rows.reshape(-1, self._row).astype(pool["rows"].dtype)))
+        return self._finish(params, x, a, pool, mask.astype(bool))
+
+    def decode_window_paged(self, params, x_w, pool, where, positions,
+                            block_size, limits=None):
+        """Window tokens (B, W, H) at ``positions`` (B, W). ``where``: the
+        page tables (B, max_blocks) for ``"tokens"``, the state slots (B,)
+        for ``"state"``. A token past its stream's ``limits`` writes no
+        cache row and moves no state."""
+        live = jnp.ones(positions.shape, bool) if limits is None \
+            else positions <= limits[:, None]
+        h = rms_norm(x_w, params["norm1"], self.eps)
+        if self.mixer == "kda":
+            before = pool["conv"][where]
+            q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, before)
+            m = live.astype(F32)
+            o, s = kda.kda_recurrent(q, k, v, g * m[..., None, None],
+                                     beta * m[..., None],
+                                     pool["state"][where])
+            width = self.conv_size - 1
+            seen = jnp.concatenate([before, raw], axis=1)
+            tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
+            pool = dict(pool, state=pool["state"].at[where].set(s),
+                        conv=pool["conv"].at[where].set(tail))
+            a = self._kda_out(params, o, gate)
+        else:
+            slots = attn_ops.paged_slots(where, positions, block_size)
+            slots = jnp.where(live, slots, 0)
+            rows = self._mla_rows(params, h)
+            pool = dict(pool, rows=pool["rows"].at[slots.reshape(-1)].set(
+                rows.reshape(-1, self._row).astype(pool["rows"].dtype)))
+            a = self._mla_absorbed(params, h, pool["rows"], where, positions,
+                                   block_size)
+        return self._finish(params, x_w, a, pool, live, phase=1)
+
+    def output_shape(self, input_shape):
+        return (input_shape[0], self.hidden_size)

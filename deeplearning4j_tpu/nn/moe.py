@@ -1,29 +1,32 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-Experts feed-forward layers with expert parallelism.
 
 The reference has no MoE and no expert parallelism (SURVEY.md §2.3: EP —
 "not required"); this is a TPU-native extension in the same spirit as ring
-attention: the strategies large models actually need, expressed as sharding
-over the mesh.
+attention: the strategies large models actually need.
 
-Design: top-k routed expert FFNs (Shazeer et al.; PAPERS.md). Dispatch is
-DENSE — every expert computes every token and the router's gate zeroes
-non-selected contributions:
+Two dispatches live here, for two uses.
 
-    y = sum_e gate_e(x) * FFN_e(x)
+**Grouped dispatch** (:func:`route_sigmoid_topk`, :func:`grouped_experts`) is
+the design for a layer of many experts, and what a served model runs: each
+token's (token, expert) picks are sorted by expert, the picks of the
+experts HELD HERE pass through one grouped matrix product per projection
+(``lax.ragged_dot``: on a TPU a native grouped kernel whose work is the
+rows it is given, not rows x experts), and the weighted results are added
+back to their tokens. The layer is told which slice of the experts it
+holds (``e_offset`` and the leading size of its expert matrices), routes
+over ALL of them, and adds nothing for the absent ones: that partial sum is
+one chip's share under expert parallelism, and the shares of all the chips
+add up to the uncut layer (tests/test_kimi_linear.py). The rows of a pass
+are bounded by twice the picks a uniform router would send here; a router
+that sends more is served by further passes of the same loop (its trip
+count is data), never by dropping a pick.
 
-Dense dispatch is deliberate: no capacity factors, no dynamic shapes, no
-sorting — everything stays jit-compilable with static shapes (XLA
-requirement), and under expert parallelism each device computes only ITS
-experts' partial sum, so compute still splits E-ways; the all-reduce of
-partial sums is the EP collective (the a2a-free formulation). For the
-expert counts the layer API targets (E ≤ ~32) this is the
-compile-friendliest formulation on TPU.
-
-``expert_parallel(...)`` runs the same layer as one GSPMD ``jit`` program
-with the expert-stacked params annotated ``NamedSharding`` over a mesh
-axis — numerically identical to the single-device layer (tested), with
-per-device expert compute 1/m of the total and the EP all-reduce inserted
-by the partitioner.
+**Dense dispatch** (:class:`MixtureOfExperts`): every expert computes every
+token and the gate zeroes what was not picked. Static shapes and no sort,
+which is what lets :func:`expert_parallel` hand the same layer to GSPMD with
+the expert axis sharded over a mesh; it costs experts/top_k times the work,
+so it is for the few experts (E <= ~32) of a trainable layer, not for a
+router of hundreds.
 """
 
 from __future__ import annotations
@@ -178,3 +181,83 @@ def _expert_parallel_program(layer: MixtureOfExperts, mesh: Mesh,
         return y.reshape(x.shape)
 
     return jax.jit(run, in_shardings=(pspec, rep))
+
+
+# ---------------------------------------------------------------------------
+# Grouped dispatch: many experts, a slice of them held here
+# ---------------------------------------------------------------------------
+
+#: what :func:`grouped_experts` counts, in this order
+MOE_STATS = ("picks", "picks_local", "experts_touched", "expert_load_max")
+
+
+def route_sigmoid_topk(x2d, router, bias, top_k: int, scale: float):
+    """Sigmoid router with a selection bias: ``s = sigmoid(x W_r)`` over ALL
+    experts in float32 at full precision (a pick is discrete: rounding the
+    product would move picks that the input does not); the ``top_k`` are the largest of ``s + bias`` (the
+    bias only selects); weights ``s_i / sum_k s * scale``. Returns (idx
+    (N, k) int32, weights (N, k) float32)."""
+    s = jax.nn.sigmoid(jnp.dot(x2d, router, precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
+
+
+def _pass_rows(n_pairs: int, n_local: int, n_experts: int) -> int:
+    """Rows of one grouped pass: twice what a uniform router sends to the
+    experts held here, in whole 128s, at most every pick."""
+    fair = -(-n_pairs * n_local // n_experts)
+    return min(n_pairs, max(128, -(-2 * fair // 128) * 128))
+
+
+def grouped_experts(x2d, idx, weights, w_gate, w_up, w_down, *,
+                    e_offset: int, n_experts: int, live=None):
+    """The gated-SiLU experts held here, applied to the picks that name
+    them: ``y_n = sum over picks (n, e) with e_offset <= e < e_offset + E_l
+    of w * W_down,e (SiLU(x W_gate,e) * x W_up,e)``.
+
+    ``x2d`` (N, H); ``idx``/``weights`` (N, k) from the router over all
+    ``n_experts``; ``w_gate``/``w_up`` (E_l, H, F), ``w_down`` (E_l, F, H).
+    ``live`` (N,) bool: tokens that count (padding and finished rows are
+    routed nowhere). Returns (y (N, H) float32, stats (4,) int32 as
+    :data:`MOE_STATS`: picks of live tokens, those that name an expert held
+    here, experts here with at least one pick, the most picks one expert
+    here got)."""
+    n, k = idx.shape
+    e_l = w_gate.shape[0]
+    local = (idx >= e_offset) & (idx < e_offset + e_l)
+    if live is not None:
+        local = local & live[:, None]
+    key = jnp.where(local, idx - e_offset, e_l).reshape(-1)      # (N*k,)
+    order = jnp.argsort(key).astype(jnp.int32)     # picks here first, by expert
+    counts = jnp.sum(jax.nn.one_hot(key, e_l + 1, dtype=jnp.int32),
+                     axis=0)[:e_l]                               # (E_l,)
+    ends = jnp.cumsum(counts)
+    n_here = ends[-1]
+    rows = _pass_rows(n * k, e_l, n_experts)
+    order = jnp.pad(order, (0, rows))              # the last pass may overhang
+    w_flat = weights.reshape(-1)
+
+    def one_pass(c, y):
+        lo = c * rows
+        pick = lax.dynamic_slice_in_dim(order, lo, rows)
+        tok = pick // k
+        sizes = jnp.clip(ends, lo, lo + rows) \
+            - jnp.clip(ends - counts, lo, lo + rows)
+        xs = x2d[tok]
+        dot = lambda a, b: lax.ragged_dot(
+            a, b, sizes, preferred_element_type=jnp.float32)
+        hid = (jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)).astype(
+            x2d.dtype)
+        out = dot(hid, w_down)
+        valid = lo + jnp.arange(rows) < n_here
+        out = jnp.where(valid[:, None], out * w_flat[pick][:, None], 0.0)
+        return y.at[tok].add(out)
+
+    y = lax.fori_loop(0, -(-n_here // rows), one_pass,
+                      jnp.zeros(x2d.shape, jnp.float32))
+    n_live = n if live is None else jnp.sum(live)
+    stats = jnp.stack([n_live * k, n_here, jnp.sum(counts > 0),
+                       jnp.max(counts)]).astype(jnp.int32)
+    return y, stats

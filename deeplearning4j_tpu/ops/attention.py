@@ -605,3 +605,25 @@ def paged_attention(q, k_pool, v_pool, tables, positions, block_size: int,
     _, l, acc = lax.fori_loop(0, turns, turn, (m0, l0, a0))
     safe_l = jnp.where(l == 0.0, 1.0, l)
     return (acc / safe_l[..., None]).astype(q.dtype)
+
+
+def latent_paged_attention(q_abs, pool, tables, positions, block_size: int,
+                           v_dim: int, scale: float):
+    """Decode attention of a latent (MLA) layer in absorbed form over its
+    paged latent rows.
+
+    ``pool``: (S, R) rows ``[c_t | kr_t]`` — the compressed key/value of a
+    token and its shared extra key dims, one row a token for ALL heads.
+    ``q_abs``: (B, H, W, R) queries already carried into that row space,
+    ``[qc_h W_uk,h^T | qr_h]``. Every head attends the same rows, so the
+    heads are W more queries of one head whose key is the row and whose
+    value is the row too: :func:`paged_attention` runs as it is, page table,
+    chunking, trip count and float32 online softmax shared with the
+    per-head K/V pools, and the first ``v_dim`` numbers of what it returns
+    are ``sum_t p_t c_t``, (B, H, W, v_dim). The caller expands that
+    through ``W_uv``. ``scale`` is the expanded form's (1/sqrt of the
+    per-head query width), not 1/sqrt(R)."""
+    b, h, w, r = q_abs.shape
+    o = paged_attention(q_abs.reshape(b, 1, h * w, r), pool, pool, tables,
+                        jnp.tile(positions, (1, h)), block_size, scale=scale)
+    return o.reshape(b, h, w, r)[..., :v_dim]

@@ -1,10 +1,13 @@
 """Planet-scale decode path: paged KV cache + speculative decoding + int8.
 
 The decode tier of the model server (docs/SERVING.md#paged-kv--speculative-
-decode): a decoder-only LM built from the native transformer layers
-(``BertEmbeddingLayer`` → ``TransformerEncoderBlock(causal=True)`` × N →
-``RnnOutputLayer``, e.g. ``zoo.bert.Bert(causal=True, task="mlm")``) served
-by compile-once executables:
+decode): a decoder-only LM as a ``MultiLayerNetwork`` of an embedding,
+decoder blocks and a per-token logits head — ``BertEmbeddingLayer`` →
+``TransformerEncoderBlock(causal=True)`` × N → ``RnnOutputLayer``
+(``zoo.bert.Bert(causal=True, task="mlm")``), or ``TokenEmbeddingLayer`` →
+``HybridDecoderBlock`` × N → ``NormedLogitsLayer`` (``zoo.KimiLinear``), or
+any layers that meet the BLOCK PROTOCOL below — served by compile-once
+executables:
 
 - **prefill** — one causal forward over the whole prompt. Prompt lengths
   round up to ``seq_buckets``; the prompt's K/V scatter into the paged
@@ -33,6 +36,36 @@ by compile-once executables:
   ``temperature > 0`` falls back to the plain per-token sampling loop —
   verify-consistent by construction (same program, same key stream as
   the non-speculative path).
+
+The block protocol (what ``_decoder_parts`` checks; docs/SERVING.md):
+
+- the first layer embeds: ``apply(params, {}, tokens (B, T))`` → ``(x, _)``,
+  ``embed_step(params, tokens (B,), positions (B,))`` → (B, H),
+  ``embed_window(params, tokens (B, W), positions (B, W))`` → (B, W, H);
+  ``max_position`` bounds a stream unless ``max_length`` is given;
+- every middle layer is causal and caches: ``cache_kind`` (``"tokens"``,
+  the default: rows behind the page tables; ``"state"``: one slot a
+  stream), ``init_pool(n)`` → a dict of arrays with ``n`` slots, of the
+  type the block chooses,
+  ``prefill_paged(params, x, pool, where, mask=)`` → ``(x, pool)`` and
+  ``decode_window_paged(params, x_w, pool, where, positions, block_size,
+  limits=)`` → ``(x_w, pool)``. ``where`` is the streams' address in that
+  layer's cache: for ``"tokens"`` the flat token slots (B, T) in prefill and
+  the page tables (B, max_blocks) in a decode window; for ``"state"`` the
+  state slots (B,) in both. A resumed or chunked prefill also needs
+  ``prefill_resume_paged``, the contiguous engine (``paged=False``)
+  ``init_cache``/``prefill``/``decode_step``;
+- the last layer has ``_logits(params, x)``.
+
+A pool dict may carry an ``"moe"`` entry: a routed feed-forward's int32
+counters, added to inside the prefill and decode programs and read once a
+batch with its tokens (``serving.moe_*_total``). It is no cache
+(``paged.NOT_CACHE``): the pool's sizes, types and block copies pass it by.
+
+A net with ``"state"`` layers (recurrent: the state cannot be shared,
+copied or rolled back) refuses the prefix cache, copy-on-write, chunked
+prefill and the speculative verify window with a ``ValueError`` at
+construction: they need a state snapshot no layer offers yet.
 
 Admission: a batch whose streams cannot all get blocks sheds with
 :class:`~deeplearning4j_tpu.serving.resilience.PoolExhaustedError`
@@ -64,29 +97,37 @@ import numpy as np
 
 from deeplearning4j_tpu.data.bucketing import BucketingPolicy
 from deeplearning4j_tpu.ops import attention as attn_ops
-from deeplearning4j_tpu.serving.paged import (BlockPool, PoolExhaustedError,
-                                              PrefixCache,
-                                              default_pool_blocks)
+from deeplearning4j_tpu.serving.paged import (NOT_CACHE, BlockPool,
+                                              PoolExhaustedError, PrefixCache,
+                                              cache_kind, default_pool_blocks)
 from deeplearning4j_tpu.serving.quantize import maybe_quantize
 from deeplearning4j_tpu.util import telemetry as tm
 from deeplearning4j_tpu.util.compile_watcher import note_trace
 
 
-def _decoder_parts(net, what: str):
-    """Validate and split a decoder-only MLN into (emb, blocks, head)."""
-    from deeplearning4j_tpu.nn.transformer import (BertEmbeddingLayer,
-                                                   TransformerEncoderBlock)
+_PAGED = ("init_pool", "prefill_paged", "decode_window_paged")
+_CONTIGUOUS = ("init_cache", "prefill", "decode_step")
 
+
+def _decoder_parts(net, what: str, paged: bool = True):
+    """Validate a decoder-only MLN against the block protocol (module doc)
+    and split it into (emb, blocks, head)."""
     layers = net.layers
-    if not layers or not isinstance(layers[0], BertEmbeddingLayer):
-        raise ValueError(f"{what} needs a BertEmbeddingLayer input "
-                         "(e.g. zoo.bert.Bert(causal=True, task='mlm'))")
+    if not layers or not all(hasattr(layers[0], m) for m in
+                             ("embed_step", "embed_window")):
+        raise ValueError(f"{what} needs an embedding input layer with "
+                         "embed_step/embed_window (BertEmbeddingLayer, "
+                         "TokenEmbeddingLayer; e.g. zoo.bert.Bert("
+                         "causal=True, task='mlm'))")
     blocks = layers[1:-1]
-    if not blocks or not all(isinstance(b, TransformerEncoderBlock)
-                             for b in blocks):
-        raise ValueError(f"{what} needs TransformerEncoderBlock middle "
-                         "layers")
-    if not all(b.causal for b in blocks):
+    need = _PAGED if paged else _CONTIGUOUS
+    lacking = [type(b).__name__ for b in blocks
+               if not all(hasattr(b, m) for m in need)]
+    if not blocks or lacking:
+        raise ValueError(f"{what} needs decoder-block middle layers with "
+                         f"{'/'.join(need)} (TransformerEncoderBlock, "
+                         f"HybridDecoderBlock); got {lacking or 'none'}")
+    if not all(getattr(b, "causal", False) for b in blocks):
         raise ValueError(f"{what} needs causal=True blocks — a "
                          "bidirectional encoder cannot decode "
                          "autoregressively")
@@ -122,10 +163,25 @@ class Generator:
                  draft_net=None, spec_tokens: int = 4,
                  quantize: Optional[str] = None,
                  model_id: str = ""):
-        self.emb, self.blocks, self.head = _decoder_parts(net, "Generator")
+        self.emb, self.blocks, self.head = _decoder_parts(net, "Generator",
+                                                          bool(paged))
         self.net = net
         self.model_id = str(model_id)
         self.max_length = int(max_length or self.emb.max_position)
+        #: a net with per-stream state layers (module doc: what it refuses)
+        self.recurrent = any(cache_kind(b) == "state" for b in self.blocks)
+        if self.recurrent:
+            for on, name in ((prefix_cache, "prefix_cache (and its "
+                              "copy-on-write)"),
+                             (prefill_chunk, "prefill_chunk"),
+                             (draft_net is not None, "speculative decoding "
+                              "(draft_net)")):
+                if on:
+                    raise ValueError(
+                        f"{name} is not served on a net with recurrent "
+                        "(per-stream state) layers: sharing, resuming or "
+                        "rolling back a stream needs a snapshot of its "
+                        "state, which no layer offers yet")
         conf_policy = BucketingPolicy.from_conf(getattr(net, "conf", None))
         if batch_buckets is None and conf_policy is not None:
             batch_buckets = conf_policy.batch_buckets
@@ -155,10 +211,19 @@ class Generator:
                 pool_blocks = default_pool_blocks(
                     bb if isinstance(bb, tuple) else (32,),
                     self.max_length, self.block_size)
+            # one state slot a stream of the largest batch (recurrent nets)
+            bb = self.policy.batch_buckets
             self.pool = BlockPool(self.blocks, block_size=self.block_size,
                                   num_blocks=int(pool_blocks),
                                   max_length=self.max_length,
-                                  model_id=self.model_id)
+                                  model_id=self.model_id,
+                                  state_slots=max(bb) if isinstance(
+                                      bb, tuple) else 32)
+            #: a routed feed-forward's counters ride in its layer's pool
+            self._moe_layers = [i for i, p in enumerate(self.pool.pools)
+                                if "moe" in p]
+            self._moe_seen = None
+            self._moe_totals_jit = jax.jit(self._moe_totals)
             # pools are DONATED through the paged programs (the hot loop
             # must not copy the whole pool per token) — every call site
             # threads the returned pools back into self.pool.pools
@@ -266,21 +331,45 @@ class Generator:
         logits = self.head._logits(params[-1], x[:, 0])
         return logits, new_caches
 
+    @staticmethod
+    def _address(tables):
+        """``tables`` as the paged programs take it: the page tables
+        (B, max_blocks), or on a recurrent net the pair (page tables, state
+        slots (B,)) -> (tables, states or None)."""
+        return tables if isinstance(tables, tuple) else (tables, None)
+
+    @staticmethod
+    def _where(blk, tables, states):
+        """A stream's address in ``blk``'s cache (module doc)."""
+        return states if cache_kind(blk) == "state" else tables
+
+    def _trash_address(self, batch: int):
+        """The address of ``batch`` rows that hold nothing: every table
+        entry the trash block, every state slot the trash slot."""
+        tables = jnp.zeros((batch, self.pool.max_blocks_per_stream),
+                           jnp.int32)
+        if self.recurrent:
+            return tables, jnp.zeros((batch,), jnp.int32)
+        return tables
+
     def _prefill_paged(self, raw, pools, tokens, lengths, tables):
         """Paged prefill: same causal forward as ``_prefill`` (the prompt
         attention runs over in-register K/V, so the logits are identical),
-        with every position's K/V scattered through the page table."""
+        with every position's K/V scattered through the page table.
+        ``tables``: see :meth:`_address`."""
         note_trace("serving.prefill_paged", tokens, lengths)
         params = self._params_of(raw)
         b, t = tokens.shape
         x, _ = self.emb.apply(params[0], {}, tokens)
         pad_mask = (jnp.arange(t)[None, :]
                     < lengths[:, None]).astype(x.dtype)
+        tables, states = self._address(tables)
         slots = attn_ops.paged_slots(
             tables, jnp.broadcast_to(jnp.arange(t), (b, t)), self.block_size)
         new_pools = []
         for i, blk in enumerate(self.blocks):
-            x, pool = blk.prefill_paged(params[i + 1], x, pools[i], slots,
+            x, pool = blk.prefill_paged(params[i + 1], x, pools[i],
+                                        self._where(blk, slots, states),
                                         mask=pad_mask)
             new_pools.append(pool)
         h_last = x[jnp.arange(b), lengths - 1]
@@ -296,11 +385,12 @@ class Generator:
         params = self._params_of(raw)
         x = self.emb.embed_step(params[0], tokens, positions)[:, None, :]
         pos_w = positions[:, None]
+        tables, states = self._address(tables)
         new_pools = []
         for i, blk in enumerate(self.blocks):
-            x, pool = blk.decode_window_paged(params[i + 1], x, pools[i],
-                                              tables, pos_w, self.block_size,
-                                              limits=limits)
+            x, pool = blk.decode_window_paged(
+                params[i + 1], x, pools[i], self._where(blk, tables, states),
+                pos_w, self.block_size, limits=limits)
             new_pools.append(pool)
         logits = self.head._logits(params[-1], x[:, 0])
         return logits, new_pools
@@ -362,7 +452,7 @@ class Generator:
 
     def _copy_block(self, pools, src, dst):
         """Copy-on-write device copy: duplicate physical block ``src``'s
-        rows into ``dst`` across every layer's K and V pool (the COW
+        rows into ``dst`` across every layer's row pools (the COW
         split of serving/paged.py — the table already points at ``dst``;
         this fills it before the suffix prefill overwrites the one
         recomputed row). Block ids are data: one executable ever."""
@@ -370,9 +460,8 @@ class Generator:
         bs = self.block_size
         rows_src = src * bs + jnp.arange(bs)
         rows_dst = dst * bs + jnp.arange(bs)
-        return [{"k": p["k"].at[rows_dst].set(p["k"][rows_src]),
-                 "v": p["v"].at[rows_dst].set(p["v"][rows_src])}
-                for p in pools]
+        return [{n: a if n in NOT_CACHE else a.at[rows_dst].set(a[rows_src])
+                 for n, a in p.items()} for p in pools]
 
     # ------------------------------------------------------------- sampling
     @staticmethod
@@ -428,8 +517,9 @@ class Generator:
                 for i in range(b_real)]
 
     # ------------------------------------------------------------ admission
-    def _grow(self, need: int):
-        """Swap in a pool twice the size (or ``need`` blocks if larger).
+    def _grow(self, need: int, need_states: int = 0):
+        """Swap in a pool twice the size (or ``need`` blocks if larger;
+        at least ``need_states`` state slots).
         Growth changes the pool shapes, so the NEXT paged calls trace once
         at the new size — a capacity event, not steady state (serving
         configs with finite buckets size the pool to their largest batch
@@ -445,12 +535,14 @@ class Generator:
             self.cache.flush()
         old_peak = self.pool.peak_streams
         self.pool.pools = None  # free before the bigger alloc
+        states = max(need_states, self.pool.num_state_slots)
         self.pool = BlockPool(self.blocks,
                               block_size=self.block_size,
                               num_blocks=grown,
                               max_length=self.max_length,
-                              model_id=self.model_id)
+                              model_id=self.model_id, state_slots=states)
         self.pool.peak_streams = old_peak
+        self._moe_seen = None
         self._pool_epoch += 1
         if self.cache is not None:
             self.cache.rebind(self.pool)
@@ -467,23 +559,36 @@ class Generator:
         never grows: the outer prefill is mid-write into the current
         buffers.
 
-        Returns ``(tables_list, tables, starts, cow, pending)``:
+        Returns ``(tables_list, tables, starts, cow, pending, held)``:
         per-stream block lists, the device table array, each stream's
         resume position (0 without a cache hit), COW ``(src, dst)`` block
-        copies to run before prefill, and the batch's pending trie nodes
-        to commit after it."""
+        copies to run before prefill, the batch's pending trie nodes
+        to commit after it, and the streams' state slots. On a recurrent
+        net ``tables`` is the pair the paged programs take
+        (:meth:`_address`): the table array and the state slots (B,)."""
         if self.cache is not None and prompts is not None:
-            return self._admit_prefix(prompts, lens, max_new, batch)
+            return (*self._admit_prefix(prompts, lens, max_new, batch), [])
         counts = [self.pool.blocks_needed(l, max_new) for l in lens]
         try:
-            tables_list = self.pool.reserve(counts)
+            tables_list, held = self._reserve(counts)
         except PoolExhaustedError:
             if not self._pool_auto or self._depth > 1:
                 raise
-            self._grow(int(sum(counts)))
-            tables_list = self.pool.reserve(counts)
+            self._grow(int(sum(counts)), len(counts))
+            tables_list, held = self._reserve(counts)
         tables = jnp.asarray(self.pool.table_array(tables_list, batch))
-        return tables_list, tables, [0] * len(lens), [], []
+        if self.recurrent:
+            tables = (tables, jnp.asarray(self.pool.state_array(held, batch)))
+        return tables_list, tables, [0] * len(lens), [], [], held
+
+    def _reserve(self, counts):
+        """Blocks and state slots of a batch, both or neither."""
+        tables_list = self.pool.reserve(counts)
+        try:
+            return tables_list, self.pool.reserve_states(len(counts))
+        except PoolExhaustedError:
+            self.pool.release(tables_list)
+            raise
 
     def _admit_prefix(self, prompts, lens, max_new: int, batch: int):
         """Prefix-aware admission: transactional match + reserve + COW +
@@ -588,7 +693,7 @@ class Generator:
         try:
             # admission stays OUTSIDE the reset-on-failure block: a shed
             # allocated nothing and must not trash live pool content
-            tables_list, tables, starts, cow, pending = self._admit(
+            tables_list, tables, starts, cow, pending, held = self._admit(
                 lens, max_new_tokens, batch, prompts=prompts)
         except BaseException:
             self._depth -= 1
@@ -624,14 +729,14 @@ class Generator:
         finally:
             self._depth -= 1
             # blocks free on completion, eos early-exit, and shed alike
-            self.pool.release(tables_list)
+            self.pool.release(tables_list, held)
 
     def _reset_pools(self):
         if self.cache is not None:
             # the buffers the cached blocks lived in are being replaced
             self.cache.flush()
-        self.pool.pools = [blk.init_pool(self.pool.num_slots)
-                           for blk in self.blocks]
+        self.pool.pools = self.pool.init_pools()
+        self._moe_seen = None
         self._pool_epoch += 1
 
     def _window_width(self, max_rem: int) -> int:
@@ -759,6 +864,39 @@ class Generator:
         tm.counter("serving.decode_kv_positions_declared_total", declared,
                    model=self.model_id)
 
+    @staticmethod
+    def _moe_totals(pools):
+        """The routed layers' counters, summed over the layers: (2, 5)
+        int32, prefill and decode rows (nn/decoder.py)."""
+        return sum(p["moe"] for p in pools if "moe" in p)
+
+    def _count_moe(self):
+        """One batch's router counts onto ``serving.moe_*_total``: the
+        device accumulators wrap (int32), so the host adds differences
+        modulo 2**32. One small program and one fetch a batch, launched
+        behind the last decode step."""
+        if not self._moe_layers:
+            return
+        from deeplearning4j_tpu.nn.moe import MOE_STATS
+
+        now = np.asarray(self._moe_totals_jit(self.pool.pools)).astype(
+            np.uint32)
+        seen = self._moe_seen if self._moe_seen is not None \
+            else np.zeros_like(now)
+        self._moe_seen = now
+        for phase, row in zip(("prefill", "decode"),
+                              (now - seen).astype(np.int64)):
+            for name, value in zip(MOE_STATS, row):
+                tm.counter(f"serving.moe_{name}_total", int(value),
+                           model=self.model_id, phase=phase)
+            # the decode step's own: what its roofline reader divides by
+            if phase == "decode":
+                tm.counter("serving.moe_decode_experts_touched_total",
+                           int(row[MOE_STATS.index("experts_touched")]),
+                           model=self.model_id)
+                tm.counter("serving.moe_decode_layer_steps_total",
+                           int(row[-1]), model=self.model_id)
+
     def _generate_paged(self, tokens, lengths, tables, b_real, lens,
                         max_new: int, *, temperature: float, key,
                         eos_id: Optional[int], trace: bool, stats=None,
@@ -805,6 +943,7 @@ class Generator:
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
         self._count_kv_read(batch, kv_read, len(steps) - 1)
+        self._count_moe()
         stacked = np.stack([np.asarray(s) for s in steps], axis=1)
         return self._trim(stacked, b_real, lens, max_new, eos_id)
 
@@ -1027,8 +1166,7 @@ class Generator:
             tm.set_health(check, ok, detail)
             if not ok:
                 return False
-            tables = jnp.zeros((b, self.pool.max_blocks_per_stream),
-                               jnp.int32)
+            tables = self._trash_address(b)
             logits, pools = self._prefill_paged_jit(
                 raw, self.pool.pools, tokens, lengths, tables)
             self.pool.pools = pools
@@ -1074,8 +1212,7 @@ class Generator:
             b = int(b)
             caches = None
             if self.paged:
-                tables = jnp.zeros((b, self.pool.max_blocks_per_stream),
-                                   jnp.int32)
+                tables = self._trash_address(b)
             widths = sorted({min(int(t), self.max_length)
                              for t in prompt_lengths})
             for t in widths:
@@ -1137,6 +1274,7 @@ class Generator:
             s["prefix_cache"] = self.cache.stats()
         if self.prefill_chunk is not None:
             s["prefill_chunk"] = self.prefill_chunk
+        s["recurrent"] = self.recurrent
         return s
 
     def prefix_hit_rate(self) -> Optional[float]:

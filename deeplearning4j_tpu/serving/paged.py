@@ -50,7 +50,25 @@ every attention read in between is masked to ``k_pos <= position``.
 Rollback never touches shared prefix blocks: generation writes land at
 positions ``>= prompt_len``, past every cacheable (full-prompt) block.
 
+Two kinds of cache, one manager. A block of the served net says what its
+cache is (``cache_kind``, serving/generate.py's block protocol):
+
+- ``"tokens"`` (the default): rows behind the page tables, one row a
+  token, whatever the block's ``init_pool`` makes a row of: a K and a V
+  row of the hidden width in float32 (``TransformerEncoderBlock``), one
+  latent row of 576 bfloat16 numbers (an MLA block of nn/decoder.py).
+- ``"state"``: one slot a STREAM, of a size that does not grow with the
+  context: a recurrent layer's state (a KDA block's (heads, dk, dv) float32
+  matrix and the tail of its short convolutions). Slot 0 is the trash slot
+  of padded batch rows; :meth:`BlockPool.reserve_states` hands a stream its
+  slot at admission, :meth:`BlockPool.release` takes it back with the
+  blocks. A state cannot be shared, copied on write or rolled back: the
+  prefix cache, copy-on-write and the speculative verify window need a
+  snapshot of it that no layer offers yet, and the generator refuses them
+  on a net with such layers.
+
 Gauges: ``serving.kv_pool_blocks_total`` / ``_free``,
+``serving.state_slots_total`` / ``_free``,
 ``serving.concurrent_streams``, ``serving.prefix_blocks_shared``
 (+ per-pool high-water in :meth:`stats`), the inputs to the
 ``concurrent_streams_per_device`` bench metric.
@@ -67,7 +85,31 @@ import numpy as np
 from deeplearning4j_tpu.serving.resilience import PoolExhaustedError
 from deeplearning4j_tpu.util import telemetry as tm
 
-__all__ = ["BlockPool", "PrefixCache", "PoolExhaustedError"]
+__all__ = ["BlockPool", "PrefixCache", "PoolExhaustedError", "cache_kind",
+           "NOT_CACHE"]
+
+#: entries of a layer's pool dict that are no cache: a routed
+#: feed-forward's int32 counters ride there through the programs
+#: (nn/decoder.py) and have no slots, whatever their shape
+NOT_CACHE = frozenset({"moe"})
+
+
+def cache_kind(block) -> str:
+    """What a served block caches: ``"tokens"`` (rows behind the page
+    tables; the default) or ``"state"`` (one slot a stream)."""
+    return getattr(block, "cache_kind", "tokens")
+
+
+def _cache_arrays(pool: dict) -> list:
+    """The arrays of a layer's pool dict that hold cache (first axis: the
+    slots), by key: everything but :data:`NOT_CACHE`."""
+    return [a for n, a in pool.items() if n not in NOT_CACHE]
+
+
+def _row_bytes(pool: dict) -> int:
+    """Bytes one slot of a layer's cache costs, by shape alone. Slicing
+    would dispatch an eager device gather per layer just to read sizes."""
+    return sum(int(a.nbytes // a.shape[0]) for a in _cache_arrays(pool))
 
 
 class BlockPool:
@@ -75,18 +117,19 @@ class BlockPool:
 
     ``num_blocks`` usable blocks of ``block_size`` token slots each; the
     device tensors carry one extra (trash) block at index 0. Device state
-    lives in ``self.pools`` — one ``{"k": (S,H*Dh), "v": (S,H*Dh)}`` per
-    transformer layer, created by the blocks' ``init_pool`` and donated
+    lives in ``self.pools`` — one dict of arrays per layer, created by the
+    blocks' ``init_pool`` (``{"k": (S,H*Dh), "v": (S,H*Dh)}`` for a
+    transformer layer; whatever a block says: module doc) and donated
     through the decode executables (the generator threads the returned
-    pools back). Allocation is REFCOUNTED: ``reserve`` hands out blocks
-    at refcount 1, ``incref`` adds holders (prefix-cache hits, the trie
-    index itself), and a block frees only when ``decref`` reaches 0."""
+    pools back). Each block chooses its cache's type. ``state_slots``: how
+    many streams' recurrent states the pool holds, for a net with
+    ``"state"`` layers. Allocation is REFCOUNTED: ``reserve`` hands out
+    blocks at refcount 1, ``incref`` adds holders (prefix-cache hits, the
+    trie index itself), and a block frees only when ``decref`` reaches 0."""
 
     def __init__(self, blocks, *, block_size: int, num_blocks: int,
                  max_length: int, model_id: str = "",
-                 dtype=None):
-        import jax.numpy as jnp
-
+                 state_slots: int = 0):
         if block_size < 1 or num_blocks < 1:
             raise ValueError("block_size and num_blocks must be >= 1")
         self.block_size = int(block_size)
@@ -97,10 +140,18 @@ class BlockPool:
         self.max_blocks_per_stream = math.ceil(self.max_length
                                                / self.block_size)
         self.num_slots = (self.num_blocks + 1) * self.block_size
-        self.pools = [blk.init_pool(self.num_slots,
-                                    dtype or jnp.float32)
-                      for blk in blocks]
+        self._blocks = list(blocks)
+        self.has_state = any(cache_kind(b) == "state" for b in self._blocks)
+        self.num_state_slots = int(state_slots) if self.has_state else 0
+        if self.has_state and self.num_state_slots < 1:
+            raise ValueError("a net with recurrent layers needs "
+                             "state_slots >= 1")
+        self.pools = self.init_pools()
         self._lock = threading.RLock()
+        # state slot 0 is the trash slot of padded rows — never handed out
+        self._free_states: List[int] = list(
+            range(1, self.num_state_slots + 1))
+        self._held_states: set = set()
         # block 0 is the trash block — never handed out
         self._free: List[int] = list(range(1, self.num_blocks + 1))
         #: refcount per ALLOCATED block (absent = free)
@@ -108,6 +159,17 @@ class BlockPool:
         self._streams = 0
         self.peak_streams = 0
         self._gauges()
+
+    def init_pools(self) -> list:
+        """Fresh device pools, one per block: token rows for ``"tokens"``
+        blocks (trash block included), stream slots for ``"state"`` blocks
+        (trash slot included)."""
+        out = []
+        for blk in self._blocks:
+            n = (self.num_state_slots + 1 if cache_kind(blk) == "state"
+                 else self.num_slots)
+            out.append(blk.init_pool(n))
+        return out
 
     # ---------------------------------------------------------- accounting
     def blocks_needed(self, prompt_len: int, max_new: int) -> int:
@@ -129,13 +191,30 @@ class BlockPool:
             return sum(1 for r in self._ref.values() if r > 1)
 
     def bytes_per_token(self) -> int:
-        """Device bytes one token slot costs across every layer (K + V).
-        Pure shape arithmetic — this runs on every stats poll, and
-        slicing (``p["k"][0]``) would dispatch an eager device gather per
-        layer just to read sizes."""
-        return sum(int(p["k"].nbytes // p["k"].shape[0]
-                       + p["v"].nbytes // p["v"].shape[0])
-                   for p in self.pools)
+        """Device bytes one token slot costs across every layer that keeps
+        rows behind the page tables (K + V, or a latent row). Pure shape
+        arithmetic — this runs on every stats poll."""
+        return sum(_row_bytes(p) for b, p in zip(self._blocks, self.pools)
+                   if cache_kind(b) == "tokens")
+
+    def bytes_per_stream_state(self) -> int:
+        """Device bytes one stream's recurrent state costs across every
+        ``"state"`` layer, whatever the stream's length."""
+        return sum(_row_bytes(p) for b, p in zip(self._blocks, self.pools)
+                   if cache_kind(b) == "state")
+
+    def _dtypes(self) -> Dict[str, str]:
+        """The cache arrays' types by cache kind (``/``-joined if a kind
+        holds several)."""
+        out: Dict[str, set] = {}
+        for b, p in zip(self._blocks, self.pools or ()):
+            out.setdefault(cache_kind(b), set()).update(
+                str(a.dtype) for a in _cache_arrays(p))
+        return {k: "/".join(sorted(v)) for k, v in out.items()}
+
+    def state_bytes(self) -> int:
+        """Total device bytes of the usable state slots."""
+        return self.num_state_slots * self.bytes_per_stream_state()
 
     def pool_bytes(self) -> int:
         """Total device bytes of the usable pool (trash block excluded)."""
@@ -157,6 +236,11 @@ class BlockPool:
         tm.gauge("serving.prefix_blocks_shared",
                  sum(1 for r in self._ref.values() if r > 1),
                  model=self.model_id)
+        if self.has_state:
+            tm.gauge("serving.state_slots_total", self.num_state_slots,
+                     model=self.model_id)
+            tm.gauge("serving.state_slots_free", len(self._free_states),
+                     model=self.model_id)
 
     # ----------------------------------------------------------- admission
     def reserve(self, counts: Sequence[int]) -> List[List[int]]:
@@ -182,6 +266,33 @@ class BlockPool:
             self.peak_streams = max(self.peak_streams, self._streams)
             self._gauges()
             return out
+
+    def reserve_states(self, n: int) -> List[int]:
+        """All-or-nothing: one state slot for each of ``n`` streams, or
+        :class:`PoolExhaustedError` having taken none. A net without
+        recurrent layers has none to give and returns ``[]``."""
+        if not self.has_state:
+            return []
+        with self._lock:
+            if n > len(self._free_states):
+                tm.counter("serving.pool_exhausted_total",
+                           model=self.model_id)
+                raise PoolExhaustedError(
+                    f"{self.model_id or 'paged-kv'}: batch needs {n} state "
+                    f"slots, pool has {len(self._free_states)} free "
+                    f"(of {self.num_state_slots})")
+            out = [self._free_states.pop() for _ in range(int(n))]
+            self._held_states.update(out)
+            self._gauges()
+            return out
+
+    def state_array(self, states: Sequence[int], batch: int) -> np.ndarray:
+        """State slots as the executables' (B,) int32 input; padded batch
+        rows (and every row of a net without recurrent layers) point at
+        the trash slot (0)."""
+        out = np.zeros((batch,), np.int32)
+        out[:len(states)] = np.asarray(states, np.int32)
+        return out
 
     def incref(self, blocks: Sequence[int]):
         """Add one holder to each block (a prefix-cache hit sharing the
@@ -215,15 +326,24 @@ class BlockPool:
                     self._ref[b] = r - 1
             self._gauges()
 
-    def release(self, tables: Sequence[Sequence[int]]):
+    def release(self, tables: Sequence[Sequence[int]],
+                states: Sequence[int] = ()):
         """Drop every stream's hold on its blocks (eos / batch done /
-        shed rollback). Shared blocks — a prefix another stream or the
+        shed rollback) and hand back its state slot. Shared blocks — a prefix another stream or the
         trie still references — stay allocated; only the LAST holder
         returns a block to the free list (the ISSUE 16 refcount fix: the
         eos early-exit used to free outright)."""
         with self._lock:
             for t in tables:
                 self.decref(t)
+            for slot in states:
+                slot = int(slot)
+                if slot not in self._held_states:
+                    raise ValueError(
+                        f"double-free: release of free state slot {slot} "
+                        f"({self.model_id or 'paged-kv'})")
+                self._held_states.discard(slot)
+                self._free_states.append(slot)
             self._streams = max(0, self._streams - len(list(tables)))
             self._gauges()
 
@@ -263,7 +383,17 @@ class BlockPool:
         with self._lock:
             free = list(self._free)
             refs = dict(self._ref)
+            free_states = list(self._free_states)
+            held_states = set(self._held_states)
         problems = []
+        n_states = len(set(free_states)) + len(held_states)
+        if (len(set(free_states)) != len(free_states) or 0 in free_states
+                or 0 in held_states or set(free_states) & held_states
+                or n_states != self.num_state_slots):
+            problems.append(
+                f"state slots: free {len(free_states)} + held "
+                f"{len(held_states)} != {self.num_state_slots}, or a slot "
+                "on both sides")
         if len(set(free)) != len(free):
             problems.append("duplicate free-list entries (double-free)")
         if 0 in free or 0 in refs:
@@ -308,6 +438,14 @@ class BlockPool:
                 "streams": self._streams,
                 "peak_streams": self.peak_streams,
                 "pool_bytes": self.pool_bytes(),
+                # by cache kind: rows behind the page tables, and the
+                # recurrent layers' stream slots
+                "bytes_by_kind": {"tokens": self.pool_bytes(),
+                                  "state": self.state_bytes()},
+                # what the net's blocks chose
+                "dtype_by_kind": self._dtypes(),
+                "state_slots_total": self.num_state_slots,
+                "state_slots_free": len(self._free_states),
                 "contiguous_stream_ceiling":
                     self.contiguous_stream_ceiling(),
                 # in-use fraction: the fleet front tier folds this into

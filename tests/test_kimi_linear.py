@@ -1,0 +1,385 @@
+"""Kimi Linear on the serving path, at a small size on the CPU (hidden 64: a
+leading dense layer, KDA, KDA+experts, MLA+experts, 8 experts top-2, seeded
+weights): the program against the benchmark's plain reference, the three
+forms of the KDA recurrence, absorbed against expanded latent attention, the
+shares of all chips against the uncut layer, and the two kinds of cache in
+one pool manager.
+
+Everything runs in float32 at ``highest``, so the tolerances are those of
+float32 sums taken in another order: 2e-4 on logits of size 0.5, 1e-4 on
+one layer's outputs.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.manifest import module_from
+from deeplearning4j_tpu.nn import moe
+from deeplearning4j_tpu.nn.decoder import HybridDecoderBlock
+from deeplearning4j_tpu.ops import attention as attn_ops
+from deeplearning4j_tpu.ops import kda
+from deeplearning4j_tpu.serving import ServingModel
+from deeplearning4j_tpu.serving.generate import Generator
+from deeplearning4j_tpu.serving.paged import BlockPool
+from deeplearning4j_tpu.serving.resilience import PoolExhaustedError
+
+CFG = dict(
+    hidden_size=64, num_hidden_layers=4, num_attention_heads=2,
+    first_k_dense_replace=1, intermediate_size=128, moe_intermediate_size=32,
+    num_experts=8, published_num_experts=8, expert_offset=0,
+    num_experts_per_token=2, num_shared_experts=1,
+    routed_scaling_factor=2.446, kv_lora_rank=24, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, rms_norm_eps=1e-5, vocab_size=96,
+    max_position_embeddings=96, param_dtype="float32", gate_low_rank=8,
+    linear_attn_config=dict(full_attn_layers=[4], kda_layers=[1, 2, 3],
+                            head_dim=16, num_heads=2,
+                            short_conv_kernel_size=4))
+PROMPTS = [[5, 6, 7, 8, 9, 10, 11], [3] * 20, [1, 2, 3]]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return module_from("reference", "kimi_linear")
+
+
+@pytest.fixture(scope="module")
+def served(ref):
+    """(weights, generator) of the small model, built as the benchmark
+    builds it: the builder's net, the reference's weights."""
+    builder = module_from("builders", "zoo.KimiLinear")
+    w = ref.make_weights(3, CFG)
+    net = builder.build(CFG)
+    builder.load(net, w)
+    gen = Generator(net, max_length=96, batch_buckets=(4,),
+                    prefill_buckets=(32,), block_size=8)
+    return w, gen
+
+
+def _reference_logits(ref, w, prompts, served_tokens, dtype=None):
+    new = len(served_tokens[0])
+    toks = np.zeros((len(prompts), 48), np.int32)
+    pos = np.zeros((len(prompts), new), np.int32)
+    for i, (p, o) in enumerate(zip(prompts, served_tokens)):
+        seq = list(p) + list(o[:-1])
+        toks[i, :len(seq)] = seq
+        pos[i] = len(p) - 1 + np.arange(new)
+    return ref.logits_at(w, jnp.asarray(toks), jnp.asarray(pos), n_heads=2,
+                         dtype=dtype)
+
+
+# ------------------------------------------------- program against reference
+def test_prefill_then_decode_matches_the_references_full_forward(ref, served):
+    """Prefill and 11 decode steps through the paged path, ragged prompts
+    and a padded row in one batch: the logits behind every served token are
+    the reference's (full forward, no cache) to 2e-4."""
+    w, gen = served
+    raw = gen._raw_params()
+    tokens, lengths, b_real, lens = gen._prep(PROMPTS, 12)
+    tables_list, addr, *_, held = gen._admit(lens, 12, 4)
+    limits = jnp.asarray([l + 11 for l in lens] + [0], jnp.int32)
+    logits, gen.pool.pools = gen._prefill_paged_jit(
+        raw, gen.pool.pools, tokens, lengths, addr)
+    got, out, pos = [logits], [], lengths
+    for _ in range(11):
+        cur = jnp.argmax(got[-1], -1).astype(jnp.int32)
+        out.append(cur)
+        logits, gen.pool.pools = gen._decode_paged_jit(
+            raw, gen.pool.pools, addr, cur, pos, limits)
+        got.append(logits)
+        pos = pos + 1
+    out.append(jnp.argmax(got[-1], -1).astype(jnp.int32))
+    gen.pool.release(tables_list, held)
+    got = np.stack([np.asarray(g) for g in got], 1)[:3]       # (3, 12, V)
+    served_tokens = np.stack([np.asarray(o) for o in out], 1)[:3].tolist()
+    want = np.asarray(_reference_logits(ref, w, PROMPTS, served_tokens))
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    assert gen.generate(PROMPTS, max_new_tokens=12) == served_tokens
+
+
+def test_the_fp8_control_is_seen(ref, served):
+    w, gen = served
+    out = gen.generate(PROMPTS, max_new_tokens=12)
+    lg = _reference_logits(ref, w, PROMPTS, out)
+    low = _reference_logits(ref, w, PROMPTS, out,
+                            dtype=jnp.dtype("float8_e4m3fn"))
+    gap = lambda pick: float(jnp.max(jnp.max(lg, -1) - jnp.take_along_axis(
+        lg, pick[..., None], -1)[..., 0]))
+    assert gap(jnp.asarray(out)) == 0.0
+    assert gap(jnp.argmax(low, -1)) > 0.01
+
+
+def test_served_through_the_model_server_objects(served):
+    """ServingModel, as chipbench/serve.py constructs it, serves the net;
+    cache types come from the net, and ``pools = None`` frees every cache."""
+    _, gen = served
+    model = ServingModel(gen.net, "kimi", kind="generate", paged=True,
+                         block_size=8, max_length=96,
+                         bucketing="batch=4;seq=32")
+    assert model.generator.generate(PROMPTS, max_new_tokens=4) == \
+        [r[:4] for r in gen.generate(PROMPTS, max_new_tokens=12)]
+    d = model.describe()
+    assert d["kv_pool"]["recurrent"] is True
+    assert d["kv_pool"]["bytes_by_kind"]["state"] > 0
+    assert d["kv_pool"]["state_slots_total"] == 4
+    kinds = {n: str(a.dtype) for p in model.generator.pool.pools
+             for n, a in p.items()}
+    assert kinds == {"state": "float32", "conv": "float32",
+                     "rows": "float32", "moe": "int32"}
+    model.generator.pool.pools = None
+
+
+# ----------------------------------------------------------------------- KDA
+def _kda_inputs(key, b, t, h, dk, dv, strong):
+    ks = jax.random.split(key, 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (b, t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, t, h, dk)))
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    g = -jnp.exp(jax.random.normal(ks[3], (b, t, h, dk))
+                 * (2.0 if strong else 0.5) - (0.0 if strong else 3.0))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    return q, k, v, g, beta, jax.random.normal(ks[5], (b, h, dk, dv))
+
+
+@pytest.mark.parametrize("t,strong", [(64, False), (150, True), (7, False),
+                                      (130, False)])
+def test_chunked_kda_is_the_recurrence(t, strong):
+    """Whole chunks, a ragged tail, less than a sub-block, and decays so
+    strong (exp(-50) a step) that a factorised decay would overflow."""
+    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(t), 2, t, 3, 16, 8,
+                                       strong)
+    o1, s1 = kda.kda_recurrent(q, k, v, g, beta, s0)
+    o2, s2 = jax.jit(kda.kda_chunked)(q, k, v, g, beta, s0)
+    assert bool(jnp.isfinite(o2).all())
+    np.testing.assert_allclose(o2, o1, atol=2e-5)
+    np.testing.assert_allclose(s2, s1, atol=2e-5)
+
+
+def test_padded_tokens_leave_a_state_alone():
+    """Rows of different lengths in one call: with beta = g = 0 past a
+    row's length the final state is the state at its length."""
+    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(1), 3, 96, 2, 16,
+                                       16, False)
+    lengths = jnp.asarray([96, 40, 1])
+    live = (jnp.arange(96)[None] < lengths[:, None]).astype(jnp.float32)
+    _, s = kda.kda_chunked(q, k, v, g * live[..., None, None],
+                           beta * live[..., None], s0)
+    for i, n in enumerate([96, 40, 1]):
+        _, want = kda.kda_recurrent(q[i:i + 1, :n], k[i:i + 1, :n],
+                                    v[i:i + 1, :n], g[i:i + 1, :n],
+                                    beta[i:i + 1, :n], s0[i:i + 1])
+        np.testing.assert_allclose(s[i], want[0], atol=2e-5)
+
+
+def test_conv_tail_of_ragged_rows():
+    x = jnp.arange(2 * 6 * 1, dtype=jnp.float32).reshape(2, 6, 1) + 1
+    tail = kda.conv_tail(x, jnp.asarray([6, 2]), 3)
+    assert tail[0, :, 0].tolist() == [4.0, 5.0, 6.0]
+    assert tail[1, :, 0].tolist() == [0.0, 7.0, 8.0]
+
+
+# ----------------------------------------------------------------------- MLA
+def test_absorbed_latent_decode_is_the_expanded_form():
+    """One MLA block: a window of 3 tokens decoded in absorbed form over
+    paged latent rows against the expanded causal attention over the whole
+    sequence."""
+    blk = HybridDecoderBlock(hidden_size=64, mixer="mla", ffn="dense",
+                             n_heads=2, kv_lora_rank=24, qk_nope_dim=16,
+                             qk_rope_dim=8, v_head_dim=16, ffn_size=32)
+    p, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, 64))
+    full, _ = blk.apply(p, {}, x)
+    bs, lens = 4, jnp.asarray([17, 9])
+    pool = blk.init_pool(16 * bs)
+    tables = jnp.asarray(np.arange(1, 13).reshape(2, 6), jnp.int32)
+    pos = jnp.broadcast_to(jnp.arange(20), (2, 20))
+    mask = (pos < lens[:, None]).astype(jnp.float32)
+    _, pool = blk.prefill_paged(p, x, pool,
+                                attn_ops.paged_slots(tables, pos, bs),
+                                mask=mask)
+    win = lens[:, None] + jnp.arange(3)[None]
+    x_w = jnp.take_along_axis(x, win[..., None], axis=1)
+    got, _ = blk.decode_window_paged(p, x_w, pool, tables, win, bs)
+    want = jnp.take_along_axis(full, win[..., None], axis=1)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+# ------------------------------------------------------------------- experts
+def test_the_shares_of_all_chips_sum_to_the_uncut_layer(ref):
+    """Four chips of 2 experts each, the shared expert counted once: their
+    partial results add up to the reference's uncut layer (8 experts held),
+    and each share is the reference's own share."""
+    cfg = dict(CFG, num_hidden_layers=2,
+               linear_attn_config=dict(CFG["linear_attn_config"],
+                                       full_attn_layers=[2], kda_layers=[1]))
+    w = ref.make_weights(7, cfg)
+    p = w["layers"][1]                                  # MLA + 8 experts
+    d = ref._dims(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(2), (40, 64))
+    mm = lambda a, b: jnp.matmul(a, b, precision="highest")
+    uncut = ref._ffn(p, d, h, mm)
+    shared = ref._ffn({k: v for k, v in p.items() if k[0] == "S"}
+                      | {"router": p["router"],
+                         "router_bias": p["router_bias"]},
+                      dict(d, held=0), h, mm)
+    idx, wts = moe.route_sigmoid_topk(h, p["router"], p["router_bias"], 2,
+                                      2.446)
+    total = shared
+    for off in range(0, 8, 2):
+        part, stats = moe.grouped_experts(
+            h, idx, wts, p["Egate"][off:off + 2], p["Eup"][off:off + 2],
+            p["Edown"][off:off + 2], e_offset=off, n_experts=8)
+        share = {k: (v[off:off + 2] if k[0] == "E" else v)
+                 for k, v in p.items()}
+        want = ref._ffn(share, dict(d, held=2, offset=off), h, mm) - shared
+        np.testing.assert_allclose(part, want, atol=1e-5)
+        assert int(stats[0]) == 80 and 0 < int(stats[2]) <= 2
+        total = total + part
+    np.testing.assert_allclose(total, uncut, atol=1e-5)
+
+
+def test_a_skewed_router_takes_more_passes_and_drops_nothing():
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    n, hs, f = 300, 32, 24
+    x = jax.random.normal(ks[0], (n, hs))
+    wg, wu = (jax.random.normal(k, (4, hs, f)) * 0.2 for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (4, f, hs)) * 0.2
+    idx = jnp.tile(jnp.arange(3)[None], (n, 1))         # all picks held here
+    w = jax.random.uniform(ks[4], (n, 3))
+    assert moe._pass_rows(n * 3, 4, 16) < n * 3         # so: several passes
+    live = jnp.arange(n) < 100
+    y, stats = jax.jit(lambda: moe.grouped_experts(
+        x, idx, w, wg, wu, wd, e_offset=0, n_experts=16, live=live))()
+    want = sum(w[:, e:e + 1] * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e]))
+                                @ wd[e]) for e in range(3))
+    np.testing.assert_allclose(y[:100], want[:100], atol=1e-4)
+    assert float(jnp.abs(y[100:]).max()) == 0.0
+    assert stats.tolist() == [300, 300, 3, 100]
+
+
+# ---------------------------------------------------------- the pool manager
+def test_state_slots_and_blocks_are_conserved(served):
+    """After release, after growth, and after an exception in the decode
+    loop: every block and every state slot is back."""
+    _, gen = served
+    pool = gen.pool
+    free0 = (pool.free_blocks(), len(pool._free_states))
+    gen.generate(PROMPTS, max_new_tokens=5)
+    assert (pool.free_blocks(), len(pool._free_states)) == free0
+    assert pool.conservation()[0]
+
+    boom = gen._decode_paged_jit
+    gen._decode_paged_jit = lambda *a: (_ for _ in ()).throw(
+        RuntimeError("boom"))
+    with pytest.raises(RuntimeError, match="boom"):
+        gen.generate(PROMPTS, max_new_tokens=5)
+    gen._decode_paged_jit = boom
+    assert (gen.pool.free_blocks(), len(gen.pool._free_states)) == free0
+    assert gen.pool.conservation()[0]
+    assert gen.generate(PROMPTS[:1], max_new_tokens=3)      # pools rebuilt
+
+
+def test_an_auto_pool_grows_its_state_slots_too(served):
+    _, big = served
+    gen = Generator(big.net, max_length=96, batch_buckets="pow2",
+                    prefill_buckets=(32,), block_size=8)
+    gen.pool = BlockPool(gen.blocks, block_size=8, num_blocks=4,
+                         max_length=96, state_slots=1)
+    out = gen.generate(PROMPTS, max_new_tokens=4)
+    assert out == [r[:4] for r in big.generate(PROMPTS, max_new_tokens=4)]
+    assert gen.pool.num_state_slots >= 3 and gen.pool.conservation()[0]
+    assert len(gen.pool._free_states) == gen.pool.num_state_slots
+
+
+def test_a_pinned_pool_sheds_when_its_state_slots_run_out(served):
+    _, big = served
+    pool = BlockPool(big.blocks, block_size=8, num_blocks=64, max_length=96,
+                     state_slots=2)
+    tables = pool.reserve([1, 1])
+    held = pool.reserve_states(2)
+    with pytest.raises(PoolExhaustedError, match="state slots"):
+        pool.reserve_states(1)
+    pool.release(tables, held)
+    with pytest.raises(ValueError, match="double-free"):
+        pool.release([], held[:1])
+    assert pool.conservation()[0]
+
+
+def test_the_pools_figures_count_cache_and_not_the_router_counters(served):
+    """The routed layers' counters ride in their layer's pool dict, (2, 5)
+    int32: no size, type or block copy of the cache may take them for rows."""
+    _, gen = served
+    pool = gen.pool
+    assert sum("moe" in p for p in pool.pools) == 3
+    # one MLA layer: a latent row of kv_lora_rank + qk_rope_head_dim float32
+    assert pool.bytes_per_token() == (24 + 8) * 4
+    # three KDA layers: (heads, 16, 16) float32 + 3 earlier inputs of q|k|v
+    assert pool.bytes_per_stream_state() == 3 * (2 * 16 * 16 + 3 * 3 * 32) * 4
+    stats = pool.stats()
+    assert stats["dtype_by_kind"] == {"tokens": "float32", "state": "float32"}
+    assert stats["bytes_by_kind"] == {
+        "tokens": pool.num_blocks * 8 * 128, "state": 4 * 9600}
+    # copy-on-write on a routed layer with rows (MLA + experts): the rows
+    # of the block move, the counters stay as they were
+    mla = pool.pools[3]
+    rows = jnp.arange(mla["rows"].size, dtype=jnp.float32).reshape(
+        mla["rows"].shape)
+    moe = mla["moe"] + 7
+    (out,) = gen._copy_block([dict(rows=rows, moe=moe)], 1, 2)
+    assert out["moe"] is moe
+    np.testing.assert_array_equal(out["rows"][16:24], rows[8:16])
+    np.testing.assert_array_equal(out["rows"][:16], rows[:16])
+
+
+@pytest.mark.parametrize("kw,what", [
+    (dict(prefix_cache=True), "prefix_cache"),
+    (dict(prefill_chunk=8), "prefill_chunk"),
+    (dict(draft_net="net"), "speculative"),
+])
+def test_a_recurrent_net_refuses_what_needs_a_state_snapshot(served, kw, what):
+    _, gen = served
+    if "draft_net" in kw:
+        kw = dict(draft_net=gen.net)
+    with pytest.raises(ValueError, match=what + ".*recurrent"):
+        Generator(gen.net, max_length=96, batch_buckets=(4,), **kw)
+
+
+def test_the_contiguous_engine_names_what_the_blocks_lack(served):
+    _, gen = served
+    with pytest.raises(ValueError, match="init_cache/prefill/decode_step"):
+        Generator(gen.net, max_length=96, batch_buckets=(4,), paged=False)
+
+
+def test_router_counters_reach_the_metrics_page(served):
+    from deeplearning4j_tpu.util import telemetry as tm
+
+    _, gen = served
+    tele = tm.get_telemetry()
+    before = {n: tele.counter_total(n) for n in (
+        "serving.moe_picks_total", "serving.moe_picks_local_total",
+        "serving.moe_decode_layer_steps_total")}
+    gen.generate(PROMPTS, max_new_tokens=5)
+    after = {n: tele.counter_total(n) for n in before}
+    # 3 routed layers; prefill: 30 prompt tokens + the padded row's one;
+    # decode: 3 live rows x 4 steps; 2 picks a token, all 8 experts held
+    picks = 3 * 2 * (31 + 3 * 4)
+    assert after["serving.moe_picks_total"] \
+        - before["serving.moe_picks_total"] == picks
+    assert after["serving.moe_picks_local_total"] \
+        - before["serving.moe_picks_local_total"] == picks
+    assert after["serving.moe_decode_layer_steps_total"] \
+        - before["serving.moe_decode_layer_steps_total"] == 3 * 4
+    text = tele.prometheus_text()
+    for name in ("dl4j_serving_moe_experts_touched_total",
+                 "dl4j_serving_moe_expert_load_max_total",
+                 "dl4j_serving_state_slots_total",
+                 "dl4j_serving_state_slots_free"):
+        assert name in text
