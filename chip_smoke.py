@@ -39,6 +39,9 @@ import numpy as np
 # worst case measured on v5e is 2.1e-2 (flash causal dq, PR 21); a wrong
 # kernel is off by the order of the values themselves.
 BF16_REL_TOL = 4e-2
+#: the KDA prefill against the recurrence: one bfloat16 rounding (2**-8) of
+#: the largest value, twice over (docs/KERNELS.md)
+KDA_REL_TOL = 2 ** -7
 
 
 def say(msg: str) -> None:
@@ -267,6 +270,54 @@ def _kernel_case(name: str, kernel_fn, exact_fn, *args) -> None:
             f"path, worst relative error {max(errs):.2e}")
 
 
+def _kda_prefill_case(b: int = 16, t: int = 1024, h: int = 32,
+                      d: int = 128) -> None:
+    """``kda_chunked`` as the serving path calls it on a TPU (one layer at
+    ``kimiL-chat-open``'s prefill bucket): the kernel, against the token-by-
+    token recurrence in float32 at ``highest``, with the lengths of a real
+    batch (rows of a few hundred tokens beside padding rows of one) and with
+    every row full. ``KDA_REL_TOL``: bfloat16 products, float32 state."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import kda
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+    draw = lambda key: jax.nn.silu(jax.random.normal(key, (b, t, h, d)))
+    q, k, v = unit(draw(ks[0])) * d ** -0.5, unit(draw(ks[1])), draw(ks[2])
+    g = -jnp.exp(jax.random.normal(ks[3], (b, t, h, d)) * 0.5 - 3.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    s0 = jnp.zeros((b, h, d, d), jnp.float32)
+    ragged = np.ones((b,), np.int32)
+    ragged[:9] = np.minimum([512, 612, 434, 300, 389, 282, 530, t, 381], t)
+
+    def oracle(q, k, v, g, beta, s0, n):
+        live = (jnp.arange(t)[None] < n[:, None]).astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            return kda.kda_recurrent(q, k, v, g * live[..., None, None],
+                                     beta * live[..., None], s0)
+
+    args = (q, k, v, g, beta, s0)
+    n0 = jnp.asarray(ragged)
+    kernel, mosaic = _compile_on_chip(kda.kda_chunked, *args, n0)
+    require(mosaic, "kda prefill: kda_chunked on a TPU is a Mosaic kernel")
+    oracle = jax.jit(oracle)
+    for name, lens in (("a batch's lengths", ragged),
+                       ("every row full", np.full((b,), t, np.int32))):
+        n = jnp.asarray(lens)
+        (o, s), (o_r, s_r) = kernel(*args, n), oracle(*args, n)
+        held = (jnp.arange(t)[None] < kda.live_chunks(n)[:, None]
+                * kda.CHUNK)[..., None, None]
+        require(not bool(jnp.any(jnp.where(held, 0.0, o))),
+                f"kda prefill, {name}: o is 0 in every chunk behind a length")
+        errs = (rel_err(o, jnp.where(held, o_r, 0.0)), rel_err(s, s_r))
+        require(max(errs) <= KDA_REL_TOL,
+                f"kda prefill, {name}: o and the state match kda_recurrent, "
+                f"relative errors {errs[0]:.2e} {errs[1]:.2e}")
+
+
 def leg_kernels() -> None:
     import jax
     import jax.numpy as jnp
@@ -348,6 +399,7 @@ def leg_kernels() -> None:
         lambda x, w: kconv.conv2d_pallas(x, w, (1, 1), pads, (1, 1), 1,
                                          False, 8),
         conv_under("exact"), arr(8, 56, 56, 64), arr(3, 3, 64, 64, scale=0.05))
+    _kda_prefill_case()
     # stride 2 is a geometry supports() admits and Mosaic (JAX 0.9.0) refuses
     # to lower: forced pallas must say so, never run another path instead
     x2, w2 = arr(8, 56, 56, 256), arr(1, 1, 256, 128, scale=0.05)

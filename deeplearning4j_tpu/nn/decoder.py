@@ -288,11 +288,10 @@ class HybridDecoderBlock(Layer):
         b, t, _ = x.shape
         h = rms_norm(x, params["norm1"], self.eps)
         q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, None)
-        live = mask.astype(F32)                     # padding moves no state
-        g, beta = g * live[..., None, None], beta * live[..., None]
-        s0 = jnp.zeros((b, self.n_heads, self.head_dim, self.head_dim), F32)
-        o, s = kda.kda_chunked(q, k, v, g, beta, s0)
         lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
+        s0 = jnp.zeros((b, self.n_heads, self.head_dim, self.head_dim), F32)
+        # padding moves no state, and chunks behind a length are not visited
+        o, s = kda.kda_chunked(q, k, v, g, beta, s0, lengths)
         return (self._kda_out(params, o, gate), s,
                 kda.conv_tail(raw, lengths, self.conv_size - 1))
 

@@ -20,6 +20,10 @@ Three forms of the same recurrence live here:
   products and the state is touched once a chunk. Every ``exp`` is of a
   number <= 0: pairs in different 16-token sub-blocks split the decay about
   the row block's first token, pairs inside one sub-block take it as it is.
+  On a TPU, with heads in whole 128-lane tiles, it is one Pallas kernel
+  (``kda_prefill``): a chunk's intermediates and the running state stay on
+  the chip, and a row's walk ends with the chunk its length ends in; the XLA
+  form runs everywhere else and computes every chunk (docs/KERNELS.md).
 - :func:`kda_step` — the decode step, one token against the state.
 
 A token with ``beta = 0`` and ``g = 0`` leaves the state as it was: that is
@@ -27,6 +31,9 @@ how padded positions and finished rows are kept from moving a live state.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -132,13 +139,22 @@ def _unit_lower_solve(a, rhs):
     return x
 
 
-def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
-    """The recurrence chunk-wise: the same arguments and results as
-    :func:`kda_recurrent`. ``T`` is padded to whole chunks with tokens that
-    leave the state alone."""
+def live_chunks(lengths, chunk: int = CHUNK):
+    """Chunks of a row that hold a token, ``ceil(lengths / chunk)``: the
+    length of its walk (an int or an array of lengths)."""
+    return (lengths + chunk - 1) // chunk
+
+
+def _kda_chunked_xla(q, k, v, g, beta, state, lengths=None,
+                     chunk: int = CHUNK):
+    """:func:`kda_chunked` as XLA programs: every chunk of the window is
+    computed, whatever the lengths."""
     f32 = jnp.float32
     b, t, h, dk = q.shape
     dv = v.shape[-1]
+    if lengths is not None:
+        live = (jnp.arange(t)[None, :] < lengths[:, None]).astype(f32)
+        g, beta = g * live[..., None, None], beta * live[..., None]
     pad = -t % chunk
     if pad:
         z = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
@@ -173,8 +189,214 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
 
     xs = tuple(jnp.moveaxis(x, 2, 0) for x in (w, uv, qd, aqk, kdec, gamma))
     state, o = lax.scan(one, state.astype(f32), xs)             # o (n,B,H,C,dv)
+    if lengths is not None:     # what the kernel writes where it computes nothing
+        held = jnp.arange(n)[:, None] < live_chunks(lengths, chunk)[None, :]
+        o = jnp.where(held[:, :, None, None, None], o, 0.0)
     o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)
     return jnp.moveaxis(o, 1, 2)[:, :t], state
+
+
+# -- the prefill as one Pallas TPU kernel -----------------------------------
+HEAD_GROUP = 8      # heads of one grid step (docs/KERNELS.md: measured)
+
+
+def _kda_chunk(q, k, v, g, beta, st, exact: bool):
+    """One chunk of one head, on values held on the chip: q, k, g (C, dk),
+    v (C, dv), beta (C, 1), ``st`` the state transposed (dv, dk), all
+    float32 -> (o (C, dv), the state after the chunk). The arithmetic of
+    :func:`_kda_chunked_xla`, product for product; ``exact`` keeps float32
+    operands where a TPU takes one bfloat16 pass (the interpreter on a
+    CPU, as XLA's default precision does there)."""
+    f32 = jnp.float32
+    c, dk = k.shape
+    ns = c // SUB
+    mxu = (lambda a: a) if exact else (lambda a: a.astype(jnp.bfloat16))
+
+    def mm(a, b_, dims=((1,), (0,))):           # one pass, float32 sums
+        return lax.dot_general(mxu(a), mxu(b_), (dims, ((), ())),
+                               preferred_element_type=f32)
+
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+
+    def pieces(a, n):       # a = sum of n bfloat16 pieces, to 8 n bits
+        out = []
+        for _ in range(n):
+            out.append(a.astype(jnp.bfloat16))
+            a = a - out[-1].astype(f32)
+        return out
+
+    def mm_hi(a, b_):       # float32 in three bfloat16 passes (Precision.HIGH)
+        if exact:
+            return dot(a, b_)
+        (ah, al), (bh, bl) = pieces(a, 2), pieces(b_, 2)
+        return dot(ah, bh) + (dot(ah, bl) + dot(al, bh))
+
+    def running_sum(x):     # along the chunk: 0/1 weights, so exact products
+        tri = (col <= row).astype(f32 if exact else jnp.bfloat16)
+        return sum(dot(tri, p) for p in ([x] if exact else pieces(x, 3)))
+
+    def of_block(x, at):
+        """Row ``at`` of each 16-row block of ``x``, held by all its rows."""
+        return jnp.concatenate(
+            [jnp.broadcast_to(x[i * SUB + at:i * SUB + at + 1],
+                              (SUB, x.shape[1])) for i in range(ns)], axis=0)
+
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    rel = col - (row // SUB) * SUB              # column within the row's block
+    row1 = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    gc = running_sum(g)                         # of the log decay
+    # row block I sees the others about the running sum just before it
+    ref = jnp.concatenate(
+        [jnp.zeros((SUB, dk), f32)]
+        + [jnp.broadcast_to(gc[i * SUB - 1:i * SUB], (SUB, dk))
+           for i in range(1, ns)], axis=0)
+    erow = jnp.exp(gc - ref)                                    # <= 1
+    rows = jnp.concatenate([k * erow, q * erow], axis=0)        # (2C, dk)
+    blk2 = lax.broadcasted_iota(jnp.int32, (2 * c, c), 0) % c // SUB
+    off = jnp.zeros((2 * c, c), f32)
+    for i in range(1, ns):
+        r_i = gc[i * SUB - 1:i * SUB]
+        cols = jnp.where(row1 < i * SUB,
+                         k * jnp.exp(jnp.minimum(r_i - gc, 0.0)), 0.0)
+        off = jnp.where(blk2 == i, mm(rows, cols, ((1,), (1,))), off)
+    akk_off, aqk_off = off[:c], off[c:]
+    # pairs inside a sub-block take the decay whole, a column at a time; the
+    # same column eliminates below itself in the inverse of (I + A)'s
+    # diagonal blocks: forward substitution, the sums in the XLA form's order
+    dkk = jnp.zeros((c, c), f32)
+    dqk = jnp.zeros((c, c), f32)
+    inv = (row == col).astype(f32)
+    for j in range(SUB):
+        kd = of_block(k, j) * jnp.exp(jnp.minimum(gc - of_block(gc, j), 0.0))
+        ck = jnp.sum(k * kd, axis=1, keepdims=True)
+        cq = jnp.sum(q * kd, axis=1, keepdims=True)
+        dkk = jnp.where(rel == j, ck, dkk)
+        dqk = jnp.where(rel == j, cq, dqk)
+        if j < SUB - 1:
+            below = jnp.where(row1 % SUB > j, beta * ck, 0.0)
+            inv = inv - below * of_block(inv, j)
+    aqk = jnp.where(col <= row, aqk_off + dqk, 0.0)
+    decay = jnp.exp(gc)                                         # <= 1
+    rhs = jnp.concatenate([beta * k * decay, beta * v], axis=1)
+    y, m = mm_hi(inv, rhs), mm_hi(inv, beta * akk_off)
+    sol = y
+    for _ in range(ns - 1):
+        sol = y - mm_hi(m, sol)
+    w, uv = sol[:, :dk], sol[:, dk:]
+    u = uv - mm(w, st, ((1,), (1,)))
+    o = mm(q * decay, st, ((1,), (1,))) + mm(aqk, u)
+    last = gc[c - 1:c]
+    kdec = k * jnp.exp(last - gc)                               # <= 1
+    return o, st * jnp.exp(last) + mm(u, kdec, ((0,), (0,)))
+
+
+def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                o_ref, s_ref, *, chunk, heads, dk, dv, exact):
+    """Grid (row, head group, chunk), the chunk innermost and sequential:
+    the state's output block stays on the chip across it, transposed."""
+    from jax.experimental import pallas as pl
+
+    b, hg, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n = len_ref[b]
+
+    @pl.when(c == 0)
+    def _():
+        for j in range(heads):
+            s_ref[0, j] = s0_ref[0, j].T
+
+    @pl.when(c * chunk >= n)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(c * chunk < n)
+    def _():
+        at = c * chunk + lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+        live = at < n
+        lane = lax.broadcasted_iota(jnp.int32, beta_ref.shape[1:], 1)
+        for j in range(heads):
+            lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
+            beta = jnp.sum(jnp.where(lane == hg * heads + j, beta_ref[0], 0.0),
+                           axis=1, keepdims=True)
+            o, st = _kda_chunk(
+                q_ref[0, :, lk], k_ref[0, :, lk], v_ref[0, :, lv],
+                jnp.where(live, g_ref[0, :, lk], 0.0),
+                jnp.where(live, beta, 0.0), s_ref[0, j], exact)
+            o_ref[0, :, lv] = o
+            s_ref[0, j] = st
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        for j in range(heads):
+            s_ref[0, j] = s_ref[0, j].T
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("chunk", "interpret", "head_group",
+                                    "exact"))
+def _kda_chunked_pallas(q, k, v, g, beta, state, lengths=None,
+                        chunk: int = CHUNK, interpret: bool = False,
+                        head_group: int = HEAD_GROUP, exact=None):
+    """:func:`kda_chunked` as one kernel (module doc): needs ``dk`` and
+    ``dv`` in whole 128-lane tiles. Jitted, so that a program of twenty such
+    layers traces and lowers the kernel once and calls it twenty times.
+    ``exact`` (float32 operands in every product) is the interpreter's way
+    unless said: a test runs the chip's bfloat16 passes on a CPU with it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    if lengths is None:
+        lengths = jnp.full((b,), t, jnp.int32)
+    pad = -t % chunk
+    flat = lambda a: jnp.pad(a.astype(f32).reshape(b, t, -1),
+                             ((0, 0), (0, pad), (0, 0)))
+    n = (t + pad) // chunk
+    hg = math.gcd(h, head_group)
+
+    def tokens(width, heads=True):
+        # a chunk behind the row's last is not fetched: the block index stays
+        return pl.BlockSpec(
+            (1, chunk, width),
+            lambda i, j, c, n_ref: (
+                i, jnp.minimum(c, jnp.maximum(
+                    live_chunks(n_ref[i], chunk) - 1, 0)), j if heads else 0))
+
+    states = pl.BlockSpec((1, hg, dk, dv), lambda i, j, c, n_ref: (i, j, 0, 0))
+    o, s = pl.pallas_call(
+        functools.partial(_kda_kernel, chunk=chunk, heads=hg, dk=dk, dv=dv,
+                          exact=interpret if exact is None else exact),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, h // hg, n),
+            in_specs=[tokens(hg * dk), tokens(hg * dk), tokens(hg * dv),
+                      tokens(hg * dk), tokens(h, heads=False), states],
+            out_specs=[pl.BlockSpec((1, chunk, hg * dv),
+                                    lambda i, j, c, n_ref: (i, c, j)),
+                       states]),
+        out_shape=[jax.ShapeDtypeStruct((b, t + pad, h * dv), f32),
+                   jax.ShapeDtypeStruct((b, h, dk, dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="kda_prefill",
+    )(lengths.astype(jnp.int32), flat(q), flat(k), flat(v), flat(g),
+      flat(beta), state.astype(f32))
+    return o[:, :t].reshape(b, t, h, dv), s
+
+
+def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
+    """The recurrence chunk-wise: the arguments and results of
+    :func:`kda_recurrent`, plus ``lengths`` (B,): a token at or behind its
+    row's length leaves the state alone, and ``o`` is 0 in every chunk that
+    starts there (a row of length 0 returns the state it was given). ``T``
+    is padded to whole chunks. On a TPU, with heads in whole 128-lane
+    tiles, one kernel walks the chunks the lengths cover
+    (:func:`_kda_chunked_pallas`); everywhere else the XLA form runs."""
+    if (jax.default_backend() == "tpu" and q.shape[-1] % 128 == 0
+            and v.shape[-1] % 128 == 0):
+        return _kda_chunked_pallas(q, k, v, g, beta, state, lengths, chunk)
+    return _kda_chunked_xla(q, k, v, g, beta, state, lengths, chunk)
 
 
 def causal_conv(x, w, tail=None):

@@ -97,6 +97,7 @@ import numpy as np
 
 from deeplearning4j_tpu.data.bucketing import BucketingPolicy
 from deeplearning4j_tpu.ops import attention as attn_ops
+from deeplearning4j_tpu.ops import kda as kda_ops
 from deeplearning4j_tpu.serving.paged import (NOT_CACHE, BlockPool,
                                               PoolExhaustedError, PrefixCache,
                                               cache_kind, default_pool_blocks)
@@ -255,6 +256,8 @@ class Generator:
         #: KV positions the decode/verify steps read, and what they would
         #: read at the declared max_length (pool_stats, _count_kv_read)
         self._kv_read = self._kv_declared = 0
+        #: (row, chunk) pairs the KDA prefills held / declared, a layer
+        self._kda_live = self._kda_declared = 0
         #: nesting depth of generate() — > 1 while a chunk-yield runs a
         #: nested decode batch; nested runs never grow/reset the pool
         self._depth = 0
@@ -770,6 +773,8 @@ class Generator:
                 raw, self.pool.pools, tokens, lengths, tables)
             self.pool.pools = pools
             n_chunks = 1
+            if self.recurrent:
+                self._count_kda_chunks(batch, t, lens)
         else:
             logits, n_chunks = self._prefill_windowed(
                 raw, tokens, lengths, tables, b_real, lens, starts,
@@ -862,6 +867,20 @@ class Generator:
         tm.counter("serving.decode_kv_positions_read_total", read,
                    model=self.model_id)
         tm.counter("serving.decode_kv_positions_declared_total", declared,
+                   model=self.model_id)
+
+    def _count_kda_chunks(self, batch: int, t: int, lens):
+        """One whole prefill onto the counters: the chunks of a recurrent
+        layer's walk that hold a token (``ops/kda.kda_chunked``: a padding
+        row of the bucket has one) against batch x chunks of the bucket,
+        worked out here from the prompt lengths."""
+        live = sum(map(kda_ops.live_chunks, lens)) + batch - len(lens)
+        declared = batch * kda_ops.live_chunks(t)
+        self._kda_live += live
+        self._kda_declared += declared
+        tm.counter("serving.kda_prefill_chunks_live_total", live,
+                   model=self.model_id)
+        tm.counter("serving.kda_prefill_chunks_declared_total", declared,
                    model=self.model_id)
 
     @staticmethod
@@ -1270,6 +1289,11 @@ class Generator:
         s["decode_kv_read_share"] = (
             round(self._kv_read / self._kv_declared, 4)
             if self._kv_declared else None)
+        if self.recurrent:
+            # share of the declared chunks the KDA prefills walked so far
+            s["kda_prefill_chunk_share"] = (
+                round(self._kda_live / self._kda_declared, 4)
+                if self._kda_declared else None)
         if self.cache is not None:
             s["prefix_cache"] = self.cache.stats()
         if self.prefill_chunk is not None:

@@ -871,6 +871,43 @@ class TestMosaicCrossLowering:
         with pytest.raises(Exception, match="strided_slice|stride"):
             _lower_for_tpu(fn, _aval(8, 56, 56, 256), _aval(1, 1, 256, 128))
 
+    def test_kda_prefill_at_the_cells_shape(self, monkeypatch):
+        """``kimiL-chat-open``'s prefill bucket, one layer: 16 rows x 1,024
+        positions, 32 heads of 128. On a TPU the chunk walk is one Mosaic
+        kernel; the CPU program of the same call holds none."""
+        from jax import export
+
+        from deeplearning4j_tpu.ops import kda
+
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        x = f32(16, 1024, 32, 128)
+        avals = (x, x, x, x, f32(16, 1024, 32), f32(16, 32, 128, 128),
+                 _aval(16, dtype=jnp.int32))
+        text = _lower_for_tpu(kda.kda_chunked, *avals)
+        assert text.count("tpu_custom_call") == 1
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: kda.kda_chunked(*a)    # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+
+    @pytest.mark.parametrize("backend,dk,dv", [
+        ("tpu", 16, 8), ("tpu", 128, 64), ("cpu", 128, 128)],
+        ids=["tpu-16x8", "tpu-128x64", "cpu-128x128"])
+    def test_kda_prefill_elsewhere_is_the_xla_form(self, monkeypatch,
+                                                   backend, dk, dv):
+        """Heads that are not whole 128-lane tiles, and any backend but the
+        TPU, take the XLA form: read from the platform and the shape."""
+        from deeplearning4j_tpu.ops import kda
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        text = _lower_for_tpu(
+            kda.kda_chunked, f32(2, 128, 2, dk), f32(2, 128, 2, dk),
+            f32(2, 128, 2, dv), f32(2, 128, 2, dk), f32(2, 128, 2),
+            f32(2, 2, dk, dv), _aval(2, dtype=jnp.int32))
+        assert "tpu_custom_call" not in text
+
     def test_resnet50_forward_reaches_no_kernel(self):
         """The flagship at its default conf on a TPU host: 53 convolutions,
         every one on the exact path — before PR 21, 51 of them routed to

@@ -10,6 +10,8 @@ float32 sums taken in another order: 2e-4 on logits of size 0.5, 1e-4 on
 one layer's outputs.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -149,34 +151,106 @@ def _kda_inputs(key, b, t, h, dk, dv, strong):
     return q, k, v, g, beta, jax.random.normal(ks[5], (b, h, dk, dv))
 
 
+# the chunked prefill three ways: as the serving path calls it at the small
+# model's 16 x 8 heads (off the TPU that is the XLA form), and at the 128-wide
+# heads the kernel takes, the XLA form beside the kernel under the interpreter
+FORMS = {
+    "served-16x8": (kda.kda_chunked, 16, 8),
+    "xla-128": (kda._kda_chunked_xla, 128, 128),
+    "kernel-128": (functools.partial(kda._kda_chunked_pallas, interpret=True),
+                   128, 128),
+}
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("t,strong", [(64, False), (150, True), (7, False),
                                       (130, False)])
-def test_chunked_kda_is_the_recurrence(t, strong):
+def test_chunked_kda_is_the_recurrence(form, t, strong):
     """Whole chunks, a ragged tail, less than a sub-block, and decays so
     strong (exp(-50) a step) that a factorised decay would overflow."""
-    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(t), 2, t, 3, 16, 8,
+    chunked, dk, dv = FORMS[form]
+    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(t), 2, t, 3, dk, dv,
                                        strong)
     o1, s1 = kda.kda_recurrent(q, k, v, g, beta, s0)
-    o2, s2 = jax.jit(kda.kda_chunked)(q, k, v, g, beta, s0)
+    o2, s2 = jax.jit(chunked)(q, k, v, g, beta, s0)
     assert bool(jnp.isfinite(o2).all())
     np.testing.assert_allclose(o2, o1, atol=2e-5)
     np.testing.assert_allclose(s2, s1, atol=2e-5)
 
 
-def test_padded_tokens_leave_a_state_alone():
-    """Rows of different lengths in one call: with beta = g = 0 past a
-    row's length the final state is the state at its length."""
-    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(1), 3, 96, 2, 16,
-                                       16, False)
-    lengths = jnp.asarray([96, 40, 1])
-    live = (jnp.arange(96)[None] < lengths[:, None]).astype(jnp.float32)
-    _, s = kda.kda_chunked(q, k, v, g * live[..., None, None],
-                           beta * live[..., None], s0)
-    for i, n in enumerate([96, 40, 1]):
+@pytest.mark.parametrize("form", FORMS)
+def test_padded_tokens_leave_a_state_alone(form):
+    """Rows of different lengths in one call (none, less than a sub-block,
+    an end inside a chunk, whole chunks, the full window): the final state
+    is the state at the row's length, the row of length 0 gets back the
+    state it gave, bit for bit, and o is the recurrence's up to the end of
+    the chunk a length ends in and exactly 0 in every chunk behind it."""
+    chunked, dk, dv = FORMS[form]
+    t, lens = 192, [0, 7, 100, 128, 192]
+    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(1), len(lens), t, 2,
+                                       dk, dv, False)
+    lengths = jnp.asarray(lens)
+    o, s = jax.jit(chunked)(q, k, v, g, beta, s0, lengths)
+    assert bool(jnp.isfinite(o).all())
+    live = (jnp.arange(t)[None] < lengths[:, None]).astype(jnp.float32)
+    want_o, _ = kda.kda_recurrent(q, k, v, g * live[..., None, None],
+                                  beta * live[..., None], s0)
+    for i, n in enumerate(lens):
         _, want = kda.kda_recurrent(q[i:i + 1, :n], k[i:i + 1, :n],
                                     v[i:i + 1, :n], g[i:i + 1, :n],
                                     beta[i:i + 1, :n], s0[i:i + 1])
         np.testing.assert_allclose(s[i], want[0], atol=2e-5)
+        held = kda.live_chunks(n) * kda.CHUNK
+        np.testing.assert_allclose(o[i, :held], want_o[i, :held], atol=2e-5)
+        assert not np.asarray(o[i, held:]).any()
+    np.testing.assert_array_equal(s[0], s0[0])
+
+
+def test_the_kernels_bfloat16_passes_on_a_cpu():
+    """The arithmetic the chip runs (one bfloat16 pass where XLA's default
+    precision takes one on a TPU, three in the solve, the running sum in
+    three exact pieces) under the interpreter: within bfloat16's 2**-8 of
+    the largest value, where float32 operands give 2e-5."""
+    q, k, v, g, beta, s0 = _kda_inputs(jax.random.PRNGKey(5), 2, 128, 2, 128,
+                                       128, False)
+    lengths = jnp.asarray([128, 70])
+    live = (jnp.arange(128)[None] < lengths[:, None]).astype(jnp.float32)
+    o1, s1 = kda.kda_recurrent(q, k, v, g * live[..., None, None],
+                               beta * live[..., None], s0)
+    o2, s2 = kda._kda_chunked_pallas(q, k, v, g, beta, s0, lengths,
+                                     interpret=True, exact=False)
+    for got, want in ((o2, o1), (s2, s1)):
+        err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        assert 2e-5 < err < 2 ** -7, err
+
+
+def test_kda_prefill_counters_follow_the_lengths(served):
+    """Host arithmetic, no device fetch: a prefill of 4 rows x 32 positions
+    declares 4 chunks of 64 a layer and holds one a row, padding row
+    included; a longer bucket shows the skipped ones. A net without a
+    ``"state"`` block reports no such share."""
+    from deeplearning4j_tpu.util import telemetry as tm
+
+    _, gen = served
+    tele = tm.get_telemetry()
+    read = lambda: [tele.counter_total(
+        f"serving.kda_prefill_chunks_{n}_total", model=gen.model_id)
+        for n in ("live", "declared")]
+    l0, d0 = read()
+    gen.generate(PROMPTS, max_new_tokens=2)
+    l1, d1 = read()
+    assert (l1 - l0, d1 - d0) == (4, 4)
+    gen._count_kda_chunks(16, 1024, [1024, 65, 64, 1])
+    l2, d2 = read()
+    assert (l2 - l1, d2 - d1) == (16 + 2 + 1 + 1 + 12, 16 * 16)
+    share = gen.pool_stats()["kda_prefill_chunk_share"]
+    assert share == round(gen._kda_live / gen._kda_declared, 4)
+    assert "dl4j_serving_kda_prefill_chunks_live_total" \
+        in tele.prometheus_text()
+    from deeplearning4j_tpu.zoo import Bert
+    bert = Generator(Bert.tiny(causal=True, task="mlm", vocab_size=32,
+                               max_length=32).init(), block_size=8)
+    assert "kda_prefill_chunk_share" not in bert.pool_stats()
 
 
 def test_conv_tail_of_ragged_rows():
