@@ -21,8 +21,8 @@ Declared-but-unmeasurable spaces (xla_flags: needs subprocess isolation
 distribution; compression_hosts: needs real DCN) are listed with their
 reasons, never silently skipped.
 
-Self-test hooks (exercised by benchmarks/autotune_smoke.py and the CI
-leg): ``--plant-slow LABEL:SECONDS`` adds a per-call sleep to one
+Self-test hooks (tests/test_autotune.py plants both through the driver):
+``--plant-slow LABEL:SECONDS`` adds a per-call sleep to one
 candidate (it must demonstrably LOSE), ``--plant-wrong LABEL`` perturbs
 one candidate's outputs (the equivalence gate must REJECT it). Both act
 on the real measurement path.

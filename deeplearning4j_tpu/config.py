@@ -36,8 +36,8 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   knob is cudnnAlgoMode's compile-once-per-shape algo selection.
 - ``DL4J_TPU_TELEMETRY`` — unified telemetry registry (util/telemetry.py,
   docs/OBSERVABILITY.md): counters/gauges/histograms, cross-process trace
-  spans, /metrics + /healthz on the UI server. Default ON (span cost is
-  ~µs against ms-scale steps — bench.py ``telemetry_overhead``); set to
+  spans, /metrics + /healthz on the UI server. Default ON (a span is two
+  clock reads and a locked append; its cost on the chip: not measured); set to
   0/false to strip every recording hook.
 - ``DL4J_TPU_TRACE_SAMPLE`` — serving request-trace head-sampling keep
   fraction in [0, 1] (serving/scheduler.py,
@@ -45,8 +45,7 @@ Recognized variables (DL4J_TPU_* namespace; reference names in comments):
   requests whose per-phase spans (queue wait / batch fill / compute /
   per-token decode) land on the merged trace. Slow, shed, and errored
   requests are ALWAYS kept regardless of the dice; ``0`` disables
-  request tracing entirely (bench.py ``request_tracing_overhead``
-  A/B's 1 vs 0). Unset = 0.02. The flight recorder is independent of
+  request tracing entirely. Unset = 0.02. The flight recorder is independent of
   this knob and always records.
 - ``DL4J_TPU_FAULTS`` — chaos knob for the elastic runtime
   (util/faults.py, docs/FAULT_TOLERANCE.md): arm injectable faults as

@@ -28,7 +28,7 @@ TPU-native shape of the reference's SharedTrainingMaster deployment story
   the last good checkpoint and re-enter the loop instead of raising.
 - Fault-injection seams (util/faults.py) are consulted on the real code
   paths — NaN poisoning of a real batch, SIGKILL of the real process —
-  so tests and the CI fault-smoke leg prove each recovery actually fires.
+  so tests/test_elastic.py proves each recovery actually fires.
 
 CPU-backend honesty (same stance as the r7 DCN dryrun): with world > 1 each
 process steps its own replica — this jaxlib's CPU backend rejects

@@ -660,8 +660,8 @@ class ParallelWrapper:
 
     def opt_state_bytes_per_device(self) -> int:
         """Bytes of optimizer state ONE device holds — the ZeRO memory
-        number (~1/N of the replicated total when sharded; bench.py
-        ``zero_optimizer_memory_bytes_per_device``)."""
+        number (~1/N of the replicated total when sharded;
+        tests/test_gspmd_identity.py holds it under a quarter at N=8)."""
         return gspmd.tree_bytes_per_device(self.model.opt_states)
 
     def reshard(self, mesh: Optional[TrainingMesh] = None):

@@ -30,7 +30,7 @@ per-channel scales with the dequantize inside the forward
 
 ``execute`` counts the XLA traces it causes via the CompileWatcher — the
 scheduler publishes them as ``serving.recompiles_total``, the steady-state-
-zero contract the CI smoke asserts.
+zero contract tests/test_serving.py asserts.
 """
 
 from __future__ import annotations

@@ -4,8 +4,8 @@
 The r13 serving tier proved the performance contracts (bit-identical
 batching, zero steady-state recompiles, priority lanes); this module makes
 the tier survive the failures production actually sees, applying r11's
-standard — every fault kind has its specific recovery asserted in CI
-(benchmarks/resilience_smoke.py) — to the serving path:
+standard — every fault kind has its specific recovery asserted
+(tests/test_serving_resilience.py) — to the serving path:
 
 - **The shed-error hierarchy** — every way a request can be refused,
   each mapping to one HTTP status the server translates mechanically:

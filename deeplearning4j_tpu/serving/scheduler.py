@@ -28,8 +28,8 @@ Telemetry (all on the process registry → /metrics): per-model request/shed
 counters, queue-depth gauge, batch-occupancy and latency histograms,
 p50/p99 latency gauges (combined AND split by ``lane``), and
 ``serving.recompiles_total`` — the count of XLA traces serving has caused
-since warmup, asserted 0 in steady state by the CI smoke
-(benchmarks/serving_smoke.py).
+since warmup, asserted 0 in steady state (tests/test_serving.py,
+tests/test_paged_decode.py).
 
 Request-scope observability (docs/OBSERVABILITY.md#request-tracing--slos):
 every request carries a ``request_id`` (the HTTP layer honors/echoes
@@ -206,7 +206,7 @@ class _Request:
 class _LatencyWindow:
     """Sliding window of recent request latencies for p50/p99 gauges (the
     telemetry histogram keeps the full Prometheus series; this gives exact
-    quantiles over the recent past for /healthz and the bench)."""
+    quantiles over the recent past for /healthz and ``stats()``)."""
 
     def __init__(self, size: int = 1024):
         self._buf = collections.deque(maxlen=size)

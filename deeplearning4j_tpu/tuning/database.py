@@ -266,8 +266,7 @@ _db_dir: Any = _UNSET
 _db_lock = threading.Lock()
 # trace-time resolve() memo: (db identity, op, sig, dtype) -> winner|None.
 # Building a TuningKey costs a sha256 + a jax.devices() walk — fine per
-# sweep, too much per eager-dispatch call (bench.py
-# autotune_dispatch_overhead gates the ≤1.05x budget). Backend/topology
+# sweep, too much per eager-dispatch call. Backend/topology
 # cannot change under a live process, so the memo is sound; commits and
 # set_database() clear it.
 _resolve_cache: Dict[tuple, Optional[dict]] = {}

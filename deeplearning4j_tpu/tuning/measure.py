@@ -24,8 +24,8 @@ winner's speedup is always relative to today's behaviour) and then
 neighbor improves — the classic coordinate-descent tail for larger
 spaces.
 
-Self-test hooks (used by ``benchmarks/autotune_smoke.py``, the CI gate
-self-test, and tests/test_autotune.py): ``handicap`` adds a per-call
+Self-test hooks (used by tests/test_autotune.py and by
+``benchmarks/autotune.py --plant-slow/--plant-wrong``): ``handicap`` adds a per-call
 sleep to a labelled candidate (a planted-slow config must demonstrably
 LOSE), ``corrupt`` perturbs a labelled candidate's outputs (a planted
 wrong-output config must be REJECTED by the equivalence gate). Both act
@@ -82,8 +82,8 @@ class MeasurementDriver:
     Parameters: ``db`` (a :class:`tuning.database.TuningDatabase`),
     ``search`` ("grid" | "random"), ``samples`` (random-mode candidate
     budget), ``seed`` (deterministic candidate sampling), ``runs``
-    (median-of-N), ``min_window_s`` (minimum timed window — the smoke
-    keeps it small, real sweeps use the default)."""
+    (median-of-N), ``min_window_s`` (minimum timed window — the tests
+    keep it small, real sweeps use the default)."""
 
     def __init__(self, db: tdb.TuningDatabase, *, search: str = "grid",
                  samples: int = 6, seed: int = 0, runs: int = 3,

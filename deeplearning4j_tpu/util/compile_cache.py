@@ -13,8 +13,8 @@ lives, by one rule:
   (git-ignored). The directory is part of every cache key, so it never
   depends on a pid, a clock or ``mkdtemp`` — a path that moves never hits.
 
-Nothing is enabled on import: entry points (``chip_smoke.py``, ``bench.py``,
-``serving/fleet_worker.py``) call :func:`enable_persistent_cache` before
+Nothing is enabled on import: entry points (``chip_smoke.py``,
+``chipbench/run.py``, ``serving/fleet_worker.py``) call :func:`enable_persistent_cache` before
 their first compile.
 
 Cache keys include the XLA/jaxlib version, backend, and the full HLO — a

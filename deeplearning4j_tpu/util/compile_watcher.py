@@ -22,8 +22,8 @@ Two complementary signals are collected:
   cache (util/compile_cache.py).
 
 Surfaced through ``RecompileListener`` (nn/listeners.py), the StatsListener
-``compile`` record group (util/stats.py), ``bench.py recompile_overhead``
-and ``benchmarks/compile_cache_sweep.py``.
+``compile`` record group (util/stats.py) and the chip benchmark's
+``compiles_in_window`` and ``setup_cache_hit_share`` (PERF.md section 3).
 """
 
 from __future__ import annotations

@@ -818,8 +818,7 @@ class CostReport:
         """Fraction of attributed per-step device time spent in the
         optimizer update phase (the ``(optimizer)`` row from the
         ``opt:update`` scope) — the number the fused donated apply
-        (docs/KERNELS.md#fused-optimizer-apply) is built to shrink; gated
-        as ``optimizer_update_ms_share`` in benchmarks/regression_gate.py.
+        (docs/KERNELS.md#fused-optimizer-apply) is built to shrink.
         None without a profiled run (``profile=True``)."""
         total = 0.0
         opt = 0.0

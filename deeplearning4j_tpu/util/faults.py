@@ -20,8 +20,8 @@ bootstrap handshake (parallel/distributed.py):
   stall a batch, corrupt a reload archive), each triggerable at a step
   number programmatically or via the ``DL4J_TPU_FAULTS`` env knob
   (``"inject_nan@5,kill_etl_worker"``). Recovery code that cannot be
-  made to fire in a test does not ship — tests/test_elastic.py and the
-  benchmarks/fault_smoke.py CI leg drive every kind through its recovery
+  made to fire in a test does not ship — tests/test_elastic.py and
+  tests/test_serving_resilience.py drive every kind through its recovery
   path.
 
 Injection sites are ordinary production code paths: each site asks
@@ -135,7 +135,7 @@ INJECT_NAN = "inject_nan"              # parallel/elastic.py: poison a batch
 SIGKILL_HOST = "sigkill_host"          # parallel/elastic.py: kill this process
 # serving-path kinds (docs/SERVING.md#resilience): the r13 tier's failure
 # modes, each firing on the REAL mechanism so the recovery exercised is the
-# production one (benchmarks/resilience_smoke.py drives all four in CI)
+# production one (tests/test_serving_resilience.py drives all four)
 SERVING_COMPUTE_ERROR = "serving_compute_error"  # serving/model.py: execute raises
 SERVING_WORKER_CRASH = "serving_worker_crash"    # serving/scheduler.py: worker loop dies
 SERVING_SLOW_BATCH = "serving_slow_batch"        # serving/model.py: execute stalls arg ms

@@ -175,8 +175,8 @@ class SloEngine:
                 self._recover_hooks.remove(hook)
 
     def reset(self):
-        """Drop every objective and restore its health check (tests, and
-        the smoke's synthetic budget-exhausted case). Objectives that are
+        """Drop every objective and restore its health check (tests: the
+        synthetic budget-exhausted case). Objectives that are
         exhausted at reset time fire their recover hooks first — dropping
         an objective ends its breach, and a state machine hung off the
         engine (the serving brownout controller) must see the recovery,

@@ -27,8 +27,8 @@ the shared measurement substrate:
 
 Overhead stance: every hook is gated on :func:`enabled` (one attribute
 read); a span costs two ``time.time_ns`` calls plus one locked append.
-``bench.py telemetry_overhead`` tracks the on/off step-time ratio
-(target ≤ 1.05x with all monitors enabled). The span buffer is a bounded
+What the hooks cost a step on the chip has not been measured (PERF.md
+section 7, the ``tracing`` issue). The span buffer is a bounded
 ring (``max_events``) so week-long training cannot leak host memory —
 drops are themselves counted (``telemetry.events_dropped_total``).
 

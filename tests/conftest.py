@@ -6,8 +6,12 @@ exercised without TPU hardware — the same trick the reference uses for
 SURVEY.md §4). Must set env vars before jax is imported anywhere.
 """
 
+import json
 import os
+import threading
 import time
+import urllib.error
+import urllib.request
 
 # Force CPU: tests need determinism, fp32 precision, and 8 virtual devices —
 # also on a host that has a TPU.
@@ -42,15 +46,23 @@ import pytest  # noqa: E402
 # a minute is split or shares its compiled programs before it is added here.
 LONGEST_FILES = (
     "chipbench_tests/test_chipbench_run_train.py",
-    "test_import_corpus.py",
     "chipbench_tests/test_chipbench_reference.py",
+    "test_import_corpus.py",
+    "test_autotune.py",
+    "test_kernels.py",
+    "test_paged_decode.py",
     "test_elastic.py",
-    "test_op_coverage.py",
     "chipbench_tests/test_chipbench_run_serve.py",
+    "test_op_coverage.py",
+    "test_kimi_linear.py",
     "test_keras_import.py",
     "test_serving.py",
-    "test_kernels.py",
+    "test_pipeline_fit.py",
+    "chipbench_tests/test_chipbench_kimi.py",
+    "test_compression.py",
     "test_zoo.py",
+    "test_distributed.py",
+    "test_prefix_cache.py",
 )
 
 
@@ -118,6 +130,55 @@ def wait_until():
             time.sleep(0.01)
 
     return wait
+
+
+@pytest.fixture(scope="session")
+def http_json():
+    """``http_json(url, obj=None, request_id=None)``: POST ``obj`` as JSON
+    (GET without one) and return (status, body, headers) whatever the
+    status; the body parsed as JSON where it is JSON."""
+
+    def call(url, obj=None, request_id=None, timeout=120):
+        headers = {"Content-Type": "application/json"}
+        if request_id is not None:
+            headers["X-Request-Id"] = request_id
+        req = urllib.request.Request(
+            url, data=None if obj is None else json.dumps(obj).encode(),
+            headers=headers)
+        try:
+            r = urllib.request.urlopen(req, timeout=timeout)
+            raw, code, hdrs = r.read(), r.status, dict(r.headers)
+        except urllib.error.HTTPError as e:
+            raw, code, hdrs = e.read(), e.code, dict(e.headers)
+        try:
+            return code, json.loads(raw), hdrs
+        except ValueError:
+            return code, raw.decode(), hdrs
+
+    return call
+
+
+@pytest.fixture(scope="session")
+def all_at_once():
+    """``all_at_once(fn, args)``: ``fn(a)`` for every ``a`` of ``args``, each
+    on a thread of its own and all in flight together; the results in the
+    order of ``args``."""
+
+    def run(fn, args, timeout=120):
+        got = [None] * len(args)
+
+        def fire(i):
+            got[i] = fn(args[i])
+
+        threads = [threading.Thread(target=fire, args=(i,))
+                   for i in range(len(args))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=timeout)
+        return got
+
+    return run
 
 
 @pytest.fixture

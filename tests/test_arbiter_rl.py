@@ -171,6 +171,9 @@ class TestRL:
         learner._train(learner.params, learner.target_params,
                        learner.opt_state, jnp.asarray(0), s, a, r, s2, done)
 
+    # slow: 8,000 environment steps; test_dqn_learns_toy_chain and
+    # test_double_dqn_learns_toy_chain above are the tier-1 tests that the
+    # same learner learns
     @pytest.mark.slow
     def test_dqn_cartpole_improves(self):
         conf = QLearningConfiguration(
@@ -195,10 +198,10 @@ class TestA3C:
         """ASYNC A3C (VERDICT r3 J21 tail): 4 actor-learner threads, stale
         gradients, shared Adam under a lock — learns the toy chain.
 
-        slow-marked (r19 tier-1 budget, ~31s on the current host): the
-        RL learn-on-toy-chain seam keeps its fast DQN/double-DQN
-        siblings in tier-1; the async worker machinery itself still
-        proves out in every full-CI pass."""
+        slow: half a minute of four Python threads taking turns at one
+        lock. In tier-1 test_a2c_learns_toy_chain is the same
+        actor-critic update without the threads, and test_dqn_learns_toy_chain
+        the toy chain."""
         from deeplearning4j_tpu.rl4j import A3CConfiguration, A3CDiscreteDense
 
         conf = A3CConfiguration(max_updates=400, num_threads=4, n_steps=8,

@@ -136,10 +136,9 @@ class TestAttentionLayers:
         res = gradcheck.check_model_gradients(loss, params, eps=1e-4)
         assert res.passed, res
 
-    # tier-1 runtime guard (ISSUE 11 satellite): ~24s fp64 gradcheck
-    # through the recurrent-attention scan; test_self_attention_gradcheck
-    # covers the attention-layer gradient seam cheaply in tier-1 and the
-    # full-suite CI leg still runs this
+    # slow: over a minute by itself (an fp64 gradcheck through the
+    # recurrent-attention scan); test_self_attention_gradcheck above is the
+    # tier-1 test of the attention layers' gradients
     @pytest.mark.slow
     def test_recurrent_attention_gradcheck(self, rng):
         layer = RecurrentAttentionLayer(n_in=4, n_out=6, n_heads=2)

@@ -143,14 +143,9 @@ class TestRegistry:
 
 
 class TestDriverGates:
-    @pytest.mark.slow
     def test_planted_slow_candidate_loses(self, db):
         """A config handicapped by a per-call sleep must demonstrably
-        LOSE the sweep — the gate that proves measurements rank.
-
-        slow-marked (r19 tier-1 budget): the same planted-slow gate runs
-        against the real sweep in benchmarks/autotune_smoke.py on EVERY
-        CI pass."""
+        LOSE the sweep — the gate that proves measurements rank."""
         drv = _driver(db)
         entry = drv.sweep(tuning.get_space("conv2d_tiles"), TINY_CONV,
                           handicap={"exact": 0.05})
@@ -160,13 +155,9 @@ class TestDriverGates:
         assert rows["exact"]["admitted"]            # slow, but correct
         assert rows["exact"]["ms"] > entry["winner"]["ms"]
 
-    @pytest.mark.slow
     def test_planted_wrong_output_rejected(self, db):
         """A candidate whose outputs diverge from the exact path must be
-        REJECTED by the equivalence gate — and never timed.
-
-        slow-marked (r19 tier-1 budget): the planted-wrong rejection also
-        runs in benchmarks/autotune_smoke.py on EVERY CI pass."""
+        REJECTED by the equivalence gate — and never timed."""
         drv = _driver(db)
         m0 = _counter("tuning.measurements_total")
         r0 = _counter("tuning.equivalence_rejects_total")
@@ -271,6 +262,30 @@ class TestDatabase:
         assert warm["status"] == "warm"
         assert warm["winner"] == cold["winner"]
         assert _counter("tuning.measurements_total") == m0
+
+    def test_independent_cold_sweeps_write_the_same_database(self,
+                                                             tmp_path):
+        """Two cold sweeps into two fresh directories (what two machines
+        of one kind do) write the same key files with the same candidate
+        digests and agree on the winner's implementation: a key or a
+        digest that held a timing, a path or a process id would make every
+        reader re-measure."""
+        sp = tuning.get_space("lstm_tiles")
+        dirs, winners = [], []
+        for name in ("a", "b"):
+            d = tuning.TuningDatabase(str(tmp_path / name))
+            entry = _driver(d, seed=0).sweep(sp, TINY_LSTM,
+                                             handicap={"exact": 0.02})
+            assert entry["status"] == "measured"
+            dirs.append(d.dir)
+            winners.append(entry["winner"]["impl"])
+        files = [sorted(f for f in os.listdir(d) if f.endswith(".json"))
+                 for d in dirs]
+        assert files[0] == files[1] and files[0]
+        digests = [[json.load(open(os.path.join(d, f)))["candidates_digest"]
+                    for f in fs] for d, fs in zip(dirs, files)]
+        assert digests[0] == digests[1]
+        assert winners == ["pallas", "pallas"]
 
     def test_changed_candidate_set_remeasures(self, db):
         """A drifted search space must NOT trust a stale winner: the
@@ -402,15 +417,10 @@ class TestDatabase:
 
 
 class TestAutoDispatch:
-    @pytest.mark.slow
     def test_auto_resolves_winner_through_db(self, db, monkeypatch):
         """kernel_impl=auto consults the database: a committed pallas
         winner (with its tile) engages the kernel on the exact geometry,
-        and the output still matches the exact path.
-
-        slow-marked (r19 tier-1 budget): auto-dispatch resolving through
-        an armed DB is asserted by benchmarks/autotune_smoke.py on EVERY
-        CI pass (tuning.hits_total > 0 + tuned == exact)."""
+        and the output still matches the exact path."""
         monkeypatch.delenv("DL4J_TPU_KERNEL_IMPL", raising=False)
         from deeplearning4j_tpu.ops import nn as nnops
 
@@ -570,17 +580,15 @@ class TestConfDefaulting:
 
 
 # ---------------------------------------------------------------------------
-# the one-command sweep, cross-process (slow: subprocess jax imports)
+# the one-command sweep, cross-process
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 class TestCrossProcess:
     def test_second_process_remeasures_nothing(self, tmp_path):
         """True cross-process warm read through benchmarks/autotune.py:
         the second PROCESS reports measurements_total == 0 and the
-        identical winner (the CI smoke leg asserts the same plus the
-        planted gates — this pins the pytest-visible contract)."""
+        identical winner."""
         db_dir = str(tmp_path / "xproc-db")
         cmd = [sys.executable,
                os.path.join(REPO, "benchmarks", "autotune.py"),

@@ -508,11 +508,11 @@ class TestPersistentCache:
         assert out.returncode == 0, out.stderr[-2000:]
         assert f"left-to-jax {d}" in out.stdout
 
-    @pytest.mark.slow
     def test_second_process_hits_cache(self, tmp_path):
         """Cross-process: a restarted process deserializes instead of
-        recompiling (the cold-start win bench_recompile_overhead measures).
-        The parent places the throwaway cache from outside."""
+        recompiling (what `setup_s` and `setup_cache_hit_share` read on the
+        chip: PERF.md section 3, compile). The parent places the throwaway
+        cache from outside."""
         child = (
             "import sys, json, jax\n"
             "from deeplearning4j_tpu.util import (enable_persistent_cache,"

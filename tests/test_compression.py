@@ -17,7 +17,7 @@ What CPU can honestly prove (the r6 convention, docs/DISTRIBUTED.md):
   delayed).
 
 What CPU cannot prove: that fewer wire bytes are faster — that ranking
-belongs to real ICI/DCN hardware (BENCH record carries the honest A/B).
+belongs to real ICI/DCN hardware (not measured: ROADMAP Speed 9).
 """
 
 import jax
@@ -242,7 +242,13 @@ class TestWireAccounting:
     def test_adaptive_threshold_drives_sparsity_down(self, rng):
         """The adaptive threshold climbs until the transmitted fraction
         reaches the target band — on this dense-gradient toy the sparse
-        wire ratio must fall well below dense within a few dozen steps."""
+        wire carries under a tenth of the dense bytes within a few dozen
+        steps, and every step's bytes land on the
+        `parallel.allreduce_wire_bytes_total` counter."""
+        from deeplearning4j_tpu.util import telemetry as tm
+
+        wire0 = tm.get_telemetry().counter_total(
+            "parallel.allreduce_wire_bytes_total")
         xs, ys = _data(rng, n=64)
         net = _net(comp="threshold", threshold=1e-3, target=1e-2,
                    updater=Sgd(0.05))
@@ -251,7 +257,9 @@ class TestWireAccounting:
         pw.fit(it, epochs=8)
         stats = pw.compression_stats()
         assert stats["threshold"] > 1e-3  # adapted upward
-        assert stats["ratio"] < 0.5, stats
+        assert stats["wire_bytes"] > 0 and stats["ratio"] < 0.1, stats
+        assert tm.get_telemetry().counter_total(
+            "parallel.allreduce_wire_bytes_total") > wire0
         # sparsity sits inside the adaptive dead band (3x each way),
         # modulo one trailing adjustment step
         sparsity = stats["nnz"] / (stats["workers"] * stats["elements"])

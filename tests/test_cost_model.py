@@ -265,6 +265,11 @@ class TestCgCostReport:
             rep.device_time_s, rel=0.05)
 
 
+# slow: half a minute to a minute by itself (ResNet-50 compiled and
+# profiled), and failing at the seed with the reconciliation tests it
+# repeats at size (ROADMAP.md, Design 9). The tier-1 tests of the same sums
+# are TestCgCostReport.test_graph_flops_sum_to_compiled_total and
+# test_graph_profile_reconciles above
 @pytest.mark.slow
 class TestFlagshipResNet50:
     def test_resnet50_flops_and_time_reconcile(self):

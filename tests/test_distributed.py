@@ -463,10 +463,10 @@ class TestCompressionAtScale:
         nz = np.asarray(quant["g"]) != 0
         assert np.all(np.abs(np.asarray(quant["g"])[nz]) == np.float32(1e-3))
 
-    # tier-1 runtime guard (ISSUE 11 satellite): ~22s of 25M-param fit
-    # steps; the conservation test above pins the 25M threshold chain and
-    # the small shared-master tests cover the master seam in tier-1 — the
-    # full-suite CI leg still runs this
+    # slow: two minutes by itself (25M-parameter fit steps), and failing
+    # at the seed (ROADMAP.md, Design 9). In tier-1 the conservation test
+    # above pins the 25M threshold chain and TestTrainingMasters'
+    # small fits hold the master seam
     @pytest.mark.slow
     def test_shared_training_master_25m_steps(self, rng):
         """3 full SharedTrainingMaster steps at 25M params on the 8-device

@@ -374,6 +374,8 @@ class TestFaultRecoveryPaths:
         out = ex.execute(records)
         assert out == serial  # bit-identical in-order merge, kill included
         assert _counter("etl.worker_restarts_total") >= before + 1
+        assert "dl4j_etl_worker_restarts_total" in \
+            tm.get_telemetry().prometheus_text()
 
     def test_etl_retries_exhausted_is_loud(self):
         from deeplearning4j_tpu.datavec.executor import (
@@ -427,6 +429,15 @@ class TestFaultRecoveryPaths:
         # the poisoned step was rolled back and replayed clean: the final
         # params are bit-identical to the run that never saw the NaN
         assert _leaves_equal(net.params, ref.params)
+        # and an operator sees the recovery: on /healthz' elastic section
+        # and among the series a scrape of /metrics returns
+        from deeplearning4j_tpu.util.ui_server import UIServer
+
+        section = json.loads(UIServer._healthz()[0])["elastic"]
+        assert list(section.values())[-1]["rollbacks"] == 1
+        text = UIServer._metrics_text()
+        assert "dl4j_elastic_rollbacks_total" in text
+        assert "dl4j_elastic_checkpoints_total" in text
 
     def test_inject_nan_rollback_under_coalesced_dispatch(self, tmp_path):
         """sync_every>1: the poisoned step's loss is detected at a WINDOW
@@ -528,7 +539,6 @@ class TestFaultRecoveryPaths:
         assert r["wire_bytes"] and r["wire_bytes"] > 0
         assert r["threshold"] and r["threshold"] > 0
 
-    @pytest.mark.slow
     def test_sigkill_with_pipelined_trainer_restores_stacked_state(
             self, tmp_path, child_env):
         """Elastic × pipeline (ISSUE 14 satellite): the 2-process SIGKILL

@@ -5,9 +5,8 @@ canned behavior — no jax, no subprocesses): routing determinism and
 rebalance bounds, header propagation across the proxy hop, failover /
 502 / 503 contracts, rolling-reload ordering and version monotonicity,
 metrics fan-in. The real-multi-process leg (archives → spawned
-``fleet_worker`` processes → SIGKILL/reload under live HTTP) is
-``slow``-marked — benchmarks/fleet_smoke.py runs the same contracts as a
-CI smoke.
+``fleet_worker`` processes → SIGKILL/reload under live HTTP) boots one
+two-worker fleet for its class.
 """
 
 import http.client
@@ -380,11 +379,12 @@ class TestStubFleet:
 # ------------------------------------------------------ real process leg
 
 
-@pytest.mark.slow
 class TestRealFleet:
     """The tests/_dist_worker.py-style leg: real spawned worker processes,
-    real HTTP, real SIGKILL. One fleet boot amortized across contracts;
-    benchmarks/fleet_smoke.py re-runs these under CI load."""
+    real HTTP, real SIGKILL. One fleet boot amortized across contracts:
+    two workers, each serving a dense classifier and a prefix-cached
+    causal BERT-tiny decoder from the archives a single-process oracle
+    loads."""
 
     @pytest.fixture(scope="class")
     def fleet_env(self, tmp_path_factory):
@@ -407,20 +407,31 @@ class TestRealFleet:
                     .set_input_type(InputType.feed_forward(8)).build())
             return MultiLayerNetwork(conf).init()
 
+        from deeplearning4j_tpu.zoo.bert import Bert
+
         net = dense(0)
         path = str(tmp / "clf.zip")
         ModelSerializer.write_model(net, path, save_updater=False)
+        gen_net = Bert.tiny(causal=True, task="mlm", vocab_size=48,
+                            max_length=32, hidden_dropout=0.0).init()
+        gen_path = str(tmp / "gen.zip")
+        ModelSerializer.write_model(gen_net, gen_path, save_updater=False)
+        reg = {"max_wait_ms": 1.0, "queue_limit": 128}
         spec = fleet_spec(
             models=[{"id": "clf", "path": path, "kind": "classify",
-                     "register": {"max_wait_ms": 1.0,
-                                  "queue_limit": 128}}],
+                     "register": reg},
+                    {"id": "gen", "path": gen_path, "kind": "generate",
+                     "register": reg,
+                     "model_kw": {"bucketing": {"batch_buckets": [1, 2, 4],
+                                                "seq_buckets": [8]},
+                                  "prefix_cache": True, "block_size": 4}}],
             env={"JAX_PLATFORMS": "cpu"})
-        fleet = FleetRouter(spec, n_workers=2, health_interval_s=0.2,
-                            name="testfleet").start()
+        fleet = FleetRouter(spec, n_workers=2, affinity_head=8,
+                            health_interval_s=0.2, name="testfleet").start()
         x = np.random.RandomState(3).normal(size=(2, 8)) \
             .astype(np.float32)
         yield {"fleet": fleet, "net": net, "x": x, "tmp": tmp,
-               "dense": dense, "np": np}
+               "dense": dense, "np": np, "gen_net": gen_net}
         fleet.stop()
 
     def test_http_identical_to_inprocess_oracle(self, fleet_env):
@@ -435,6 +446,62 @@ class TestRealFleet:
             assert hdrs.get("X-Request-Id") == "oracle-1"
             assert np.allclose(np.asarray(body["outputs"]), oracle,
                                atol=1e-5)
+
+    def test_shared_prefixes_keep_their_worker_and_its_cache_warm(
+            self, fleet_env):
+        """Four groups of six prompts, each group sharing an 8-token head
+        (two radix blocks): every answer is the single-process oracle's,
+        token for token; affinity decides the routing; each worker that
+        served a group reads a prefix hit rate no lower than one process
+        serving all of it would; and a second, identical burst compiles
+        nothing on any worker."""
+        from deeplearning4j_tpu.serving import Generator
+
+        fleet = fleet_env["fleet"]
+        groups = []
+        for g in range(4):
+            head = [(7 * g + k) % 40 + 1 for k in range(8)]
+            groups.append([head + [(g + 11 * t + j) % 40 + 1
+                                   for j in range(4)] for t in range(6)])
+        oracle = Generator(fleet_env["gen_net"], paged=True, block_size=4,
+                           prefix_cache=True, batch_buckets=(1, 2, 4),
+                           prefill_buckets=(8,))
+
+        def burst():
+            for grp in groups:
+                for p in grp:
+                    st, body, _h = _post(
+                        fleet.port, "/v1/models/gen/generate",
+                        {"prompt_tokens": p, "max_new_tokens": 4},
+                        timeout=60)
+                    assert st == 200
+                    assert body["tokens"] == oracle.generate(
+                        [p], max_new_tokens=4)
+
+        def scrape(port, name, **labels):
+            """Every value of the series ``name`` carrying ``labels``."""
+            _st, data = _get(port, "/metrics")
+            return [float(line.rsplit(" ", 1)[1])
+                    for line in data.decode().splitlines()
+                    if line.startswith("dl4j_" + name)
+                    and all(f'{k}="{v}"' in line
+                            for k, v in labels.items())]
+
+        burst()
+        compiles = {w.worker_id: scrape(w.port,
+                                        "xla_backend_compiles_total")
+                    for w in fleet.workers}
+        burst()
+        assert compiles == {w.worker_id: scrape(
+            w.port, "xla_backend_compiles_total") for w in fleet.workers}
+        assert sum(scrape(fleet.port,
+                          "serving_fleet_routing_decisions_total",
+                          reason="affinity")) > 0
+        rates = [r for w in fleet.workers
+                 for r in scrape(fleet.port, "serving_prefix_cache_hit_rate",
+                                 worker=w.worker_id, model="gen")]
+        assert rates and all(r > 0 for r in rates)
+        assert max(rates) >= oracle.prefix_hit_rate() - 1e-6
 
     def test_sigkill_failover_and_respawn(self, fleet_env):
         fleet, x = fleet_env["fleet"], fleet_env["x"]

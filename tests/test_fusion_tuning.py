@@ -274,10 +274,10 @@ def _flagship_loss(policy, barriers, x, y):
     return net, loss_fn
 
 
-# tier-1 runtime guard (ISSUE 11 satellite): ~21s — ResNet-50 flagship
-# build under every policy; the small-net policied-step equivalence +
-# gradcheck tests above keep the remat-policy seam in tier-1, the
-# full-suite CI leg still runs the flagship
+# slow: over a minute by itself (the ResNet-50 flagship graph built and
+# compiled under every policy). In tier-1 test_mln_policied_step_matches_plain
+# and test_mln_policied_step_gradcheck above hold the remat-policy seam on
+# a small net
 @pytest.mark.slow
 def test_flagship_policied_loss_matches_plain(rng):
     """Tiny-config ResNet-50 (the flagship graph shape, stage boundaries at
@@ -294,6 +294,8 @@ def test_flagship_policied_loss_matches_plain(rng):
         np.testing.assert_allclose(float(fn(net.params)), base, rtol=1e-5)
 
 
+# slow: most of a minute by itself; test_mln_policied_step_gradcheck is the
+# tier-1 test of gradients through policied segments
 @pytest.mark.slow
 def test_flagship_policied_grad_matches_plain(rng):
     """Full jax.grad through the segmented flagship graph equals the plain

@@ -105,6 +105,23 @@ def test_mp_executor_bit_identical_to_serial(iris_csv):
         assert ex.execute(records) == serial  # exact, order included
 
 
+def test_mp_executor_worker_count_from_the_environment(iris_csv,
+                                                        monkeypatch):
+    """``num_workers=None`` takes DL4J_TPU_ETL_WORKERS: with two forced,
+    the executor forks two workers (not the serial fallback, not one per
+    core) and their merge is still the serial result."""
+    from deeplearning4j_tpu import config as cfg
+
+    monkeypatch.setenv("DL4J_TPU_ETL_WORKERS", "2")
+    monkeypatch.setattr(cfg.Environment, "_instance", None)
+    records = list(CSVRecordReader(iris_csv))
+    tp = _iris_tp()
+    ex = MultiProcessTransformExecutor(tp, min_records_per_worker=1,
+                                       timeout=HANG_S)
+    assert ex.num_workers == 2
+    assert ex.execute(records) == tp.execute(records)
+
+
 def test_mp_executor_small_input_serial_path(iris_csv):
     # below 2*min_records_per_worker the serial path runs — still identical
     records = list(CSVRecordReader(iris_csv))[:10]
@@ -332,7 +349,6 @@ def _mnist_like(n=32, seed=0):
     return x, y
 
 
-@pytest.mark.slow
 def test_sync_every_param_trajectory_equivalent():
     """sync_every only changes WHEN the host observes the loss, never the
     math: fixed-seed LeNet runs must land on bit-identical final params."""
@@ -352,7 +368,6 @@ def test_sync_every_param_trajectory_equivalent():
             np.testing.assert_array_equal(np.asarray(l1), np.asarray(l4))
 
 
-@pytest.mark.slow
 def test_sync_every_listeners_see_every_iteration_coalesced():
     x, y = _mnist_like(24)
     rec1, rec3 = _RecordingListener(), _RecordingListener()

@@ -83,10 +83,9 @@ _TF_APPS = {
 
 
 class TestTFFullModelCorpus:
-    # tier-1 runtime guard (ISSUE 11 satellite): the two heaviest goldens
-    # (NASNetMobile ~15s, InceptionV3 ~11s) carry the slow mark — eight
-    # cheaper corpus goldens keep the import seam covered in tier-1, and
-    # the full-suite CI leg still runs every model
+    # slow: the two heaviest goldens (NASNetMobile, InceptionV3: about
+    # half a minute each in the longest file but one); the other
+    # parameters of this same test are the tier-1 cases of the import seam
     @pytest.mark.parametrize(
         "name",
         [pytest.param(n, marks=pytest.mark.slow)

@@ -115,10 +115,6 @@ def _ref_conv(x, w, strides, pads, dil, g):
 
 
 class TestPallasConv:
-    # the full grid is ~10s of interpret-mode execution: the dedicated CI
-    # kernel leg runs it every time; tier-1 (-m 'not slow') keeps the two
-    # structurally distinct cases below for breadth
-    @pytest.mark.slow
     @pytest.mark.parametrize(
         "hw,k,s,d,g,cin,cout,pad", _CONV_GRID,
         ids=[f"hw{c[0]}k{c[1]}s{c[2]}d{c[3]}g{c[4]}p{c[7]}"
@@ -191,7 +187,6 @@ class TestPallasConv:
                                     - ref.astype(jnp.float32))))
         assert err < 0.1  # bf16 output quantization, fp32 accumulation
 
-    @pytest.mark.slow
     def test_conv_layer_full_fit_trajectory(self):
         """4-step conv-net fit: kernel_impl=pallas trajectory tracks exact
         within 1e-4 relative (the r12 trajectory-test convention)."""
@@ -349,7 +344,6 @@ class TestFusedLstm:
             klstm.lstm_cell_fused(xp[0], h0, c0, U, klstm.ORDER_IFOG,
                                   "interpret", 4)
 
-    @pytest.mark.slow
     def test_layer_masked_equivalence(self):
         """nn.recurrent.LSTM with a ragged (B,T) mask: pallas == exact for
         values and gradients (mask passthrough stays in the shared _scan)."""
@@ -393,7 +387,6 @@ class TestFusedLstm:
         assert _max_err(Yp, Ye) < 2e-5
         assert _max_err(Ycp, Yce) < 2e-5
 
-    @pytest.mark.slow
     def test_tbptt_full_fit_trajectory(self):
         """TBPTT-segmented LSTM fit (carries across segments, update per
         segment): pallas trajectory tracks exact within 1e-4."""

@@ -239,6 +239,9 @@ def _lenet_conf(compute_dtype="float32"):
     )
 
 
+# slow: six epochs over 2,048 MNIST digits; test_lenet_shapes_one_step below
+# is the tier-1 test of the same net, test_fit_reduces_score_and_learns_blobs
+# above that fit() learns
 @pytest.mark.slow
 def test_lenet_mnist_trains_to_high_accuracy():
     train_it = MnistDataSetIterator(batch=64, train=True, n_examples=2048)

@@ -130,7 +130,6 @@ class TestIteratorIntegration:
         assert rec[0].shape == (20, 20, 3)
 
 
-@pytest.mark.slow
 def test_throughput_report(tmp_path):
     """Measure and print pipeline throughput on a synthetic 224x224 JPEG
     corpus (the >=3k img/s target from VERDICT assumes a multi-core

@@ -82,6 +82,9 @@ class TestDecoding:
         assert all(o.confidence >= 0.9 for o in dets[:1])
 
 
+# slow: two zoo detectors built and compiled (half a minute and a quarter);
+# TestYoloLoss and TestDecoding above are the tier-1 tests of the detection
+# loss and decode, tests/test_zoo.py::test_darknet19 of the backbone
 @pytest.mark.slow
 class TestDetectionZoo:
     def test_tiny_yolo_builds_and_steps(self, rng):

@@ -66,9 +66,9 @@ def test_packed_matches_plain_mln(rng):
                                        atol=1e-6, rtol=1e-5, err_msg=k)
 
 
-# tier-1 runtime guard (ISSUE 11 satellite): ~24s — the MLN variant above
-# proves the same packed==plain contract on the cheap topology; the CG
-# twin stays in the full-suite CI leg
+# slow: most of a minute by itself (a ResNet-50 graph compiled twice);
+# test_packed_matches_plain_mln above is the tier-1 test of the same
+# packed == plain contract on the cheap topology
 @pytest.mark.slow
 def test_packed_matches_plain_cg(rng):
     from deeplearning4j_tpu.zoo import ResNet50

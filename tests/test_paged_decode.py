@@ -627,3 +627,99 @@ class TestInt8Serving:
                                       np.asarray(after[0]))
         finally:
             router.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the same contracts as a client of the HTTP server sees them
+# ---------------------------------------------------------------------------
+
+
+class TestDecodeOverHttp:
+    """One server for the class, loaded the way an operator loads it: a
+    speculative target whose draft rides in from its own archive, an int8
+    model from an int8 archive, and a decoder over a deliberately small
+    pinned pool."""
+
+    @pytest.fixture(scope="class")
+    def served(self, target_net, draft_net, tmp_path_factory):
+        from deeplearning4j_tpu.serving import ModelServer
+
+        tmp = tmp_path_factory.mktemp("decode-http")
+        fp32, int8, draft = (str(tmp / n) for n in
+                             ("bert.zip", "bert-int8.zip", "draft.zip"))
+        ModelSerializer.write_model(target_net, fp32, save_updater=False)
+        ModelSerializer.write_model(target_net, int8, quantize="int8")
+        ModelSerializer.write_model(draft_net, draft, save_updater=False)
+        buckets = "batch=1,2,4;seq=8,16"
+        router = ModelRouter(name="decode-http")
+        router.load("spec", fp32, kind="generate", bucketing=buckets,
+                    block_size=4, draft_path=draft, spec_tokens=3)
+        router.load("int8", int8, kind="generate", bucketing=buckets,
+                    block_size=4, quantize="int8")
+        # 24 blocks of 4 = 96 slots: the contiguous ceiling is 96 // 32 = 3
+        # streams, four short prompts fit paged, eight long ones do not
+        router.register(ServingModel(target_net, "tiny-pool",
+                                     kind="generate", bucketing=buckets,
+                                     block_size=4, pool_blocks=24),
+                        max_wait_ms=1.0, queue_limit=64)
+        server = ModelServer(router, port=0).start()  # warms every bucket
+        yield server, router
+        server.stop()
+
+    def test_speculative_traffic_token_identical_and_compiles_nothing(
+            self, served, ref_tokens, http_json, all_at_once):
+        server, _router = served
+        rec0 = tm.get_telemetry().counter_total("serving.recompiles_total")
+        got = all_at_once(
+            lambda prompt: http_json(
+                f"{server.url}/v1/models/spec/generate",
+                {"prompt_tokens": [prompt], "max_new_tokens": 8,
+                 "lane": "batch"}), RAGGED)
+        assert [g[0] for g in got] == [200] * len(RAGGED)
+        assert [g[1]["tokens"][0] for g in got] == ref_tokens
+        assert tm.get_telemetry().counter_total(
+            "serving.recompiles_total") == rec0
+        # what an operator reads of the speculation
+        _code, dump, _h = http_json(
+            f"{server.url}/v1/models/spec/debug/requests")
+        assert any("draft_accept_rate" in r for r in dump["requests"]
+                   if r["status"] == "ok")
+        _code, status, _h = http_json(f"{server.url}/v1/models")
+        assert status["models"]["spec"]["speculative"]["spec_tokens"] == 3
+        assert "kv_pool" in status["models"]["spec"]
+        _code, text, _h = http_json(f"{server.url}/metrics")
+        for series in ("serving_spec_accept_rate",
+                       "serving_kv_pool_blocks_free",
+                       "serving_concurrent_streams"):
+            assert series in text, series
+
+    def test_pool_exhaustion_is_429_with_retry_after_then_blocks_reused(
+            self, served, http_json):
+        server, router = served
+        url = f"{server.url}/v1/models/tiny-pool/generate"
+        code, body, hdrs = http_json(url, {"prompt_tokens": [[7] * 20] * 8,
+                                           "max_new_tokens": 8})
+        assert code == 429 and body["error"] == "PoolExhaustedError"
+        assert int(hdrs["Retry-After"]) >= 1
+        _code, dump, _h = http_json(
+            f"{server.url}/v1/models/tiny-pool/debug/requests")
+        assert "pool_exhausted" in [r.get("cause")
+                                    for r in dump["requests"]]
+        code, body, _h = http_json(
+            url, {"prompt_tokens": RAGGED + [RAGGED[0]],
+                  "max_new_tokens": 4})
+        assert code == 200 and len(body["tokens"]) == 4
+        pool = router.get("tiny-pool")[0].generator.pool
+        assert pool.peak_streams > pool.contiguous_stream_ceiling()
+
+    def test_int8_archive_serves_beside_fp32_at_a_quarter_of_the_bytes(
+            self, served, http_json):
+        server, router = served
+        code, body, _h = http_json(
+            f"{server.url}/v1/models/int8/generate",
+            {"prompt_tokens": RAGGED, "max_new_tokens": 6})
+        assert code == 200 and [len(r) for r in body["tokens"]] == [6] * 3
+        qp = router.get("int8")[0].generator._qp
+        assert qp.fp32_bytes() / qp.resident_bytes() >= 3.5
+        _code, text, _h = http_json(f"{server.url}/metrics")
+        assert "serving_weight_bytes" in text

@@ -44,17 +44,17 @@ def _builder(pipe=True, fused=False, comp=None, thresh=1e-3, updater=None):
     return b
 
 
-def _net(pipe=True, **kw):
+def _net(pipe=True, width=H, **kw):
     lb = (_builder(pipe=pipe, **kw).list()
-          .layer(DenseLayer(n_in=8, n_out=H, activation="relu"))
+          .layer(DenseLayer(n_in=8, n_out=width, activation="relu"))
           .stage_boundary()
-          .layer(DenseLayer(n_in=H, n_out=H, activation="tanh"))
-          .layer(DenseLayer(n_in=H, n_out=H, activation="relu"))
+          .layer(DenseLayer(n_in=width, n_out=width, activation="tanh"))
+          .layer(DenseLayer(n_in=width, n_out=width, activation="relu"))
           .stage_boundary()
-          .layer(DenseLayer(n_in=H, n_out=H, activation="tanh"))
-          .layer(DenseLayer(n_in=H, n_out=H, activation="relu"))
+          .layer(DenseLayer(n_in=width, n_out=width, activation="tanh"))
+          .layer(DenseLayer(n_in=width, n_out=width, activation="relu"))
           .stage_boundary()
-          .layer(OutputLayer(n_in=H, n_out=4, loss="mcxent",
+          .layer(OutputLayer(n_in=width, n_out=4, loss="mcxent",
                              activation="softmax"))
           .set_input_type(InputType.feed_forward(8)))
     return MultiLayerNetwork(lb.build()).init()
@@ -340,6 +340,34 @@ class TestPipelinedFit:
         assert ratio < 0.62, (per_dev, replicated, ratio)
         assert pt.param_bytes_per_device() < gspmd.tree_bytes(net.params)
 
+    def test_model_over_a_device_budget_places_and_trains_on_3d_mesh(
+            self, data, devices):
+        """A stage-dominated net (four 512x512 stage layers) whose
+        replicated parameters and moments exceed a declared per-device
+        budget places under it on the (data=2, model=2, pipe=2) mesh,
+        near 1/pipe_stages of the replicated bytes, and a step runs. The
+        bubble of the schedule is computed from it, never timed, and
+        published as a gauge."""
+        from deeplearning4j_tpu.parallel import gspmd
+        from deeplearning4j_tpu.util import telemetry as tm
+
+        net = _net(width=512)
+        pt = PipelinedTrainer(net, mesh=TrainingMesh(data=2, model=2, pipe=2),
+                              replicas=2, skew_every=0)
+        pt._build()
+        replicated = (gspmd.tree_bytes(net.params)
+                      + gspmd.tree_bytes(net.opt_states))
+        budget = int(replicated * 0.75)  # one device cannot hold the model
+        per_dev = pt.train_state_bytes_per_device()
+        assert per_dev < budget < replicated
+        assert per_dev / replicated < 1 / 2 + 0.12
+        pt.step_batch(DataSet(*data))
+        assert np.isfinite(float(net.score_value))
+        assert pt.bubble_fraction == pytest.approx(bubble_fraction(2, 2))
+        gauges = [v for (name, _l), v in tm.get_telemetry().gauges.items()
+                  if name == "parallel.pipeline_bubble_fraction"]
+        assert gauges and gauges[-1] == pytest.approx(1 / 3)
+
     def test_full_3d_mesh_with_tp_rules(self, data, devices):
         xs, ys = data
         ds = DataSet(xs, ys)
@@ -386,13 +414,8 @@ class TestPipelinedFit:
 
 @pytest.mark.multichip
 class TestCompositions:
-    @pytest.mark.slow
     def test_compression_t0_identity_and_checkpoint(self, data, tmp_path,
                                                     devices):
-        # slow-marked (tier-1 budget discipline): the t->0 bit-identity
-        # contract also runs in every CI pass via
-        # benchmarks/pipeline_smoke.py; this test adds the checkpointed
-        # residual + resume legs on top
         """threshold→0 compression is the exact identity encode: the
         pipelined compressed fit is BIT-identical to the uncompressed
         pipelined fit. An active threshold ships encoded wire bytes and a
@@ -433,7 +456,6 @@ class TestCompositions:
                         _leaves(nb._grad_comp_state)):
             assert np.array_equal(a, b)
 
-    @pytest.mark.slow
     def test_fused_engine_composition(self, data, devices):
         """FusedUpdateEngine composition: the pipeline-layout engine's
         trajectory tracks the unpipelined fused fit (the pipe-placement
@@ -488,7 +510,6 @@ class TestCompositions:
             for a, b in zip(_leaves(nf.params), _leaves(nr.params)):
                 assert np.array_equal(a, b)
 
-    @pytest.mark.slow
     def test_remat_policy_through_stages(self, data, devices):
         """Activation checkpointing (the r6 remat machinery) wraps each
         stage body: same values/gradients, only XLA's fwd/bwd liveness

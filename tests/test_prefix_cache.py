@@ -138,7 +138,6 @@ class TestPrefixIdentity:
         assert warm == gen_contiguous.generate(SHARED, max_new_tokens=8)
         assert st["prefix_hit_rate"] > 0
 
-    @pytest.mark.slow
     def test_speculative_identity(self, target_net, draft_net,
                                   gen_contiguous):
         """Speculative decode over a warm prefix cache: rollback of
@@ -166,7 +165,6 @@ class TestChunkedPrefill:
         assert stats["prefill_chunks"] >= 2  # 9-token prompt, 4-wide
         _conserved(gen)
 
-    @pytest.mark.slow  # tier-1 budget: covered by chunk-width invariance
     def test_chunked_plus_cache_identity(self, gen_both, gen_contiguous):
         """Both features together: chunked prefill resuming from a warm
         prefix — cold == warm == oracle."""
@@ -184,7 +182,6 @@ class TestChunkedPrefill:
         assert after > before
         _conserved(gen_both)
 
-    @pytest.mark.slow  # tier-1 budget: decode_smoke asserts this over HTTP
     def test_zero_steady_state_recompiles_mixed_traffic(self, gen_both):
         """The compile-once substrate survives the new machinery: after
         warmup, mixed hit/miss/chunked/ragged traffic traces NOTHING."""
@@ -293,7 +290,6 @@ class TestRefcountConservation:
         with pytest.raises(ValueError, match="double-free"):
             gen.pool.decref(tbl)
 
-    @pytest.mark.slow  # tier-1 budget: grow op covered by the property test
     def test_pool_grow_flushes_and_rebinds_cache(self, target_net,
                                                  gen_contiguous):
         """Auto-pool growth under prefix caching: the trie is flushed,
@@ -358,7 +354,6 @@ class TestObservability:
         assert hits and hits[-1] > 0
         assert t.gauge_values("serving.prefix_blocks_shared")
 
-    @pytest.mark.slow
     def test_flight_recorder_and_spans_attribution(self, target_net):
         """Per-phase attribution rides the scheduler: flight records and
         trace spans carry prefix_hit_rate / resumed_position /
@@ -382,3 +377,75 @@ class TestObservability:
             assert rec["prefill_chunks"] >= 1
         finally:
             sched.shutdown()
+
+
+class TestPrefixOverHttp:
+    """Sessions sharing a system prompt as clients of the HTTP server: a
+    prefix-cached, chunk-prefilled decoder over a PINNED pool, so that the
+    429 contract stays testable under prefix sharing."""
+
+    @pytest.fixture(scope="class")
+    def served(self, target_net):
+        from deeplearning4j_tpu.serving import ModelRouter, ModelServer
+
+        router = ModelRouter(name="prefix-http")
+        router.register(ServingModel(target_net, "prefix", kind="generate",
+                                     bucketing="batch=1,2,4;seq=8,16",
+                                     block_size=4, pool_blocks=24,
+                                     prefix_cache=True, prefill_chunk=8),
+                        max_wait_ms=1.0, queue_limit=64)
+        server = ModelServer(router, port=0).start()
+        yield server, router
+        server.stop()
+
+    def test_cold_and_warm_waves_identical_429_survives_sharing(
+            self, served, gen_contiguous, http_json, all_at_once):
+        server, router = served
+        url = f"{server.url}/v1/models/prefix/generate"
+        post = lambda obj: http_json(url, obj)  # noqa: E731
+        ref = gen_contiguous.generate(SHARED, max_new_tokens=6)
+        rec0 = tm.get_telemetry().counter_total("serving.recompiles_total")
+        for wave in ("cold", "warm"):
+            got = all_at_once(
+                lambda p: post({"prompt_tokens": [p], "max_new_tokens": 6,
+                                "lane": "batch"}), SHARED)
+            assert [g[0] for g in got] == [200] * len(SHARED), wave
+            assert [g[1]["tokens"][0] for g in got] == ref, wave
+        assert tm.get_telemetry().counter_total(
+            "serving.recompiles_total") == rec0
+        text = http_json(f"{server.url}/metrics")[1]
+        rates = [float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+                 if "serving_prefix_cache_hit_rate{" in line]
+        assert any(v > 0 for v in rates), rates
+        assert "serving_chunked_prefill_chunks_total" in text
+        # one scheduler batch of the flood (4 streams x 7 blocks) needs 28
+        # of the pinned 24, eviction included
+        flood = [[(i + j) % (VOCAB - 1) + 1 for j in range(20)]
+                 for i in range(8)]
+        code, body, hdrs = post({"prompt_tokens": flood,
+                                 "max_new_tokens": 8})
+        assert code == 429 and body["error"] == "PoolExhaustedError"
+        assert int(hdrs["Retry-After"]) >= 1
+        code, body, _h = post({"prompt_tokens": SHARED[:2],
+                               "max_new_tokens": 4})
+        assert code == 200
+        assert body["tokens"] == [r[:4] for r in ref[:2]]
+        _conserved(router.get("prefix")[0].generator)
+
+    def test_interactive_decodes_complete_beside_a_chunked_burst(
+            self, served, http_json, all_at_once):
+        """Long prompts (two chunks of 8) in the batch lane while short
+        interactive requests arrive: every one of both is answered, and
+        the burst's flight records say how many chunks each prefill took."""
+        server, _router = served
+        url = f"{server.url}/v1/models/prefix/generate"
+        longs = [{"prompt_tokens": [SYSTEM + [30 + i] * 7],
+                  "max_new_tokens": 6, "lane": "batch"} for i in range(6)]
+        shorts = [{"prompt_tokens": [p], "max_new_tokens": 4}
+                  for p in SHARED * 2]
+        got = all_at_once(lambda obj: http_json(url, obj), longs + shorts)
+        assert [g[0] for g in got] == [200] * 12
+        recs = http_json(f"{server.url}/v1/models/prefix/debug/requests")[
+            1]["requests"]
+        assert any(r.get("prefill_chunks", 0) >= 2 for r in recs)
+        assert any("prefix_hit_rate" in r for r in recs)

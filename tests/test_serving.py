@@ -420,6 +420,86 @@ def _post(url, obj, timeout=30):
             return e.code, {}
 
 
+class TestHttpRequestScope:
+    """What a caller sees on the wire beside the payload: the shed
+    contract's headers, the request id on every answer, and the
+    per-model debug routes. One dense server for the class."""
+
+    @pytest.fixture(scope="class")
+    def dense_server(self):
+        net = _dense_net()
+        router = ModelRouter(name="http-scope")
+        router.register(ServingModel(net, "dense"), max_wait_ms=1.0)
+        server = ModelServer(router, port=0).start()
+        yield server
+        server.stop()
+
+    X = R.normal(size=(3, 10)).astype(np.float32).tolist()
+
+    def test_request_id_echoed_on_200_and_minted_when_absent(
+            self, dense_server, http_json):
+        url = f"{dense_server.url}/v1/models/dense/infer"
+        code, body, hdrs = http_json(url, {"inputs": self.X},
+                                     request_id="rid-ok")
+        assert code == 200
+        assert hdrs.get("X-Request-Id") == body["request_id"] == "rid-ok"
+        code, body, hdrs = http_json(url, {"inputs": self.X})
+        assert code == 200 and hdrs.get("X-Request-Id")
+        assert body["request_id"] == hdrs["X-Request-Id"]
+
+    def test_shed_answers_429_with_retry_after_and_the_callers_id(
+            self, dense_server, http_json):
+        code, body, hdrs = http_json(
+            f"{dense_server.url}/v1/models/dense/infer",
+            {"inputs": self.X, "deadline_ms": -1}, request_id="rid-shed")
+        assert code == 429 and body["error"] == "DeadlineExceededError"
+        assert int(hdrs["Retry-After"]) >= 1
+        assert hdrs.get("X-Request-Id") == body["request_id"] == "rid-shed"
+        # the flight recorder's dump for the model holds the shed with its
+        # cause, and the served requests with their phase timings
+        http_json(f"{dense_server.url}/v1/models/dense/infer",
+                  {"inputs": self.X})
+        code, dump, _h = http_json(
+            f"{dense_server.url}/v1/models/dense/debug/requests?last=64")
+        recs = dump["requests"]
+        assert code == 200
+        assert any(r["id"] == "rid-shed" and r["status"] == "shed"
+                   and r["cause"] == "deadline" for r in recs)
+        assert any(r["status"] == "ok" and r["compute_ms"] is not None
+                   and r["total_ms"] >= r["compute_ms"] for r in recs)
+
+    @pytest.mark.parametrize("method,path", [
+        ("POST", "/v1/models/ghost/infer"),
+        ("GET", "/v1/models/ghost/debug/requests"),
+        ("GET", "/v1/nothing"),
+    ])
+    def test_unknown_model_or_route_answers_404(self, dense_server,
+                                                http_json, method, path):
+        body = {"inputs": self.X} if method == "POST" else None
+        assert http_json(dense_server.url + path, body)[0] == 404
+
+    def test_slo_route_serves_burn_rate_windows(self, dense_server,
+                                                http_json):
+        from deeplearning4j_tpu.util import slo
+
+        slo.register(slo.SloObjective("http-avail", "availability",
+                                      target=0.5, model="dense"))
+        try:
+            http_json(f"{dense_server.url}/v1/models/dense/infer",
+                      {"inputs": self.X})
+            code, doc, _h = http_json(f"{dense_server.url}/slo")
+            objs = {o["name"]: o for o in doc["objectives"]}
+            assert code == 200
+            assert "burn_rate" in objs["http-avail"]["windows"]["60s"]
+            assert objs["http-avail"]["compliant"] is True
+            _code, text, _h = http_json(f"{dense_server.url}/metrics")
+            assert 'dl4j_slo_burn_rate{slo="http-avail"' in text
+            assert "serving_queue_depth" in text
+            assert "serving_request_latency_seconds" in text
+        finally:
+            slo.reset()
+
+
 class TestHttpServer:
     def test_infer_shed_and_drain_on_sigterm(self):
         """The HTTP contract end-to-end: 200 with bit-identical outputs,

@@ -132,9 +132,9 @@ class TestRegistry:
         # round-trips through JSON (Perfetto-loadable)
         assert json.loads(json.dumps(trace))["traceEvents"]
 
-    def test_event_ring_bounds_memory(self, _clean_registry):
+    def test_event_ring_bounds_memory(self, _clean_registry, monkeypatch):
         tele = _clean_registry
-        tele.max_events = 10
+        monkeypatch.setattr(tele, "max_events", 10)  # the registry is global
         for i in range(25):
             tele.event(f"e{i}", 0, 1)
         assert len(tele.drain_events()) == 10
@@ -283,6 +283,74 @@ class TestInstrumentation:
                    and e["pid"] == os.getpid() for e in events)
         snap = _clean_registry.snapshot()
         assert snap["counters"]["etl.records_total"] == 64
+
+    @pytest.mark.skipif(not hasattr(os, "fork"), reason="needs fork")
+    def test_whole_input_pipeline_lands_in_one_trace_and_one_scrape(
+            self, rng, _clean_registry, tmp_path):
+        """Every instrumented layer in one two-step fit: forked ETL
+        workers, the prefetch thread, a bucketed step with the health
+        monitor and coalesced listener dispatch. One Chrome trace holds
+        rows of three or more (pid, thread) pairs from two or more
+        processes, every event well-formed; one scrape holds each layer's
+        series; the stats records carry the telemetry group."""
+        from deeplearning4j_tpu.data import AsyncDataSetIterator
+        from deeplearning4j_tpu.datavec import (
+            CollectionRecordReader, ParallelTransformRecordReader,
+            RecordReaderDataSetIterator, Schema, TransformProcess)
+        from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
+                                           NeuralNetConfiguration)
+        from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
+        from deeplearning4j_tpu.nn.updaters import Adam
+        from deeplearning4j_tpu.util import (InMemoryStatsStorage,
+                                             StatsListener)
+
+        records = [[float(v) for v in rng.normal(size=4)]
+                   + [int(rng.integers(0, 3))] for _ in range(256)]
+        sb = Schema.builder()
+        sb.add_column_double(*[f"f{i}" for i in range(4)])
+        sb.add_column_integer("label")
+        tp = (TransformProcess.builder(sb.build())
+              .double_math_op("f0", "multiply", 2.0).build())
+        reader = ParallelTransformRecordReader(
+            CollectionRecordReader(records), tp, num_workers=2)
+        reader.executor.min_records_per_worker = 8  # fork on a tiny input
+        it = RecordReaderDataSetIterator(reader, batch_size=128,
+                                         label_index=4, num_classes=3)
+        conf = (NeuralNetConfiguration.builder().seed(0).updater(Adam(1e-2))
+                .sync_every(2).batch_buckets((128,)).list()
+                .layer(DenseLayer(n_in=4, n_out=16, activation="relu"))
+                .layer(OutputLayer(n_in=16, n_out=3, loss="mcxent",
+                                   activation="softmax"))
+                .set_input_type(InputType.feed_forward(4)).build())
+        net = MultiLayerNetwork(conf).init()
+        storage = InMemoryStatsStorage()
+        net.set_listeners(TrainingHealthMonitor(window=2, log_fn=None),
+                          StatsListener(storage, collect_histograms=False))
+        net.fit(AsyncDataSetIterator(it, buffer_size=2), epochs=1)
+        assert net.iteration == 2
+        assert storage.records and "telemetry" in storage.records[-1]
+
+        text = tm.install_default_collectors().prometheus_text()
+        for series in ("dl4j_xla_backend_compiles_total",
+                       "dl4j_train_step_seconds_count",
+                       "dl4j_prefetch_queue_depth", "dl4j_train_steps_total",
+                       "dl4j_etl_chunks_total", "dl4j_health_loss_ewma"):
+            assert series in text, series
+        ok, checks = _clean_registry.health_report()
+        assert ok and "training.finite" in checks
+
+        with open(_clean_registry.write_chrome_trace(
+                str(tmp_path / "trace.json"))) as f:
+            events = json.load(f)["traceEvents"]
+        for e in events:
+            assert isinstance(e["name"], str) and e["ph"] in ("X", "i", "M")
+            assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
+            assert e["ph"] == "M" or isinstance(e["ts"], (int, float))
+            assert e["ph"] != "X" or isinstance(e["dur"], (int, float))
+        rows = {(e["pid"], e["tid"]) for e in events if e["ph"] == "X"}
+        assert len(rows) >= 3 and len({p for p, _ in rows}) >= 2
+        assert {"mln.train_step", "prefetch.etl_wait", "etl.transform_chunk",
+                "listeners.flush"} <= {e["name"] for e in events}
 
     def test_parallel_wrapper_skew_probe(self, rng, _clean_registry):
         from deeplearning4j_tpu.data import ArrayDataSetIterator

@@ -79,6 +79,9 @@ def test_resnet50_structure():
     np.testing.assert_allclose(np.asarray(out).sum(axis=1), 1.0, atol=1e-3)
 
 
+# slow: a minute by itself (ResNet-50's train step compiled, 15 steps);
+# test_resnet50_structure above is the tier-1 test of the graph, and
+# tests/chipbench_tests/test_chipbench_run_train.py trains it
 @pytest.mark.slow
 def test_resnet50_learns():
     model = ResNet50(num_classes=4, input_shape=(32, 32, 3))

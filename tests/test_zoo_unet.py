@@ -52,10 +52,14 @@ def test_unet_conv_cost_model_attributes_the_dag(rng):
         assert conv_flops > 0.5 * total_attr, (conv_flops, total_attr)
 
 
+# slow: the U-Net compiled for eight virtual devices. In tier-1
+# test_unet_builds_and_fits_one_batch above fits the same DAG on one device
+# and tests/test_compression.py::TestConvergenceParity::
+# test_compressed_fit_tracks_exact_fit trains through the same encoded path
 @pytest.mark.slow
 @pytest.mark.multichip
 def test_unet_compressed_dp_fit_end_to_end(rng):
-    """The ISSUE's one slow leg: the diffusion U-Net trains through the
+    """The diffusion U-Net trains through the
     encoded-gradient DP path (threshold scheme, adaptive sparsity) on the
     8-virtual-device mesh — loss decreases, the wire accounting reports,
     and the residual state matches the DAG's gradient structure."""
