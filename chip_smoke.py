@@ -42,6 +42,7 @@ BF16_REL_TOL = 4e-2
 #: the KDA prefill against the recurrence: one bfloat16 rounding (2**-8) of
 #: the largest value, twice over (docs/KERNELS.md)
 KDA_REL_TOL = 2 ** -7
+KDA_STEP_REL_TOL = 1e-5     # the decode step: float32, no matrix product
 
 
 def say(msg: str) -> None:
@@ -270,6 +271,22 @@ def _kernel_case(name: str, kernel_fn, exact_fn, *args) -> None:
             f"path, worst relative error {max(errs):.2e}")
 
 
+def _kda_draws(seed: int, b: int, t: int, h: int, d: int):
+    """q, k, v, g (b, t, h, d) and beta (b, t, h) as a KDA layer makes them:
+    unit q and k after SiLU, log decays about -0.05, beta in (0, 1)."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+    draw = lambda key: jax.nn.silu(jax.random.normal(key, (b, t, h, d)))
+    q, k, v = unit(draw(ks[0])) * d ** -0.5, unit(draw(ks[1])), draw(ks[2])
+    g = -jnp.exp(jax.random.normal(ks[3], (b, t, h, d)) * 0.5 - 3.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    return q, k, v, g, beta
+
+
 def _kda_prefill_case(b: int = 16, t: int = 1024, h: int = 32,
                       d: int = 128) -> None:
     """``kda_chunked`` as the serving path calls it on a TPU (one layer at
@@ -282,13 +299,7 @@ def _kda_prefill_case(b: int = 16, t: int = 1024, h: int = 32,
 
     from deeplearning4j_tpu.ops import kda
 
-    ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    unit = lambda a: a * jax.lax.rsqrt(
-        jnp.sum(a * a, -1, keepdims=True) + 1e-6)
-    draw = lambda key: jax.nn.silu(jax.random.normal(key, (b, t, h, d)))
-    q, k, v = unit(draw(ks[0])) * d ** -0.5, unit(draw(ks[1])), draw(ks[2])
-    g = -jnp.exp(jax.random.normal(ks[3], (b, t, h, d)) * 0.5 - 3.0)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    q, k, v, g, beta = _kda_draws(3, b, t, h, d)
     s0 = jnp.zeros((b, h, d, d), jnp.float32)
     ragged = np.ones((b,), np.int32)
     ragged[:9] = np.minimum([512, 612, 434, 300, 389, 282, 530, t, 381], t)
@@ -316,6 +327,55 @@ def _kda_prefill_case(b: int = 16, t: int = 1024, h: int = 32,
         require(max(errs) <= KDA_REL_TOL,
                 f"kda prefill, {name}: o and the state match kda_recurrent, "
                 f"relative errors {errs[0]:.2e} {errs[1]:.2e}")
+
+
+def _kda_decode_case(b: int = 16, h: int = 32, d: int = 128,
+                     n_slots: int = 17) -> None:
+    """``kda_step_paged`` as the serving path calls it on a TPU (one layer
+    of ``kimiL-chat-open``'s decode step: 16 rows of one token, a pool of
+    17 slots): the kernel, against ``kda_step`` on the gathered rows in
+    float32 at ``highest``, with a batch's rows (live rows in permuted
+    slots, a finished row that keeps its slot, padding rows on slot 0) and
+    with every row live. Float32 on the vector unit: ``KDA_STEP_REL_TOL``.
+    Every slot no live row names is the pool's, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import kda
+
+    q, k, v, g, beta = _kda_draws(5, b, 1, h, d)
+    pool = jax.random.normal(jax.random.PRNGKey(6), (n_slots, h, d, d))
+
+    def oracle(q, k, v, g, beta, pool, slots):
+        with jax.default_matmul_precision("highest"):
+            return kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], pool[slots])
+
+    args = (q, k, v, g, beta, pool)
+    mixed = np.zeros((b,), np.int32)
+    mixed[:9] = [9, 4, 16, 1, 7, 12, 3, 8, 5]
+    mixed_live = np.arange(b) < 9
+    mixed_live[4] = False                       # finished, keeps slot 7
+    every = np.arange(1, b + 1, dtype=np.int32)
+    kernel, mosaic = _compile_on_chip(
+        kda.kda_step_paged, *args, jnp.asarray(mixed),
+        jnp.asarray(mixed_live)[:, None])
+    require(mosaic, "kda decode: kda_step_paged on a TPU is a Mosaic kernel")
+    oracle = jax.jit(oracle)
+    for name, slots, live in (("a batch's rows", mixed, mixed_live),
+                              ("every row live", every, np.ones(b, bool))):
+        o, new = kernel(*args, jnp.asarray(slots), jnp.asarray(live)[:, None])
+        o_r, s_r = oracle(*args, jnp.asarray(slots))
+        named = slots[live]
+        errs = (rel_err(o[:, 0][live], o_r[live]),
+                rel_err(new[named], s_r[live]))
+        require(max(errs) <= KDA_STEP_REL_TOL,
+                f"kda decode, {name}: o and the named slots match kda_step, "
+                f"relative errors {errs[0]:.2e} {errs[1]:.2e}")
+        rest = np.setdiff1d(np.arange(n_slots), named)
+        require(bool(jnp.array_equal(new[rest], pool[rest])),
+                f"kda decode, {name}: the {len(rest)} slots no live row "
+                "names are bit-identical")
 
 
 def leg_kernels() -> None:
@@ -400,6 +460,7 @@ def leg_kernels() -> None:
                                          False, 8),
         conv_under("exact"), arr(8, 56, 56, 64), arr(3, 3, 64, 64, scale=0.05))
     _kda_prefill_case()
+    _kda_decode_case()
     # stride 2 is a geometry supports() admits and Mosaic (JAX 0.9.0) refuses
     # to lower: forced pallas must say so, never run another path instead
     x2, w2 = arr(8, 56, 56, 256), arr(1, 1, 256, 128, scale=0.05)

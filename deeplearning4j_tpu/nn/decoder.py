@@ -424,14 +424,13 @@ class HybridDecoderBlock(Layer):
         if self.mixer == "kda":
             before = pool["conv"][where]
             q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, before)
-            m = live.astype(F32)
-            o, s = kda.kda_recurrent(q, k, v, g * m[..., None, None],
-                                     beta * m[..., None],
-                                     pool["state"][where])
+            # each live stream's state, stepped in its slot of the pool
+            o, state = kda.kda_step_paged(q, k, v, g, beta, pool["state"],
+                                          where, live)
             width = self.conv_size - 1
             seen = jnp.concatenate([before, raw], axis=1)
             tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
-            pool = dict(pool, state=pool["state"].at[where].set(s),
+            pool = dict(pool, state=state,
                         conv=pool["conv"].at[where].set(tail))
             a = self._kda_out(params, o, gate)
         else:

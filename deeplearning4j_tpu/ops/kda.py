@@ -25,6 +25,11 @@ Three forms of the same recurrence live here:
   the chip, and a row's walk ends with the chunk its length ends in; the XLA
   form runs everywhere else and computes every chunk (docs/KERNELS.md).
 - :func:`kda_step` — the decode step, one token against the state.
+  :func:`kda_step_paged` runs it on the streams' slots of a state pool: on
+  a TPU, with heads in whole 128-lane tiles and one token a row, one Pallas
+  kernel (``kda_decode``) whose state block is the row's slot, read and
+  written in place; everywhere else the rows are gathered, stepped and
+  scattered.
 
 A token with ``beta = 0`` and ``g = 0`` leaves the state as it was: that is
 how padded positions and finished rows are kept from moving a live state.
@@ -385,6 +390,12 @@ def _kda_chunked_pallas(q, k, v, g, beta, state, lengths=None,
     return o[:, :t].reshape(b, t, h, dv), s
 
 
+def _kernel_shapes(q, v) -> bool:
+    """Where the Pallas kernels run: a TPU, heads in whole 128-lane tiles."""
+    return (jax.default_backend() == "tpu" and q.shape[-1] % 128 == 0
+            and v.shape[-1] % 128 == 0)
+
+
 def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
     """The recurrence chunk-wise: the arguments and results of
     :func:`kda_recurrent`, plus ``lengths`` (B,): a token at or behind its
@@ -393,10 +404,111 @@ def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
     is padded to whole chunks. On a TPU, with heads in whole 128-lane
     tiles, one kernel walks the chunks the lengths cover
     (:func:`_kda_chunked_pallas`); everywhere else the XLA form runs."""
-    if (jax.default_backend() == "tpu" and q.shape[-1] % 128 == 0
-            and v.shape[-1] % 128 == 0):
+    if _kernel_shapes(q, v):
         return _kda_chunked_pallas(q, k, v, g, beta, state, lengths, chunk)
     return _kda_chunked_xla(q, k, v, g, beta, state, lengths, chunk)
+
+
+# -- the decode step in place in the pool ------------------------------------
+DECODE_HEAD_GROUP = 16  # heads of one grid step (docs/KERNELS.md: measured)
+
+
+def _kda_decode_kernel(slot_ref, x_ref, v_ref, s_in, o_ref, s_out, t_ref, *,
+                       heads):
+    """Grid (row, head group). ``x_ref`` holds the group's q | k | exp(g) |
+    beta k, a head a row (4 x heads, dk); transposed once a grid step they
+    are the columns that meet the state's rows. The state block is the
+    row's slot of the pool, read and written in place; a dead row (slot 0)
+    keeps the trash slot's first block as it is and computes nothing."""
+    from jax.experimental import pallas as pl
+
+    live = slot_ref[pl.program_id(0)] != 0
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        s_out[...] = s_in[...]
+
+    @pl.when(live)
+    def _():
+        t_ref[0:4 * heads, :] = x_ref[0, 0]
+        cols = t_ref[...].T                       # (dk, 128): a head a lane
+        for j in range(heads):
+            col = lambda n: cols[:, n * heads + j:n * heads + j + 1]
+            s = s_in[0, j] * col(2)
+            d = v_ref[0, 0, j:j + 1, :] \
+                - jnp.sum(s * col(1), axis=0, keepdims=True)
+            s = s + col(3) * d
+            s_out[0, j] = s
+            o_ref[0, 0, j:j + 1, :] = jnp.sum(s * col(0), axis=0,
+                                              keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "head_group"))
+def _kda_decode_pallas(q, k, v, g, beta, pool, slots, interpret: bool = False,
+                       head_group: int = DECODE_HEAD_GROUP):
+    """:func:`kda_step` on the slots of a pool, in place: q, k, g (B, H,
+    dk), v (B, H, dv), beta (B, H), ``pool`` (slots, H, dk, dv) float32,
+    ``slots`` (B,) with every dead row on the trash slot 0 -> (o (B, H,
+    dv), pool). The state's block index is ``slots[row]`` for input and
+    output alike and the pool is aliased input to output, so a live
+    stream's state moves once each way and no other slot is touched; dead
+    rows share one block of the trash slot, which is fetched once a run of
+    them. Float32 on the vector unit throughout. Jitted, so that a program
+    of twenty such layers lowers the kernel once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    hg = math.gcd(h, min(head_group, 32))     # 4 x hg rows of a 128-row tile
+    ng = h // hg
+    grp = lambda a: a.astype(f32).reshape(b, ng, hg, -1)
+    x = jnp.concatenate([grp(q), grp(k), jnp.exp(grp(g)),
+                         grp(beta.astype(f32)[..., None] * k)], axis=2)
+    # a dead row's blocks are those of the dead step before it: not fetched
+    group = lambda i, j, s: jnp.where(s[i] == 0, 0, j)
+    rows = lambda i, j, s: (jnp.where(s[i] == 0, 0, i), group(i, j, s), 0, 0)
+    states = pl.BlockSpec((1, hg, dk, dv),
+                          lambda i, j, s: (s[i], group(i, j, s), 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_kda_decode_kernel, heads=hg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, ng),
+            in_specs=[pl.BlockSpec((1, 1, 4 * hg, dk), rows),
+                      pl.BlockSpec((1, 1, hg, dv), rows), states],
+            out_specs=[pl.BlockSpec((1, 1, hg, dv),
+                                    lambda i, j, s: (i, j, 0, 0)), states],
+            scratch_shapes=[pltpu.VMEM((128, dk), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, ng, hg, dv), f32),
+                   jax.ShapeDtypeStruct(pool.shape, f32)],
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="kda_decode",
+    )(slots.astype(jnp.int32), x, grp(v), pool)
+    return o.reshape(b, h, dv), pool
+
+
+def kda_step_paged(q, k, v, g, beta, pool, slots, live):
+    """The decode window on the streams' slots of a state pool: q, k, g
+    (B, W, H, dk), v (B, W, H, dv), beta (B, W, H), ``pool`` (slots, H, dk,
+    dv) float32, ``slots`` (B,), ``live`` (B, W) bool -> (o (B, W, H, dv),
+    pool). A token that is not live moves no state. On a TPU, with heads in
+    whole 128-lane tiles and a window of one token, each live row's state
+    is read from its slot and written back there by one kernel
+    (:func:`_kda_decode_pallas`) and a dead row names the trash slot;
+    everywhere else the rows are gathered, stepped and scattered."""
+    if q.shape[1] == 1 and _kernel_shapes(q, v):
+        o, pool = _kda_decode_pallas(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], pool,
+            jnp.where(live[:, 0], slots, 0))
+        return o[:, None], pool
+    m = live.astype(jnp.float32)
+    o, s = kda_recurrent(q, k, v, g * m[..., None, None],
+                         beta * m[..., None], pool[slots])
+    return o, pool.at[slots].set(s)
 
 
 def causal_conv(x, w, tail=None):

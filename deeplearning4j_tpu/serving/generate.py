@@ -169,8 +169,10 @@ class Generator:
         self.net = net
         self.model_id = str(model_id)
         self.max_length = int(max_length or self.emb.max_position)
-        #: a net with per-stream state layers (module doc: what it refuses)
-        self.recurrent = any(cache_kind(b) == "state" for b in self.blocks)
+        #: layers that keep a per-stream state; a net with any is
+        #: recurrent (module doc: what it refuses)
+        self._kda_layers = sum(cache_kind(b) == "state" for b in self.blocks)
+        self.recurrent = self._kda_layers > 0
         if self.recurrent:
             for on, name in ((prefix_cache, "prefix_cache (and its "
                               "copy-on-write)"),
@@ -258,6 +260,8 @@ class Generator:
         self._kv_read = self._kv_declared = 0
         #: (row, chunk) pairs the KDA prefills held / declared, a layer
         self._kda_live = self._kda_declared = 0
+        #: stream states the KDA decode steps moved / the bucket declared
+        self._kda_states_live = self._kda_states_declared = 0
         #: nesting depth of generate() — > 1 while a chunk-yield runs a
         #: nested decode batch; nested runs never grow/reset the pool
         self._depth = 0
@@ -883,6 +887,20 @@ class Generator:
         tm.counter("serving.kda_prefill_chunks_declared_total", declared,
                    model=self.model_id)
 
+    def _count_kda_states(self, batch: int, b_real: int, steps: int):
+        """One batch's decode steps onto the counters: the stream states a
+        step reads and writes in place (``ops/kda.kda_step_paged``: a live
+        row's, in every recurrent layer) against the bucket's rows, which
+        a gather and scatter of the declared batch moved."""
+        live = steps * b_real * self._kda_layers
+        declared = steps * batch * self._kda_layers
+        self._kda_states_live += live
+        self._kda_states_declared += declared
+        tm.counter("serving.kda_decode_states_live_total", live,
+                   model=self.model_id)
+        tm.counter("serving.kda_decode_states_declared_total", declared,
+                   model=self.model_id)
+
     @staticmethod
     def _moe_totals(pools):
         """The routed layers' counters, summed over the layers: (2, 5)
@@ -962,6 +980,8 @@ class Generator:
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
         self._count_kv_read(batch, kv_read, len(steps) - 1)
+        if self.recurrent:
+            self._count_kda_states(batch, b_real, len(steps) - 1)
         self._count_moe()
         stacked = np.stack([np.asarray(s) for s in steps], axis=1)
         return self._trim(stacked, b_real, lens, max_new, eos_id)
@@ -1294,6 +1314,10 @@ class Generator:
             s["kda_prefill_chunk_share"] = (
                 round(self._kda_live / self._kda_declared, 4)
                 if self._kda_declared else None)
+            # share of the bucket's rows whose state the decode steps moved
+            s["kda_decode_state_share"] = (
+                round(self._kda_states_live / self._kda_states_declared, 4)
+                if self._kda_states_declared else None)
         if self.cache is not None:
             s["prefix_cache"] = self.cache.stats()
         if self.prefill_chunk is not None:

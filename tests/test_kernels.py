@@ -901,6 +901,51 @@ class TestMosaicCrossLowering:
             f32(2, 2, dk, dv), _aval(2, dtype=jnp.int32))
         assert "tpu_custom_call" not in text
 
+    def test_kda_decode_at_the_cells_shape(self, monkeypatch):
+        """``kimiL-chat-open``'s decode bucket, one layer: 16 rows of one
+        token against a pool of 17 slots, 32 heads of 128. On a TPU the
+        step is one Mosaic kernel whose pool result aliases its pool
+        operand, and no gather or scatter of the pool is left beside it;
+        the CPU program of the same call holds no kernel."""
+        from jax import export
+
+        from deeplearning4j_tpu.ops import kda
+
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        x = f32(16, 1, 32, 128)
+        avals = (x, x, x, x, f32(16, 1, 32), f32(17, 32, 128, 128),
+                 _aval(16, dtype=jnp.int32), _aval(16, 1, dtype=jnp.bool_))
+        text = _lower_for_tpu(kda.kda_step_paged, *avals)
+        assert text.count("tpu_custom_call") == 1
+        assert "output_operand_aliases" in text
+        assert "stablehlo.gather" not in text
+        assert "stablehlo.scatter" not in text
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: kda.kda_step_paged(*a)   # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+
+    @pytest.mark.parametrize("backend,w,dk,dv", [
+        ("tpu", 1, 16, 8), ("tpu", 1, 128, 64), ("tpu", 2, 128, 128),
+        ("cpu", 1, 128, 128)],
+        ids=["tpu-16x8", "tpu-128x64", "tpu-window-2", "cpu-128x128"])
+    def test_kda_decode_elsewhere_is_the_xla_form(self, monkeypatch, backend,
+                                                  w, dk, dv):
+        """Heads that are not whole 128-lane tiles, a window of more than
+        one token, and any backend but the TPU gather, step and scatter."""
+        from deeplearning4j_tpu.ops import kda
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        text = _lower_for_tpu(
+            kda.kda_step_paged, f32(4, w, 2, dk), f32(4, w, 2, dk),
+            f32(4, w, 2, dv), f32(4, w, 2, dk), f32(4, w, 2),
+            f32(5, 2, dk, dv), _aval(4, dtype=jnp.int32),
+            _aval(4, w, dtype=jnp.bool_))
+        assert "tpu_custom_call" not in text
+        assert "stablehlo.scatter" in text
+
     def test_resnet50_forward_reaches_no_kernel(self):
         """The flagship at its default conf on a TPU host: 53 convolutions,
         every one on the exact path — before PR 21, 51 of them routed to

@@ -253,6 +253,153 @@ def test_kda_prefill_counters_follow_the_lengths(served):
     assert "kda_prefill_chunk_share" not in bert.pool_stats()
 
 
+# --------------------------------------------- the decode step in the pool
+def _decode_inputs(key, b, w, h, dk, dv, n_slots):
+    q, k, v, g, beta, _ = _kda_inputs(key, b, w, h, dk, dv, False)
+    pool = jax.random.normal(jax.random.fold_in(key, 7), (n_slots, h, dk, dv))
+    return q, k, v, g, beta, pool
+
+
+def _gather_step_scatter(q, k, v, g, beta, pool, slots, live):
+    """The decode step as it stood before the pool entry: the oracle."""
+    m = live.astype(jnp.float32)
+    o, s = kda.kda_recurrent(q, k, v, g * m[..., None, None],
+                             beta * m[..., None], pool[slots])
+    return o, pool.at[slots].set(s)
+
+
+# slots (B,), rows past their limit (live False with a slot of their own)
+DECODE_ROWS = {
+    "every-row-live": ([3, 1, 6, 4], [True] * 4),
+    "dead-rows-share-slot-0": ([3, 0, 6, 0, 0, 2], [True, False, True,
+                                                    False, False, True]),
+    "permuted-slots": ([6, 2, 5, 1, 3], [True] * 5),
+    "one-live-row": ([0, 0, 4, 0], [False, False, True, False]),
+    "a-finished-row-keeps-its-slot": ([2, 5, 1], [True, False, True]),
+    "a-dead-row-first-and-between": ([0, 3, 5, 6], [False, True, False,
+                                                    True]),
+}
+
+
+@pytest.mark.parametrize("heads", [2, 8])
+@pytest.mark.parametrize("rows", DECODE_ROWS)
+def test_the_decode_kernel_is_the_step_on_the_named_slots(rows, heads):
+    """The kernel under the interpreter at 128-wide heads, two head groups
+    a row: o of the live rows and their slots are ``kda_step`` on the
+    gathered rows to 1e-5 of the largest value; every slot the step does not
+    name, every slot a dead row names and the trash slot are bit-identical;
+    a dead row's o is 0."""
+    slots, live = DECODE_ROWS[rows]
+    q, k, v, g, beta, pool = _decode_inputs(
+        jax.random.PRNGKey(len(slots)), len(slots), 1, 2 * heads, 128, 128, 7)
+    slots, live = jnp.asarray(slots, jnp.int32), np.asarray(live)
+    one = lambda a: a[:, 0]
+    o, new = kda._kda_decode_pallas(
+        one(q), one(k), one(v), one(g), one(beta), pool,
+        jnp.where(live, slots, 0), interpret=True, head_group=heads)
+    want_o, want_s = kda.kda_step(one(q), one(k), one(v), one(g), one(beta),
+                                  pool[slots])
+    named = np.asarray(slots)[live]
+    tol = lambda a: 1e-5 * float(jnp.abs(a).max())
+    np.testing.assert_allclose(o[live], want_o[live], atol=tol(want_o))
+    np.testing.assert_allclose(new[named], want_s[live], atol=tol(want_s))
+    assert float(jnp.abs(new[named] - pool[named]).max()) > 0.01
+    assert not np.asarray(o[~live]).any()
+    rest = np.asarray([i for i in range(7) if i not in set(named.tolist())])
+    np.testing.assert_array_equal(new[rest], pool[rest])
+
+
+@pytest.mark.parametrize("case,w,dk,dv", [("narrow-heads", 1, 16, 8),
+                                          ("a-window-of-two", 2, 128, 128),
+                                          ("one-token-on-a-cpu", 1, 128, 128)])
+def test_the_pool_entry_off_the_kernels_path_is_gather_step_scatter(case, w,
+                                                                    dk, dv):
+    """Narrow heads, a window of two tokens, and any shape off the TPU take
+    the XLA form, bit for bit what ``decode_window_paged`` did before."""
+    q, k, v, g, beta, pool = _decode_inputs(jax.random.PRNGKey(w), 4, w, 2,
+                                            dk, dv, 6)
+    slots = jnp.asarray([5, 0, 2, 4], jnp.int32)
+    live = jnp.asarray([[True] * w, [False] * w, [True] + [False] * (w - 1),
+                        [True] * w])
+    got = jax.jit(kda.kda_step_paged)(q, k, v, g, beta, pool, slots, live)
+    want = jax.jit(_gather_step_scatter)(q, k, v, g, beta, pool, slots, live)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(got[1][jnp.asarray([1, 3])],
+                                  pool[jnp.asarray([1, 3])])
+
+
+def test_the_pool_entry_takes_the_kernel_where_the_chip_would(monkeypatch):
+    """Steered to the TPU's branch (the kernel itself through the
+    interpreter): one token a row at 128-wide heads goes to the kernel with
+    the dead rows on slot 0, and the result is the XLA form's."""
+    calls = []
+    kernel = kda._kda_decode_pallas
+
+    def spy(*a, **kw):
+        calls.append(np.asarray(a[6]).tolist())
+        return kernel(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(kda, "_kda_decode_pallas", spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k, v, g, beta, pool = _decode_inputs(jax.random.PRNGKey(2), 4, 1, 2,
+                                            128, 128, 6)
+    slots = jnp.asarray([5, 0, 2, 4], jnp.int32)
+    live = jnp.asarray([[True], [False], [False], [True]])
+    o, new = kda.kda_step_paged(q, k, v, g, beta, pool, slots, live)
+    assert calls == [[5, 0, 0, 4]]
+    want_o, want = _gather_step_scatter(q, k, v, g, beta, pool, slots, live)
+    keep = np.asarray(live[:, 0])
+    np.testing.assert_allclose(o[keep], want_o[keep], atol=1e-5)
+    np.testing.assert_allclose(new[1:], want[1:], atol=1e-5)
+    np.testing.assert_array_equal(new[jnp.asarray([0, 1, 2, 3])],
+                                  pool[jnp.asarray([0, 1, 2, 3])])
+    # narrow heads and a wider window stay off it, on a TPU too
+    for w, d in ((1, 16), (2, 128)):
+        a = _decode_inputs(jax.random.PRNGKey(3), 4, w, 2, d, d, 6)
+        kda.kda_step_paged(*a, slots, jnp.ones((4, w), bool))
+    assert len(calls) == 1
+
+
+def test_kda_decode_counters_follow_the_live_rows(served):
+    """Host arithmetic, no device fetch: a batch of three streams in a
+    bucket of four moves 3 of 4 declared states a KDA layer a decode step;
+    both series reach ``/metrics`` and their ratio ``pool_stats()``; a net
+    without a ``"state"`` block reports neither."""
+    from deeplearning4j_tpu.util import telemetry as tm
+
+    _, gen = served
+    tele = tm.get_telemetry()
+    read = lambda: [tele.counter_total(
+        f"serving.kda_decode_states_{n}_total", model=gen.model_id)
+        for n in ("live", "declared")]
+    kda_layers = sum(b.mixer == "kda" for b in gen.blocks)
+    assert kda_layers == 3
+    l0, d0 = read()
+    gen.generate(PROMPTS, max_new_tokens=5)            # 4 decode steps
+    l1, d1 = read()
+    assert (l1 - l0, d1 - d0) == (4 * 3 * kda_layers, 4 * 4 * kda_layers)
+    gen.generate(PROMPTS[:1], max_new_tokens=2)        # 1 step, 1 live row
+    l2, d2 = read()
+    assert (l2 - l1, d2 - d1) == (kda_layers, 4 * kda_layers)
+    gen.generate(PROMPTS, max_new_tokens=1)            # no decode step
+    assert read() == [l2, d2]
+    share = gen.pool_stats()["kda_decode_state_share"]
+    assert share == round(gen._kda_states_live / gen._kda_states_declared, 4)
+    assert 0.25 < share <= 0.75
+    text = tele.prometheus_text()
+    for n in ("live", "declared"):
+        assert f"dl4j_serving_kda_decode_states_{n}_total" in text
+    from deeplearning4j_tpu.zoo import Bert
+    bert = Generator(Bert.tiny(causal=True, task="mlm", vocab_size=32,
+                               max_length=32).init(), block_size=8,
+                     model_id="bert-no-kda")
+    bert.generate([[1, 2, 3]], max_new_tokens=3)
+    assert "kda_decode_state_share" not in bert.pool_stats()
+    assert tele.counter_total("serving.kda_decode_states_live_total",
+                              model="bert-no-kda") == 0
+
+
 def test_conv_tail_of_ragged_rows():
     x = jnp.arange(2 * 6 * 1, dtype=jnp.float32).reshape(2, 6, 1) + 1
     tail = kda.conv_tail(x, jnp.asarray([6, 2]), 3)
