@@ -112,11 +112,12 @@ def training(cell: Cell, seeds, control_seeds) -> None:
 def serving(cell: Cell, seeds, control_seeds, seconds: float) -> None:
     """One server for all the seeds: each seed's weights are put into the
     running model, a short window at the cell's own load is driven, and the
-    finished requests are judged."""
+    finished requests are judged. A seed's weights are let go of before
+    the next are made: two sets of a large configuration do not fit a
+    chip."""
     import jax.numpy as jnp
 
     from chipbench import serve, traffic
-    from chipbench.manifest import module_from
 
     cfg, mix = cell.cfg, cell.mix
     ref = module_from("reference", cfg["reference"])
@@ -124,9 +125,13 @@ def serving(cell: Cell, seeds, control_seeds, seconds: float) -> None:
     weights = ref.make_weights(seeds[0], cfg)
     server, model = serve.start_server(cfg, mix, weights, builder)
     try:
-        for seed in seeds:
-            weights = ref.make_weights(seed, cfg)
-            builder.load(model.net, weights)
+        for k, seed in enumerate(seeds):
+            if k:
+                weights = None
+                model.net.params = [None] * len(model.net.params)
+                gc.collect()
+                weights = ref.make_weights(seed, cfg)
+                builder.load(model.net, weights)
             reqs = traffic.requests(mix, cfg, seed, seconds)
             got = serve.drive(server.url, mix, reqs, mix["kind"], seconds)
             ok = [r for r in got["rows"] if r["status"] == 200]
@@ -150,7 +155,6 @@ def sweep(cell: Cell, rates, seconds: float, seed: int) -> None:
     import numpy as np
 
     from chipbench import serve, traffic
-    from chipbench.manifest import module_from
 
     cfg, mix = cell.cfg, dict(cell.mix)
     ref = module_from("reference", cfg["reference"])

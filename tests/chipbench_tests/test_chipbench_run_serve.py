@@ -144,3 +144,50 @@ def test_a_run_is_of_the_committed_cell_and_takes_no_override(extra, capsys):
                   "--seconds", "1", "--trace", "0"] + extra)
     out = capsys.readouterr()
     assert out.out == "" and "unrecognized arguments" in out.err
+
+
+def test_calibrate_serving_reads_every_seed_on_one_server(tree, capsys,
+                                                         monkeypatch):
+    """``--seeds`` on a serving cell: one server, each seed's weights made
+    once and put in after the last were let go of, one ``program`` row a
+    seed under the harness's own verdict. A row is ``correct`` only where
+    the served tokens came from that seed's weights, so the second row
+    shows that they were put in. (``calibrate.main`` asks for a TPU;
+    ``serving`` is what it calls.)"""
+    from chipbench import calibrate, serve
+
+    seeds, made, live, nets = [2 ** 31 + 5, 2 ** 31 + 6], [], [], []
+    inner, start = calibrate.module_from, serve.start_server
+
+    def counting(subdir, name):
+        mod = inner(subdir, name)
+        if subdir == "reference":
+            make = mod.make_weights
+
+            def make_weights(seed, cfg):
+                # what the model still holds while the next set is made
+                live.append([p for net in nets for p in net.params
+                             if p is not None])
+                made.append(seed)
+                return make(seed, cfg)
+            mod.make_weights = make_weights
+        return mod
+
+    def start_server(*a, **kw):
+        server, model = start(*a, **kw)
+        nets.append(model.net)
+        return server, model
+
+    monkeypatch.setattr(calibrate, "module_from", counting)
+    monkeypatch.setattr(serve, "start_server", start_server)
+    calibrate.serving(manifest.Cell(tree, "tiny-chat-open"), seeds,
+                      seeds[1:], 1.5)
+    rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")]
+    assert [(r["who"], r["seed"]) for r in rows] == [
+        ("program", seeds[0]), ("program", seeds[1]), ("control", seeds[1])]
+    assert all(r["correct"] is True and r["over"] == []
+               for r in rows if r["who"] == "program"), rows
+    assert rows[0]["tokens_per_s"] > 0 and rows[1]["tokens_per_s"] > 0
+    assert made == seeds, "each seed's weights are made once"
+    assert live == [[], []], "and none is held while the next are made"

@@ -11,6 +11,10 @@ from chipbench import manifest, traffic
 MAN = manifest.load_manifest()
 CHAT = manifest.Cell(MAN, "bertL-chat-open")
 DOC = manifest.Cell(MAN, "bertL-doc-closed")
+KIMI = manifest.Cell(MAN, "kimiL-chat-open")
+# an open loop whose lengths keep the trace's order: chat1k-open with a block
+# of half its bucket put in (no shipped open loop sets the key: PERF.md, PR 34)
+BLOCKED = dict(KIMI.mix, shuffle_block=8)
 SEEDS = [0, 1, 12345, 2 ** 31 + 11]
 
 
@@ -18,7 +22,7 @@ def lengths(reqs):
     return collections.Counter(len(r["prompt"]) for r in reqs)
 
 
-@pytest.mark.parametrize("cell", [CHAT, DOC], ids=lambda c: c.name)
+@pytest.mark.parametrize("cell", [CHAT, DOC, KIMI], ids=lambda c: c.name)
 def test_same_seed_same_requests(cell):
     a = traffic.requests(cell.mix, cell.cfg, 2 ** 31 + 5, 10.0)
     b = traffic.requests(cell.mix, cell.cfg, 2 ** 31 + 5, 10.0)
@@ -55,8 +59,55 @@ def test_a_closed_loop_gets_through_the_same_lengths_for_every_seed(seed):
         [len(r["prompt"]) for r in other[:block]], "another order inside"
 
 
+def blocked(seed, seconds=30.0):
+    return traffic.requests(BLOCKED, KIMI.cfg, seed, seconds)
+
+
+def test_an_open_loop_with_a_block_gives_every_seed_the_same_work():
+    # the same arrivals, the same multiset of lengths, and the same lengths
+    # inside every block of that many arrivals
+    block = BLOCKED["shuffle_block"]
+    base, other = blocked(SEEDS[0]), blocked(SEEDS[3])
+    assert [r["due"] for r in base] == [r["due"] for r in other]
+    assert lengths(base) == lengths(other) and len(base) > 3 * block
+    for s in range(0, len(base), block):
+        assert lengths(base[s:s + block]) == lengths(other[s:s + block]), s
+
+
+def test_the_seed_still_moves_order_inside_a_block_and_token_ids():
+    block = BLOCKED["shuffle_block"]
+    base, other = blocked(SEEDS[0]), blocked(SEEDS[3])
+    order = [[len(r["prompt"]) for r in reqs] for reqs in (base, other)]
+    moved = [s for s in range(0, len(base), block)
+             if order[0][s:s + block] != order[1][s:s + block]]
+    assert len(moved) > len(base) // block // 2, "in most blocks"
+    assert all(a["prompt"] != b["prompt"] for a, b in zip(base, other)
+               if len(a["prompt"]) == len(b["prompt"]))
+
+
+def test_a_longer_window_extends_the_same_blocked_trace():
+    short, long = blocked(7), blocked(7, 45.0)
+    assert len(long) > len(short)
+    assert [r["due"] for r in long[:len(short)]] == [r["due"] for r in short]
+    assert all(r["due"] >= 30.0 for r in long[len(short):])
+
+
+def test_chat1k_open_keeps_what_its_cell_stands_on():
+    """The shipped file's invariants (PERF.md section 4): a rate on the
+    quarter grid above the 3/s it left, no block (it steadied nothing), and
+    ten or more requests beyond the 90th percentile of a window."""
+    mix = KIMI.mix
+    assert {"kind", "clients", "trace_seed", "rate_per_s", "prompt_tokens",
+            "max_new_tokens", "buckets", "max_wait_ms", "queue_limit",
+            "grace_s", "check_requests", "trace_after_s",
+            "trace_seconds"} <= set(mix)
+    assert mix["rate_per_s"] > 3.0 and mix["rate_per_s"] % 0.25 == 0
+    assert "shuffle_block" not in mix
+    assert len(traffic.arrival_offsets(mix, 30.0)) // 10 >= 10
+
+
 def test_lengths_stay_inside_the_mixs_bounds_and_the_models_positions():
-    for cell in (CHAT, DOC):
+    for cell in (CHAT, DOC, KIMI):
         spec = cell.mix["prompt_tokens"]
         for r in traffic.requests(cell.mix, cell.cfg, 3, 30.0):
             assert spec["min"] <= len(r["prompt"]) <= spec["max"]
