@@ -9,12 +9,17 @@ Mixers (``mixer=``):
   attention. Its cache is a STATE, one slot a stream: the (heads, dk, dv)
   float32 matrix and the last ``conv_size - 1`` inputs of the short
   convolutions. Whatever the context length, the state has one size.
-- ``"mla"`` — multi-head latent attention without positions: the cache is
-  one row ``[c_t | kr_t]`` a token for all heads (``kv_lora_rank +
-  qk_rope_dim`` numbers, in the parameters' type), behind the same page
-  tables as a per-head K/V cache. Prefill attends in the expanded form,
-  decode in the absorbed form over the paged rows
-  (ops/attention.latent_paged_attention).
+- ``"mla"`` — multi-head latent attention: the cache is one row ``[c_t |
+  kr_t]`` a token for all heads (``kv_lora_rank + qk_rope_dim`` numbers, in
+  the parameters' type), behind the same page tables as a per-head K/V
+  cache. Prefill attends in the expanded form, decode in the absorbed form
+  over the paged rows (ops/attention.latent_paged_attention). Two things
+  are a configuration's: ``rope`` rotates the ``qk_rope_dim`` dims of every
+  head's query and of the shared key row by each token's own position
+  (the key BEFORE it is written, so the cache holds ``[c | RoPE(kr)]`` and
+  the absorbed decode stays one pass; without it the dims are carried
+  unrotated and the layer sees no positions), and ``q_lora_rank`` makes the
+  query low-rank (``RMSNorm(h W_dq) W_uq``; 0 = one full-rank ``Wq``).
 
 Feed-forwards (``ffn=``): ``"dense"`` gated SiLU, or ``"moe"``: a sigmoid
 router over ``n_experts`` with a selection bias, the weights of the chosen
@@ -23,8 +28,11 @@ HELD HERE (``n_local_experts`` from ``expert_offset``) through the grouped
 dispatch of nn/moe.py.
 
 Every block meets the serving block protocol of serving/generate.py
-(``cache_kind``, ``init_pool``, ``prefill_paged``, ``decode_window_paged``);
-``apply`` is the same mathematics without a cache. Numbers: parameters in
+(``cache_kind``, ``init_pool``, ``prefill_paged``, ``decode_window_paged``,
+``prefill_resume_paged``); ``apply`` is the same mathematics without a
+cache. :class:`NextTokenModule` is a model's own next-token-prediction
+(MTP) head: one more block fed the main stack's last hidden state, which
+serving/generate.py takes as a self-draft. Numbers: parameters in
 their own type (bfloat16 when served), matrix products accumulate in
 float32, the residual stream, norms, softmax, router and KDA state are
 float32.
@@ -59,6 +67,23 @@ def _mm(x, w):
 
 def _normal(key, shape, std, dtype, mean=0.0):
     return (mean + std * jax.random.normal(key, shape, F32)).astype(dtype)
+
+
+def rope(x, positions, theta: float):
+    """Rotary positions over the whole last axis of ``x`` (B, T, ..., d) by
+    ``positions`` (B, T), in float32: dim i is paired with dim i + d/2 (the
+    "halves" layout) and the pair turned by ``positions * theta^(-2i/d)``."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    ang = positions.astype(F32)[..., None] * freq            # (B, T, d/2)
+    # taken before the head axis is put in: a query's and the key's, in
+    # every layer of a program, are then one computation to the compiler
+    over_heads = ang.shape[:2] + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = jnp.cos(ang).reshape(over_heads), \
+        jnp.sin(ang).reshape(over_heads)
+    x = x.astype(F32)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
 @register_layer
@@ -138,8 +163,11 @@ class HybridDecoderBlock(Layer):
     # mla
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
-    qk_rope_dim: int = 64         # carried unrotated (no positions)
+    qk_rope_dim: int = 64         # rotated only where ``rope`` says so
     v_head_dim: int = 128
+    q_lora_rank: int = 0          # 0 = one full-rank Wq
+    rope: bool = False            # rotate the qk_rope_dim dims by position
+    rope_theta: float = 10000.0
     # ffn
     ffn_size: int = 0             # dense width, or one expert's
     n_experts: int = 0            # the router's width
@@ -196,11 +224,17 @@ class HybridDecoderBlock(Layer):
             p["dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
         elif self.mixer == "mla":
             h = self.n_heads
+            q_out = h * (self.qk_nope_dim + self.qk_rope_dim)
             p.update(Wdkv=mat(hs, self._row), kv_norm=one(self.kv_lora_rank),
                      Wukv=mat(self.kv_lora_rank,
                               h * (self.qk_nope_dim + self.v_head_dim)),
-                     Wq=mat(hs, h * (self.qk_nope_dim + self.qk_rope_dim)),
                      Wo=mat(h * self.v_head_dim, hs))
+            if self.q_lora_rank:
+                p.update(Wdq=mat(hs, self.q_lora_rank),
+                         q_norm=one(self.q_lora_rank),
+                         Wuq=mat(self.q_lora_rank, q_out))
+            else:
+                p["Wq"] = mat(hs, q_out)
         else:
             raise ValueError(f"unknown mixer {self.mixer!r}")
         f = self.ffn_size
@@ -230,14 +264,16 @@ class HybridDecoderBlock(Layer):
         if self.ffn == "dense":
             return gated("Wgate", "Wup", "Wdown"), None
         h2 = h.reshape(-1, self.hidden_size)
-        idx, w = moe.route_sigmoid_topk(h2, params["router"].astype(F32),
-                                        params["router_bias"], self.top_k,
-                                        self.routed_scale)
-        y, stats = moe.grouped_experts(
-            h2.astype(params["Egate"].dtype), idx, w, params["Egate"],
-            params["Eup"], params["Edown"], e_offset=self.expert_offset,
-            n_experts=self.n_experts,
-            live=None if live is None else live.reshape(-1))
+        with jax.named_scope("moe.route"):
+            idx, w = moe.route_sigmoid_topk(
+                h2, params["router"].astype(F32), params["router_bias"],
+                self.top_k, self.routed_scale)
+        with jax.named_scope("moe.grouped"):
+            y, stats = moe.grouped_experts(
+                h2.astype(params["Egate"].dtype), idx, w, params["Egate"],
+                params["Eup"], params["Edown"], e_offset=self.expert_offset,
+                n_experts=self.n_experts,
+                live=None if live is None else live.reshape(-1))
         y = y.reshape(x.shape)
         if self.shared_size:
             y = y + gated("Sgate", "Sup", "Sdown")
@@ -296,17 +332,38 @@ class HybridDecoderBlock(Layer):
                 kda.conv_tail(raw, lengths, self.conv_size - 1))
 
     # ------------------------------------------------------------ MLA mixer
-    def _mla_rows(self, params, h):
-        """Normed input -> the cache rows [RMSNorm(c) | kr] (B, T, R)."""
+    def _rope(self, x, positions):
+        """The rotated dims of a query or key at ``positions`` (B, T); as
+        they came where this configuration carries them unrotated."""
+        if not self.rope:
+            return x
+        with jax.named_scope("mla.rope"):
+            return rope(x, positions, self.rope_theta)
+
+    def _mla_rows(self, params, h, positions):
+        """Normed input -> the cache rows [RMSNorm(c) | kr] (B, T, R), the
+        key dims rotated by ``positions`` before they are cached."""
         ckr = _mm(h, params["Wdkv"])
         c = rms_norm(ckr[..., :self.kv_lora_rank], params["kv_norm"],
                      self.eps)
-        return jnp.concatenate([c, ckr[..., self.kv_lora_rank:]], -1)
+        kr = self._rope(ckr[..., self.kv_lora_rank:], positions)
+        return jnp.concatenate([c, kr], -1)
 
-    def _mla_q(self, params, h):
+    def _mla_q(self, params, h, positions):
         b, t, _ = h.shape
-        return _mm(h, params["Wq"]).reshape(
-            b, t, self.n_heads, self.qk_nope_dim + self.qk_rope_dim)
+        if self.q_lora_rank:
+            with jax.named_scope("mla.q_lowrank"):
+                q = _mm(rms_norm(_mm(h, params["Wdq"]), params["q_norm"],
+                                 self.eps), params["Wuq"])
+        else:
+            q = _mm(h, params["Wq"])
+        q = q.reshape(b, t, self.n_heads,
+                      self.qk_nope_dim + self.qk_rope_dim)
+        if not self.rope:
+            return q
+        dn = self.qk_nope_dim
+        return jnp.concatenate(
+            [q[..., :dn], self._rope(q[..., dn:], positions)], -1)
 
     @property
     def _mla_scale(self) -> float:
@@ -318,12 +375,14 @@ class HybridDecoderBlock(Layer):
         heads would be 2 GB) -> (mixer output, cache rows)."""
         b, t, _ = h.shape
         nh, dn, dv = self.n_heads, self.qk_nope_dim, self.v_head_dim
-        rows = self._mla_rows(params, h)
+        # whole prompts are right-padded: every row's positions are 0..T-1
+        positions = jnp.broadcast_to(jnp.arange(t), (b, t))
+        rows = self._mla_rows(params, h, positions)
         c, kr = rows[..., :self.kv_lora_rank], rows[..., self.kv_lora_rank:]
         kv = _mm(c, params["Wukv"]).reshape(b, t, nh, dn + dv)
         dt = params["Wukv"].dtype
         kc, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
-        q = self._mla_q(params, h).astype(dt)
+        q = self._mla_q(params, h, positions).astype(dt)
         kr = kr.astype(dt)
         k_pos = jnp.arange(t)
         keep = mask.astype(bool)[:, None, None, :]
@@ -354,7 +413,7 @@ class HybridDecoderBlock(Layer):
         b, w, _ = h.shape
         nh, dn, dv = self.n_heads, self.qk_nope_dim, self.v_head_dim
         wukv = params["Wukv"].reshape(self.kv_lora_rank, nh, dn + dv)
-        q = self._mla_q(params, h)
+        q = self._mla_q(params, h, positions)
         q_abs = jnp.concatenate(
             [jnp.einsum("bwhd,rhd->bwhr", q[..., :dn].astype(wukv.dtype),
                         wukv[..., :dn], preferred_element_type=F32),
@@ -413,11 +472,12 @@ class HybridDecoderBlock(Layer):
         return self._finish(params, x, a, pool, mask.astype(bool))
 
     def decode_window_paged(self, params, x_w, pool, where, positions,
-                            block_size, limits=None):
-        """Window tokens (B, W, H) at ``positions`` (B, W). ``where``: the
-        page tables (B, max_blocks) for ``"tokens"``, the state slots (B,)
-        for ``"state"``. A token past its stream's ``limits`` writes no
-        cache row and moves no state."""
+                            block_size, limits=None, phase: int = 1):
+        """Window tokens (B, W, H) at ``positions`` (B, W), each row's own.
+        ``where``: the page tables (B, max_blocks) for ``"tokens"``, the
+        state slots (B,) for ``"state"``. A token past its stream's
+        ``limits`` writes no cache row and moves no state. A router's counts
+        go onto row ``phase`` (:meth:`_finish`)."""
         live = jnp.ones(positions.shape, bool) if limits is None \
             else positions <= limits[:, None]
         h = rms_norm(x_w, params["norm1"], self.eps)
@@ -436,12 +496,69 @@ class HybridDecoderBlock(Layer):
         else:
             slots = attn_ops.paged_slots(where, positions, block_size)
             slots = jnp.where(live, slots, 0)
-            rows = self._mla_rows(params, h)
+            rows = self._mla_rows(params, h, positions)
             pool = dict(pool, rows=pool["rows"].at[slots.reshape(-1)].set(
                 rows.reshape(-1, self._row).astype(pool["rows"].dtype)))
             a = self._mla_absorbed(params, h, pool["rows"], where, positions,
                                    block_size)
-        return self._finish(params, x_w, a, pool, live, phase=1)
+        return self._finish(params, x_w, a, pool, live, phase=phase)
+
+    def prefill_resume_paged(self, params, x_w, pool, where, positions,
+                             block_size, limits=None):
+        """A chunk of prompt tokens from each row's own resume point: the
+        window's write-then-attend over the paged rows, counted as prefill.
+        (A net with ``"state"`` layers is refused before it gets here:
+        serving/generate.py.)"""
+        return self.decode_window_paged(params, x_w, pool, where, positions,
+                                        block_size, limits=limits, phase=0)
 
     def output_shape(self, input_shape):
         return (input_shape[0], self.hidden_size)
+
+
+@dataclasses.dataclass(frozen=True)
+class NextTokenModule:
+    """A model's own next-token-prediction (MTP) head (DeepSeek-V3 report,
+    arXiv:2412.19437, section 2.2): ``x'_i = W_eh [RMSNorm_e(Emb(t_{i+1})) ;
+    RMSNorm_h(h_i)]`` with ``h_i`` the main stack's last hidden state before
+    its final norm, one ``block`` with latent rows of its own, a final
+    RMSNorm, and the MAIN model's embedding and head (shared by reference,
+    never copied) -> logits for ``t_{i+2}``. No layer of a net: the serving
+    path takes it, with its parameters, as a self-draft
+    (:class:`SelfDraft`)."""
+
+    block: HybridDecoderBlock
+    eps: float = 1e-5
+    init_range: float = 0.02
+    param_dtype: str = "float32"
+
+    def initialize(self, key):
+        hs, r, dt = self.block.hidden_size, self.init_range, self.param_dtype
+        ks = jax.random.split(key, 5)
+        one = lambda k: _normal(k, (hs,), r, dt, mean=1.0)
+        return {"enorm": one(ks[0]), "hnorm": one(ks[1]),
+                "Weh": _normal(ks[2], (2 * hs, hs), r, dt),
+                "block": self.block.initialize(ks[3], None)[0],
+                "norm": one(ks[4])}
+
+    def join(self, params, emb_x, h):
+        """The block's input: the next token's embedding (first) and the
+        main stack's hidden state, each normed, through ``W_eh``."""
+        return _mm(jnp.concatenate(
+            [rms_norm(emb_x, params["enorm"], self.eps),
+             rms_norm(h, params["hnorm"], self.eps)], -1), params["Weh"])
+
+    def logits(self, params, head, head_params, x):
+        """This module's final norm, then the main model's head."""
+        return head._logits(dict(head_params, norm=params["norm"]), x)
+
+
+class SelfDraft:
+    """A model's own next-token-prediction module as its draft
+    (``Generator(self_draft=)``): ``module`` (:class:`NextTokenModule`:
+    ``block``, ``join``, ``logits``) and its ``params`` (``enorm``,
+    ``hnorm``, ``Weh``, ``block``, ``norm``). The embedding and the head are
+    the served net's, by reference."""
+
+    def __init__(self, module, params=None):
+        self.module, self.params = module, params

@@ -36,6 +36,15 @@ executables:
   ``temperature > 0`` falls back to the plain per-token sampling loop —
   verify-consistent by construction (same program, same key stream as
   the non-speculative path).
+- **self-draft** — the model's OWN next-token-prediction (MTP) module in
+  place of a draft net (:class:`SelfDraft`, ``self_draft=``): it reads the
+  target's last hidden state beside the token that followed, shares the
+  target's embedding and head, and keeps one more ``"tokens"`` layer of
+  latent rows in the SAME block pool, behind the streams' own page tables.
+  One proposal a step (``spec_tokens`` 1), verified through the same window
+  (W = 2) and accepted or corrected by the same logic: a step yields one or
+  two tokens, each the target's own argmax
+  (docs/SERVING.md#self-draft-the-models-own-mtp-head).
 
 The block protocol (what ``_decoder_parts`` checks; docs/SERVING.md):
 
@@ -64,8 +73,9 @@ batch with its tokens (``serving.moe_*_total``). It is no cache
 
 A net with ``"state"`` layers (recurrent: the state cannot be shared,
 copied or rolled back) refuses the prefix cache, copy-on-write, chunked
-prefill and the speculative verify window with a ``ValueError`` at
-construction: they need a state snapshot no layer offers yet.
+prefill and the speculative verify window (a draft net's and a self-draft's
+alike) with a ``ValueError`` at construction: they need a state snapshot no
+layer offers yet.
 
 Admission: a batch whose streams cannot all get blocks sheds with
 :class:`~deeplearning4j_tpu.serving.resilience.PoolExhaustedError`
@@ -96,6 +106,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.data.bucketing import BucketingPolicy
+from deeplearning4j_tpu.nn.decoder import SelfDraft
 from deeplearning4j_tpu.ops import attention as attn_ops
 from deeplearning4j_tpu.ops import kda as kda_ops
 from deeplearning4j_tpu.serving.paged import (NOT_CACHE, BlockPool,
@@ -152,7 +163,9 @@ class Generator:
     / ``pool_blocks`` (pool geometry; default pool holds the largest
     batch bucket at full context, so admission only bites when sized
     down deliberately), ``draft_net`` + ``spec_tokens`` (speculative
-    decoding — the draft runs its own small contiguous cache), and
+    decoding — the draft runs its own small contiguous cache),
+    ``self_draft`` (:class:`SelfDraft`: the model's own MTP module drafts one
+    token a step, its latent rows one more layer of the pool), and
     ``quantize`` ("int8" weight-only serving)."""
 
     def __init__(self, net, *, max_length: Optional[int] = None,
@@ -162,6 +175,7 @@ class Generator:
                  prefix_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
                  draft_net=None, spec_tokens: int = 4,
+                 self_draft: Optional[SelfDraft] = None,
                  quantize: Optional[str] = None,
                  model_id: str = ""):
         self.emb, self.blocks, self.head = _decoder_parts(net, "Generator",
@@ -177,14 +191,31 @@ class Generator:
             for on, name in ((prefix_cache, "prefix_cache (and its "
                               "copy-on-write)"),
                              (prefill_chunk, "prefill_chunk"),
-                             (draft_net is not None, "speculative decoding "
-                              "(draft_net)")):
+                             (draft_net is not None or self_draft is not None,
+                              "speculative decoding (draft_net, "
+                              "self_draft)")):
                 if on:
                     raise ValueError(
                         f"{name} is not served on a net with recurrent "
                         "(per-stream state) layers: sharing, resuming or "
                         "rolling back a stream needs a snapshot of its "
                         "state, which no layer offers yet")
+        #: the model's own MTP module as the draft (module doc), or None
+        self.mtp = self_draft
+        if self_draft is not None:
+            # a module's row i holds the token AFTER it (t_{i+1}): a prefix
+            # shared up to a block's end, or a chunk resumed there, would
+            # need the next stream's or chunk's first token
+            for on, name in ((not paged, "paged=False"),
+                             (draft_net is not None, "a draft_net as well"),
+                             (prefix_cache, "prefix_cache"),
+                             (prefill_chunk, "prefill_chunk")):
+                if on:
+                    raise ValueError(
+                        f"self_draft is not served with {name}: its latent "
+                        "rows live in the paged pool, one draft proposes, "
+                        "and a row that follows the NEXT token cannot be "
+                        "shared or resumed at a block's end yet")
         conf_policy = BucketingPolicy.from_conf(getattr(net, "conf", None))
         if batch_buckets is None and conf_policy is not None:
             batch_buckets = conf_policy.batch_buckets
@@ -216,7 +247,8 @@ class Generator:
                     self.max_length, self.block_size)
             # one state slot a stream of the largest batch (recurrent nets)
             bb = self.policy.batch_buckets
-            self.pool = BlockPool(self.blocks, block_size=self.block_size,
+            self.pool = BlockPool(self._cache_blocks(),
+                                  block_size=self.block_size,
                                   num_blocks=int(pool_blocks),
                                   max_length=self.max_length,
                                   model_id=self.model_id,
@@ -240,6 +272,10 @@ class Generator:
                                                donate_argnums=(1,))
             self._copy_block_jit = jax.jit(self._copy_block,
                                            donate_argnums=(0,))
+            self._mtp_prefill_jit = jax.jit(self._mtp_prefill,
+                                            donate_argnums=(2,))
+            self._mtp_draft_jit = jax.jit(self._mtp_draft,
+                                          donate_argnums=(2,))
         # prefix cache (ISSUE 16 tentpole): a radix trie over prompt
         # prefixes → block chains, so N streams with a common head hold
         # ONE physical copy and resume prefill past it. Off by default —
@@ -271,7 +307,9 @@ class Generator:
         # speculative decoding: the draft is a plain contiguous-cache
         # generator over the (tiny) draft net — same bucket policy, so
         # draft prefill shapes always match the target's prep
-        self.spec_tokens = int(spec_tokens)
+        # (a self-draft proposes ONE token: its module predicts the token
+        # after the next, no further)
+        self.spec_tokens = 1 if self_draft is not None else int(spec_tokens)
         self.draft: Optional[Generator] = None
         if draft_net is not None:
             if not self.paged:
@@ -302,6 +340,18 @@ class Generator:
         if self._qp is None:
             return raw
         return self._qp.rebuild(raw)
+
+    def _cache_blocks(self):
+        """The layers that keep a cache in the pool: the net's blocks, and
+        behind them a self-draft's one block."""
+        if self.mtp is None:
+            return self.blocks
+        return self.blocks + [self.mtp.module.block]
+
+    def _rest(self, pools):
+        """The pools behind the net's blocks (a self-draft's layer), which
+        the target's programs hand through as they came."""
+        return list(pools[len(self.blocks):])
 
     # ------------------------------------------------------ traced programs
     def _prefill(self, raw, tokens, lengths):
@@ -381,6 +431,10 @@ class Generator:
             new_pools.append(pool)
         h_last = x[jnp.arange(b), lengths - 1]
         logits = self.head._logits(params[-1], h_last)
+        new_pools += self._rest(pools)
+        if self.mtp is not None:
+            # the last hidden state of every position: the module's input
+            return logits, x, new_pools
         return logits, new_pools
 
     def _decode_paged(self, raw, pools, tables, tokens, positions, limits):
@@ -400,7 +454,7 @@ class Generator:
                 pos_w, self.block_size, limits=limits)
             new_pools.append(pool)
         logits = self.head._logits(params[-1], x[:, 0])
-        return logits, new_pools
+        return logits, new_pools + self._rest(pools)
 
     def _verify_paged(self, raw, pools, tables, window, positions0, limits):
         """Speculative verify: ``window`` (B, W) tokens at positions
@@ -420,7 +474,56 @@ class Generator:
                                               limits=limits)
             new_pools.append(pool)
         logits = self.head._logits(params[-1], x)
+        new_pools += self._rest(pools)
+        if self.mtp is not None:
+            return logits, x, new_pools
         return logits, new_pools
+
+    def _mtp_prefill(self, raw, mtp_params, pools, tokens, lengths, tables,
+                     hidden, cur):
+        """The self-draft over whole prompts, behind the target's prefill:
+        at position i the module reads ``hidden`` (B, T, H) there and the
+        token at i + 1 (``cur`` (B,), the target's pick, behind the last
+        prompt token), writes its latent row i through the same page tables
+        and hands back its logits at each row's last position: the proposal
+        for the token after ``cur``."""
+        note_trace("serving.mtp_prefill", tokens, lengths)
+        params, mod = self._params_of(raw), self.mtp.module
+        b, t = tokens.shape
+        rows = jnp.arange(b)
+        pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+        nxt = jnp.roll(tokens, -1, axis=1).at[rows, lengths - 1].set(cur)
+        x = mod.join(mtp_params,
+                     self.emb.embed_window(params[0], nxt, pos), hidden)
+        pad_mask = (pos < lengths[:, None]).astype(x.dtype)
+        slots = attn_ops.paged_slots(tables, pos, self.block_size)
+        x, pool = mod.block.prefill_paged(mtp_params["block"], x, pools[-1],
+                                          slots, mask=pad_mask)
+        logits = mod.logits(mtp_params, self.head, params[-1],
+                            x[rows, lengths - 1])
+        return logits, list(pools[:-1]) + [pool]
+
+    def _mtp_draft(self, raw, mtp_params, pools, tables, after, hidden,
+                   positions0, limits, take):
+        """One self-draft step behind a verify window: the module reads the
+        window's ``hidden`` (B, W, H) with the tokens that FOLLOW each
+        position (``after`` (B, W): the target's own picks), writes its
+        latent rows at the window's positions and hands back its logits at
+        column ``take`` (B,), the last position the round committed. A row
+        written past it is stale and overwritten by the next round before
+        any read, as the target's are (serving/paged.py)."""
+        note_trace("serving.mtp_draft", after, positions0)
+        params, mod = self._params_of(raw), self.mtp.module
+        b, w = after.shape
+        pos_w = positions0[:, None] + jnp.arange(w)[None, :]
+        x = mod.join(mtp_params,
+                     self.emb.embed_window(params[0], after, pos_w), hidden)
+        x, pool = mod.block.decode_window_paged(
+            mtp_params["block"], x, pools[-1], tables, pos_w,
+            self.block_size, limits=limits)
+        logits = mod.logits(mtp_params, self.head, params[-1],
+                            x[jnp.arange(b), take])
+        return logits, list(pools[:-1]) + [pool]
 
     def _prefill_window_paged(self, raw, pools, window, positions, tables,
                               limits, last_idx):
@@ -543,7 +646,7 @@ class Generator:
         old_peak = self.pool.peak_streams
         self.pool.pools = None  # free before the bigger alloc
         states = max(need_states, self.pool.num_state_slots)
-        self.pool = BlockPool(self.blocks,
+        self.pool = BlockPool(self._cache_blocks(),
                               block_size=self.block_size,
                               num_blocks=grown,
                               max_length=self.max_length,
@@ -710,7 +813,8 @@ class Generator:
                 sum(starts) / max(1, sum(lens)), 4)
             stats["resumed_positions"] = list(starts)
         try:
-            speculate = (self.draft is not None and self.spec_tokens > 0
+            speculate = ((self.draft is not None or self.mtp is not None)
+                         and self.spec_tokens > 0
                          and not (temperature and temperature > 0.0))
             if speculate:
                 return self._generate_speculative(
@@ -755,13 +859,28 @@ class Generator:
             return min(self.prefill_chunk, self.max_length)
         return self._prefill_len(max_rem)
 
+    def _launch_prefill(self, raw, tokens, lengths, tables):
+        """One whole-prompt prefill, launched and counted (rows x declared
+        positions, and the launch: what one launch computed is their
+        ratio). Returns (logits, the last hidden state of every position
+        for a self-draft, else None)."""
+        out = self._prefill_paged_jit(raw, self.pool.pools, tokens, lengths,
+                                      tables)
+        self.pool.pools = out[-1]
+        tm.counter("serving.prefill_positions_total",
+                   int(tokens.shape[0]) * int(tokens.shape[1]),
+                   model=self.model_id)
+        tm.counter("serving.prefill_launches_total", model=self.model_id)
+        return out[0], (out[1] if self.mtp is not None else None)
+
     def _run_prefill(self, raw, tokens, lengths, tables, b_real, lens,
                      starts, cow, pending, tele, stats, yield_hook,
                      speculative: bool = False):
         """Dispatch the prompt phase: COW block copies, then either the
         r20 whole-prompt prefill (bit-path unchanged — no cache hit, no
         chunking) or the resume/chunk window loop, then commit this
-        batch's trie nodes. Returns next-token logits (B, V)."""
+        batch's trie nodes. Returns next-token logits (B, V) and, for a
+        self-draft, the last hidden state of every position (else None)."""
         batch = int(tokens.shape[0])
         t = int(tokens.shape[1])
         for src, dst in cow:
@@ -772,10 +891,10 @@ class Generator:
         t_pf = time.time_ns() if tele else 0
         whole = (not any(starts)) and (self.prefill_chunk is None
                                        or t <= self.prefill_chunk)
+        hidden = None
         if whole:
-            logits, pools = self._prefill_paged_jit(
-                raw, self.pool.pools, tokens, lengths, tables)
-            self.pool.pools = pools
+            logits, hidden = self._launch_prefill(raw, tokens, lengths,
+                                                  tables)
             n_chunks = 1
             if self.recurrent:
                 self._count_kda_chunks(batch, t, lens)
@@ -795,7 +914,7 @@ class Generator:
             # the prefill that writes these blocks has been issued —
             # program order guarantees any later read sees the writes
             self.cache.commit(pending)
-        return logits
+        return logits, hidden
 
     def _prefill_windowed(self, raw, tokens, lengths, tables, b_real,
                           lens, starts, yield_hook):
@@ -950,9 +1069,9 @@ class Generator:
             [l + max_new - 1 for l in lens]
             + [0] * (batch - b_real), np.int32))
 
-        logits = self._run_prefill(raw, tokens, lengths, tables, b_real,
-                                   lens, starts, cow, pending, tele,
-                                   stats, yield_hook)
+        logits, _ = self._run_prefill(raw, tokens, lengths, tables, b_real,
+                                      lens, starts, cow, pending, tele,
+                                      stats, yield_hook)
         positions = lengths
         longest = max(lens)  # the batch's largest position, step 0
         kv_read = 0
@@ -995,10 +1114,13 @@ class Generator:
         the TARGET's argmax — the draft only decides how many the verify
         window can commit at once. Prefix sharing applies to the TARGET's
         paged prefill only; the draft keeps its own full contiguous
-        prefill (its cache is private, tiny, and never shared)."""
+        prefill (its cache is private, tiny, and never shared). With a
+        self-draft (module doc) the proposal is the model's own MTP
+        module's: made behind the prefill and behind every verify window
+        from the hidden states those hand back."""
         raw = self._raw_params()
-        draft = self.draft
-        draft_raw = draft._raw_params()
+        draft, mtp = self.draft, self.mtp
+        draft_raw = draft._raw_params() if draft is not None else None
         tele = tm.get_telemetry() if trace else None
         batch = int(tokens.shape[0])
         w = self.spec_tokens + 1  # window = last accepted + k proposals
@@ -1006,12 +1128,17 @@ class Generator:
                                + [0] * (batch - b_real), np.int32)
         limits = jnp.asarray(limits_np)
 
-        logits = self._run_prefill(raw, tokens, lengths, tables, b_real,
-                                   lens, starts, cow, pending, tele,
-                                   stats, yield_hook, speculative=True)
-        _, dcaches = draft._prefill_jit(draft_raw, tokens, lengths)
-
+        logits, hidden = self._run_prefill(
+            raw, tokens, lengths, tables, b_real, lens, starts, cow, pending,
+            tele, stats, yield_hook, speculative=True)
         cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # token AT pos
+        if mtp is None:
+            _, dcaches = draft._prefill_jit(draft_raw, tokens, lengths)
+        else:
+            dlogits, self.pool.pools = self._mtp_prefill_jit(
+                raw, mtp.params, self.pool.pools, tokens, lengths, tables,
+                hidden, cur)
+            proposal = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
         pos_np = np.asarray(lengths)  # cur's position, per row
         prev = tokens[jnp.arange(batch), jnp.asarray(pos_np) - 1]
         emitted: List[List[int]] = [[] for _ in range(batch)]
@@ -1024,33 +1151,36 @@ class Generator:
             if eos_id is not None and int(host_cur[i]) == eos_id:
                 done[i] = True
 
+        unfinished = lambda: not done.all() and any(
+            len(emitted[i]) < max_new for i in range(b_real) if not done[i])
         rounds = kv_read = 0
-        while not done.all() and any(len(emitted[i]) < max_new
-                                     for i in range(b_real)
-                                     if not done[i]):
+        while unfinished():
             rounds += 1
             positions = jnp.asarray(np.minimum(pos_np,
                                                self.max_length - 1))
-            # draft proposal: repair the slot behind cur (idempotent — the
-            # K/V write is a pure function of (token, position), and after
-            # a fully-accepted window the draft never saw that token),
-            # then chain spec_tokens greedy draft steps
-            _, dcaches = draft._decode_jit(
-                draft_raw, dcaches, prev,
-                jnp.maximum(positions - 1, 0))
             window_cols = [cur]
-            dcur = cur
-            for j in range(self.spec_tokens):
-                dlogits, dcaches = draft._decode_jit(
-                    draft_raw, dcaches, dcur,
-                    jnp.minimum(positions + j,
-                                self.max_length - 1))
-                dcur = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-                window_cols.append(dcur)
+            if mtp is not None:
+                window_cols.append(proposal)
+            else:
+                # draft proposal: repair the slot behind cur (idempotent —
+                # the K/V write is a pure function of (token, position), and
+                # after a fully-accepted window the draft never saw that
+                # token), then chain spec_tokens greedy draft steps
+                _, dcaches = draft._decode_jit(
+                    draft_raw, dcaches, prev,
+                    jnp.maximum(positions - 1, 0))
+                dcur = cur
+                for j in range(self.spec_tokens):
+                    dlogits, dcaches = draft._decode_jit(
+                        draft_raw, dcaches, dcur,
+                        jnp.minimum(positions + j,
+                                    self.max_length - 1))
+                    dcur = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+                    window_cols.append(dcur)
             window = jnp.stack(window_cols, axis=1)  # (B, w)
             live = int((~done).sum())
             t_vf = time.time_ns() if tele else 0
-            glogits, pools = self._verify_paged_jit(
+            glogits, *hidden, pools = self._verify_paged_jit(
                 raw, self.pool.pools, tables, window, positions, limits)
             self.pool.pools = pools
             kv_read += self._kv_positions_read(
@@ -1090,8 +1220,27 @@ class Generator:
                                 np.asarray(cur))
             cur = jnp.asarray(new_cur.astype(np.int32))
             prev = jnp.asarray(new_prev.astype(np.int32))
+            if mtp is not None and unfinished():
+                # the module follows the target's own picks over the window
+                # and proposes from the last position this round committed
+                t_md = time.time_ns() if tele else 0
+                dlogits, self.pool.pools = self._mtp_draft_jit(
+                    raw, mtp.params, self.pool.pools, tables,
+                    jnp.asarray(g.astype(np.int32)), hidden[0], positions,
+                    limits, jnp.asarray((m - 1).astype(np.int32)))
+                proposal = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+                if tele:
+                    tele.event_deferred(
+                        "serving.generate.mtp_draft", t_md, time.time_ns(),
+                        batch=batch, window=w, round=rounds)
             pos_np = pos_np + m
         self._count_kv_read(batch, kv_read, rounds)
+        self._count_moe()
+        if mtp is not None:
+            tm.counter("serving.mtp_draft_proposed_total",
+                       int(accept_den[:b_real].sum()), model=self.model_id)
+            tm.counter("serving.mtp_draft_accepted_total",
+                       int(accept_num[:b_real].sum()), model=self.model_id)
         if stats is not None:
             rates = [
                 (float(accept_num[i] / accept_den[i])
@@ -1205,10 +1354,8 @@ class Generator:
             tm.set_health(check, ok, detail)
             if not ok:
                 return False
-            tables = self._trash_address(b)
-            logits, pools = self._prefill_paged_jit(
-                raw, self.pool.pools, tokens, lengths, tables)
-            self.pool.pools = pools
+            logits, _ = self._launch_prefill(raw, tokens, lengths,
+                                             self._trash_address(b))
         else:
             logits, _ = self._prefill_jit(raw, tokens, lengths)
         return bool(np.isfinite(np.asarray(logits)).all())
@@ -1217,8 +1364,9 @@ class Generator:
     def warmup(self, batch_sizes=None, prompt_lengths=None) -> int:
         """Pre-trace every (batch bucket × prefill bucket) prefill, every
         batch-bucket decode step, and — when speculating — every
-        batch-bucket verify window and the draft's own programs, so
-        steady-state serving never compiles (docs/SERVING.md). Defaults to
+        batch-bucket verify window and the draft's own programs (a draft
+        net's, or a self-draft's two: behind the prefill, behind the
+        window), so steady-state serving never compiles (docs/SERVING.md). Defaults to
         the explicit bucket lists of the policy. Returns the number of
         signatures primed."""
         if batch_sizes is None:
@@ -1258,9 +1406,14 @@ class Generator:
                 tokens = jnp.zeros((b, t), jnp.int32)
                 lengths = jnp.ones((b,), jnp.int32)
                 if self.paged:
-                    _, pools = self._prefill_paged_jit(
-                        raw, self.pool.pools, tokens, lengths, tables)
-                    self.pool.pools = pools
+                    _, hidden = self._launch_prefill(raw, tokens, lengths,
+                                                     tables)
+                    if self.mtp is not None:
+                        _, self.pool.pools = self._mtp_prefill_jit(
+                            raw, self.mtp.params, self.pool.pools, tokens,
+                            lengths, tables, hidden,
+                            jnp.zeros((b,), jnp.int32))
+                        primed += 1
                 else:
                     _, caches = self._prefill_jit(raw, tokens, lengths)
                 primed += 1
@@ -1281,12 +1434,18 @@ class Generator:
                     raw, self.pool.pools, tables, cur, pos, limits)
                 self.pool.pools = pools
                 primed += 1
-                if self.draft is not None and self.spec_tokens > 0:
+                if (self.draft is not None or self.mtp is not None) \
+                        and self.spec_tokens > 0:
                     vwin = jnp.zeros((b, self.spec_tokens + 1), jnp.int32)
-                    _, pools = self._verify_paged_jit(
+                    _, *hidden, pools = self._verify_paged_jit(
                         raw, self.pool.pools, tables, vwin, pos, limits)
                     self.pool.pools = pools
                     primed += 1
+                    if self.mtp is not None:
+                        _, self.pool.pools = self._mtp_draft_jit(
+                            raw, self.mtp.params, self.pool.pools, tables,
+                            vwin, hidden[0], pos, limits, cur)
+                        primed += 1
             elif caches is not None:
                 self._decode_jit(raw, caches, cur, pos)
                 primed += 1
@@ -1323,6 +1482,7 @@ class Generator:
         if self.prefill_chunk is not None:
             s["prefill_chunk"] = self.prefill_chunk
         s["recurrent"] = self.recurrent
+        s["self_draft"] = self.mtp is not None
         return s
 
     def prefix_hit_rate(self) -> Optional[float]:

@@ -18,7 +18,8 @@ Two kinds:
   bit-identical to per-request results.
 - ``kind="generate"``: paged-KV-cache autoregressive decode
   (serving/generate.py — paged block pool, optional speculative decoding
-  via ``draft_net``/``spec_tokens``, optional ``quantize="int8"``).
+  via ``draft_net``/``spec_tokens`` or the model's own MTP module as
+  ``self_draft``, optional ``quantize="int8"``).
   Requests are token prompts; coalesced prompts decode as one batch,
   per-request ``max_new_tokens`` honored by trimming (rows are
   attention-independent, so batching never changes a row's tokens). A
@@ -61,7 +62,7 @@ class ServingModel:
                  pool_blocks: Optional[int] = None,
                  prefix_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
-                 draft_net=None, spec_tokens: int = 4,
+                 draft_net=None, spec_tokens: int = 4, self_draft=None,
                  quantize: Optional[str] = None):
         if kind not in ("classify", "generate"):
             raise ValueError(f"unknown serving kind {kind!r}")
@@ -79,6 +80,7 @@ class ServingModel:
         self._prefill_chunk = prefill_chunk
         self._draft_net = draft_net
         self._spec_tokens = int(spec_tokens)
+        self._self_draft = self_draft
         self.quantize = quantize
         self._qp = None       # classify-kind int8 residents
         self._qforward = None
@@ -120,6 +122,7 @@ class ServingModel:
                 prefix_cache=self._prefix_cache,
                 prefill_chunk=self._prefill_chunk,
                 draft_net=self._draft_net, spec_tokens=self._spec_tokens,
+                self_draft=self._self_draft,
                 quantize=quantize, model_id=self.model_id)
             self.policy = self.generator.policy
             self._qp = self.generator._qp
@@ -364,6 +367,7 @@ class ServingModel:
                             prefill_chunk=self._prefill_chunk,
                             draft_net=self._draft_net,
                             spec_tokens=self._spec_tokens,
+                            self_draft=self._self_draft,
                             quantize=self.quantize)
 
     def structure_matches(self, net) -> bool:
@@ -468,4 +472,8 @@ class ServingModel:
                     if hasattr(self.generator.draft.net, "num_params")
                     else None,
                 }
+            elif self.generator.mtp is not None:
+                out["speculative"] = {
+                    "spec_tokens": self.generator.spec_tokens,
+                    "self_draft": True}
         return out

@@ -1,8 +1,12 @@
 """Kimi Linear (moonshotai/Kimi-Linear-48B-A3B-Instruct; Kimi Linear
 report, arXiv:2510.26692): a hybrid decoder of KDA (gated delta-rule linear
-attention) and MLA (latent attention without positions) layers in the
-pattern KDA, KDA, KDA, MLA, a leading dense gated-SiLU feed-forward and
-routed experts with one shared expert after it.
+attention) and MLA (latent attention) layers in the pattern KDA, KDA, KDA,
+MLA, a leading dense gated-SiLU feed-forward and routed experts with one
+shared expert after it. THIS configuration's latent layers see no positions:
+it carries the 64 "rope" dims unrotated (``rope=False``, the block's
+default; ``mla_use_nope`` in the published config) and keeps one full-rank
+``Wq`` (``q_lora_rank`` 0). The block itself rotates and takes a low-rank
+query where a configuration says so (zoo/glm4_moe_lite.py).
 
 Built from nn/decoder.py as an ordinary ``MultiLayerNetwork``: token
 embedding, ``n_layers`` :class:`HybridDecoderBlock`, a normed untied head;

@@ -433,7 +433,43 @@ def test_absorbed_latent_decode_is_the_expanded_form():
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
+def test_this_configurations_latent_layers_stay_unrotated_and_full_rank(
+        served):
+    """Rotation and the query rank are a configuration's (nn/decoder.py):
+    Kimi's blocks keep neither, so their parameters and programs are what
+    they were; the same block with both set has other leaves."""
+    import dataclasses
+
+    _, gen = served
+    mla = [b for b in gen.blocks if b.mixer == "mla"]
+    assert mla and all(not b.rope and b.q_lora_rank == 0 for b in mla)
+    p, _ = mla[0].initialize(jax.random.PRNGKey(0), None)
+    assert "Wq" in p and not {"Wdq", "q_norm", "Wuq"} & set(p)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 6, 64))
+    pos = jnp.arange(6)[None]
+    h = jnp.ones((1, 6, 64))
+    assert mla[0]._rope(x, pos) is x                    # a trace-time branch
+    turned = dataclasses.replace(mla[0], rope=True, q_lora_rank=8)
+    q, _ = turned.initialize(jax.random.PRNGKey(0), None)
+    assert {"Wdq", "q_norm", "Wuq"} <= set(q) and "Wq" not in q
+    rows = lambda blk, prm: blk._mla_rows(prm, h, pos)
+    assert float(jnp.abs(rows(mla[0], p)[0, 0] - rows(mla[0], p)[0, 5]
+                         ).max()) == 0.0                # no positions seen
+    assert float(jnp.abs(rows(turned, q)[0, 0] - rows(turned, q)[0, 5]
+                         ).max()) > 1e-3
+
+
 # ------------------------------------------------------------------- experts
+@pytest.mark.parametrize("pairs,held,routed,rows", [
+    (128, 16, 256, 128), (131072, 16, 256, 16384), (128, 64, 64, 128),
+    (131072, 64, 64, 131072), (4096, 64, 64, 4096), (1024, 8, 64, 256)])
+def test_pass_rows_is_twice_the_fair_share_at_most_every_pick(pairs, held,
+                                                              routed, rows):
+    """A share of the experts gets passes of twice its fair share; with every
+    expert held one pass takes every pick."""
+    assert moe._pass_rows(pairs, held, routed) == rows
+
+
 def test_the_shares_of_all_chips_sum_to_the_uncut_layer(ref):
     """Four chips of 2 experts each, the shared expert counted once: their
     partial results add up to the reference's uncut layer (8 experts held),

@@ -373,6 +373,36 @@ class TestSpeculative:
                                     temperature=0.9, key=key)
         assert a == b
 
+    @pytest.mark.parametrize("rows", [1, 3])
+    def test_a_models_own_mtp_head_drafts_through_the_serving_model(
+            self, rows):
+        """The self-draft (a latent-attention expert model's MTP module in
+        place of a draft net) through ``ServingModel``: ragged rows crossing
+        page boundaries come out as undrafted greedy decoding serves them,
+        the module's rows ride the same pool, and its blocks come back."""
+        from deeplearning4j_tpu.zoo import Glm4MoeLite
+
+        zoo = Glm4MoeLite.tiny(vocab_size=VOCAB, max_length=MAXLEN)
+        net = zoo.init()
+        draft = zoo.mtp()
+        draft.params = draft.module.initialize(jax.random.PRNGKey(9))
+        kw = dict(kind="generate", paged=True, block_size=4,
+                  max_length=MAXLEN, bucketing="batch=1,2,4;seq=8,16")
+        plain = ServingModel(net, "plain", **kw)
+        model = ServingModel(net, "drafted", self_draft=draft, **kw)
+        prompts = RAGGED[:rows]
+        want = plain.generator.generate(prompts, max_new_tokens=8)
+        stats = {}
+        assert model.generator.generate(prompts, max_new_tokens=8,
+                                        stats=stats) == want
+        assert len(stats["draft_accept_rate"]) == rows
+        assert model.describe()["speculative"] == {"spec_tokens": 1,
+                                                   "self_draft": True}
+        pool = model.generator.pool
+        assert len(pool.pools) == len(plain.generator.pool.pools) + 1
+        assert pool.free_blocks() == pool.num_blocks
+        assert pool.conservation()[0]
+
 
 class TestPoolExhaustion:
     def test_exhaustion_sheds_and_blocks_reused(self, target_net):
