@@ -12,8 +12,16 @@ Mixers (``mixer=``):
 - ``"mla"`` — multi-head latent attention: the cache is one row ``[c_t |
   kr_t]`` a token for all heads (``kv_lora_rank + qk_rope_dim`` numbers, in
   the parameters' type), behind the same page tables as a per-head K/V
-  cache. Prefill attends in the expanded form, decode in the absorbed form
-  over the paged rows (ops/attention.latent_paged_attention). Two things
+  cache. A row is STORED at that width rounded up to whole 128-lane tiles
+  (576 -> 640, ``[c | kr | zeros]``; a width that is whole tiles already is
+  stored as it is): a TPU lays an (S, R) array whose R is not whole tiles
+  out with S on the lanes, and every program that scatters or gathers slots
+  then copies the whole pool to a slot-major layout and back (the rule of
+  nn/transformer.py's ``init_pool``; PERF.md, PR 28 and PR 36). The extra
+  lanes are exactly zero and meet zeros of the query, so every product is
+  the configuration's; ``Wdkv`` and every byte count keep the latent width.
+  Prefill attends in the expanded form, decode in the absorbed form over
+  the paged rows (ops/attention.latent_paged_attention). Two things
   are a configuration's: ``rope`` rotates the ``qk_rope_dim`` dims of every
   head's query and of the shared key row by each token's own position
   (the key BEFORE it is written, so the cache holds ``[c | RoPE(kr)]`` and
@@ -51,6 +59,8 @@ from deeplearning4j_tpu.ops import attention as attn_ops
 from deeplearning4j_tpu.ops import kda
 
 F32 = jnp.float32
+#: the minor width of a TPU's (8, 128) tile: a cache row is stored in whole ones
+LANES = 128
 
 
 def rms_norm(x, weight, eps: float):
@@ -196,7 +206,13 @@ class HybridDecoderBlock(Layer):
 
     @property
     def _row(self) -> int:
+        """The latent width: what ``Wdkv`` makes and every byte count reads."""
         return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def _stored(self) -> int:
+        """The width a latent row is stored at: whole lane tiles."""
+        return -(-self._row // LANES) * LANES
 
     # ----------------------------------------------------------- parameters
     def initialize(self, key, input_shape):
@@ -341,13 +357,15 @@ class HybridDecoderBlock(Layer):
             return rope(x, positions, self.rope_theta)
 
     def _mla_rows(self, params, h, positions):
-        """Normed input -> the cache rows [RMSNorm(c) | kr] (B, T, R), the
-        key dims rotated by ``positions`` before they are cached."""
+        """Normed input -> the cache rows [RMSNorm(c) | kr | 0] (B, T,
+        stored width), the key dims rotated by ``positions`` before they are
+        cached, the lanes past the latent width exactly zero."""
         ckr = _mm(h, params["Wdkv"])
         c = rms_norm(ckr[..., :self.kv_lora_rank], params["kv_norm"],
                      self.eps)
         kr = self._rope(ckr[..., self.kv_lora_rank:], positions)
-        return jnp.concatenate([c, kr], -1)
+        fill = jnp.zeros(c.shape[:-1] + (self._stored - self._row,), F32)
+        return jnp.concatenate([c, kr, fill], -1)
 
     def _mla_q(self, params, h, positions):
         b, t, _ = h.shape
@@ -378,7 +396,8 @@ class HybridDecoderBlock(Layer):
         # whole prompts are right-padded: every row's positions are 0..T-1
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
         rows = self._mla_rows(params, h, positions)
-        c, kr = rows[..., :self.kv_lora_rank], rows[..., self.kv_lora_rank:]
+        c = rows[..., :self.kv_lora_rank]
+        kr = rows[..., self.kv_lora_rank:self._row]
         kv = _mm(c, params["Wukv"]).reshape(b, t, nh, dn + dv)
         dt = params["Wukv"].dtype
         kc, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
@@ -414,10 +433,12 @@ class HybridDecoderBlock(Layer):
         nh, dn, dv = self.n_heads, self.qk_nope_dim, self.v_head_dim
         wukv = params["Wukv"].reshape(self.kv_lora_rank, nh, dn + dv)
         q = self._mla_q(params, h, positions)
+        # zeros against the stored row's zero lanes: they add 0 to a score
+        fill = jnp.zeros(q.shape[:-1] + (self._stored - self._row,), F32)
         q_abs = jnp.concatenate(
             [jnp.einsum("bwhd,rhd->bwhr", q[..., :dn].astype(wukv.dtype),
                         wukv[..., :dn], preferred_element_type=F32),
-             q[..., dn:]], axis=-1)                       # (B, W, heads, R)
+             q[..., dn:], fill], axis=-1)            # (B, W, heads, stored)
         ctx = attn_ops.latent_paged_attention(
             jnp.moveaxis(q_abs, 1, 2), pool, tables, positions, block_size,
             self.kv_lora_rank, self._mla_scale)           # (B, heads, W, r)
@@ -442,15 +463,21 @@ class HybridDecoderBlock(Layer):
     def init_pool(self, num_slots: int):
         """``cache_kind`` ``"state"``: ``num_slots`` stream slots of the KDA
         state (float32) and the convolutions' tail; ``"tokens"``:
-        ``num_slots`` latent rows in the parameters' type. A routed
-        feed-forward adds its counters."""
+        ``num_slots`` latent rows in the parameters' type, each of the
+        STORED width (the latent width rounded up to whole 128-lane tiles,
+        the extra lanes zero), because a TPU lays an (S, R) array whose R is
+        not whole tiles out with S on the lanes, and every program that
+        scatters or gathers slots then copies the whole pool to a slot-major
+        layout and back (the rule of nn/transformer.py's ``init_pool``,
+        PERF.md PR 28; here PR 36). A routed feed-forward adds its
+        counters."""
         if self.mixer == "kda":
             pool = {"state": jnp.zeros((num_slots, self.n_heads,
                                         self.head_dim, self.head_dim), F32),
                     "conv": jnp.zeros((num_slots, self.conv_size - 1,
                                        3 * self._inner), F32)}
         else:
-            pool = {"rows": jnp.zeros((num_slots, self._row),
+            pool = {"rows": jnp.zeros((num_slots, self._stored),
                                       self.param_dtype)}
         if self.ffn == "moe":
             pool["moe"] = jnp.zeros((2, len(moe.MOE_STATS) + 1), jnp.int32)
@@ -468,7 +495,7 @@ class HybridDecoderBlock(Layer):
             a, rows = self._mla_expanded(
                 params, rms_norm(x, params["norm1"], self.eps), mask)
             pool = dict(pool, rows=pool["rows"].at[where.reshape(-1)].set(
-                rows.reshape(-1, self._row).astype(pool["rows"].dtype)))
+                rows.reshape(-1, self._stored).astype(pool["rows"].dtype)))
         return self._finish(params, x, a, pool, mask.astype(bool))
 
     def decode_window_paged(self, params, x_w, pool, where, positions,
@@ -498,7 +525,7 @@ class HybridDecoderBlock(Layer):
             slots = jnp.where(live, slots, 0)
             rows = self._mla_rows(params, h, positions)
             pool = dict(pool, rows=pool["rows"].at[slots.reshape(-1)].set(
-                rows.reshape(-1, self._row).astype(pool["rows"].dtype)))
+                rows.reshape(-1, self._stored).astype(pool["rows"].dtype)))
             a = self._mla_absorbed(params, h, pool["rows"], where, positions,
                                    block_size)
         return self._finish(params, x_w, a, pool, live, phase=phase)

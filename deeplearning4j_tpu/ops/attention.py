@@ -613,7 +613,9 @@ def latent_paged_attention(q_abs, pool, tables, positions, block_size: int,
     paged latent rows.
 
     ``pool``: (S, R) rows ``[c_t | kr_t]`` — the compressed key/value of a
-    token and its shared extra key dims, one row a token for ALL heads.
+    token and its shared extra key dims, one row a token for ALL heads (R
+    the width the caller stores: nn/decoder.py pads rows and queries with
+    zeros to whole 128-lane tiles, which add 0 to every score).
     ``q_abs``: (B, H, W, R) queries already carried into that row space,
     ``[qc_h W_uk,h^T | qr_h]``. Every head attends the same rows, so the
     heads are W more queries of one head whose key is the row and whose

@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 from chipbench.manifest import module_from
-from deeplearning4j_tpu.nn.decoder import HybridDecoderBlock, rope
+from deeplearning4j_tpu.nn.decoder import (HybridDecoderBlock, rms_norm,
+                                           rope)
 from deeplearning4j_tpu.ops import attention as attn_ops
 from deeplearning4j_tpu.serving import ServingModel
 from deeplearning4j_tpu.serving.generate import Generator, SelfDraft
@@ -247,13 +248,49 @@ def test_a_resumed_window_writes_the_whole_prefills_rows():
     np.testing.assert_allclose(y_tail, y_whole[:, 13:], atol=1e-4)
 
 
+def test_written_rows_are_the_latent_rows_and_zero_lanes():
+    """One block, rows of 19 and 17 tokens: a prefill of the first 9, a
+    resumed window of 4 and six decode steps (the shorter row past its limit
+    in the last two) leave, in every slot a live token wrote, lanes 0-31 as
+    ``_mla_rows`` computes them at the token's own position and lanes 32-127
+    exactly 0; so is every other lane 32-127 of the pool."""
+    blk = _block(rope=True, q_lora_rank=24)
+    p, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 19, 64))
+    bs, lens = 4, np.asarray([19, 17])
+    tables = jnp.asarray(np.arange(1, 11).reshape(2, 5), jnp.int32)
+    pos = jnp.broadcast_to(jnp.arange(19), (2, 19))
+    slots = np.asarray(attn_ops.paged_slots(tables, pos, bs))
+    limits = jnp.asarray(lens - 1, jnp.int32)
+    _, pool = blk.prefill_paged(p, x[:, :9], blk.init_pool(11 * bs),
+                                jnp.asarray(slots[:, :9]),
+                                mask=jnp.ones((2, 9)))
+    _, pool = blk.prefill_resume_paged(p, x[:, 9:13], pool, tables,
+                                       pos[:, 9:13], bs, limits=limits)
+    for t in range(13, 19):
+        _, pool = blk.decode_window_paged(p, x[:, t:t + 1], pool, tables,
+                                          pos[:, t:t + 1], bs, limits=limits)
+    got = np.asarray(pool["rows"])
+    assert got.shape == (44, 128)
+    assert not got[:, 32:].any()
+    want = np.asarray(blk._mla_rows(
+        p, rms_norm(x, p["norm1"], blk.eps), pos))
+    assert want.shape == (2, 19, 128) and not want[..., 32:].any()
+    for i, n in enumerate(lens):
+        np.testing.assert_allclose(got[slots[i, :n]], want[i, :n], atol=1e-5)
+        assert np.abs(want[i, :n, :32]).sum(-1).min() > 0
+    # the row past its limit wrote to the trash block and not to its slots
+    assert not got[slots[1, 17:]].any()
+
+
 # ------------------------------------------------------------ the latent block
 def _block(cls=HybridDecoderBlock, **kw):
     """One latent block with weights large enough (N(0, 0.15)) for the
     softmax to see the scores."""
+    kw = dict(kv_lora_rank=24, init_range=0.15) | kw
     return cls(hidden_size=64, mixer="mla", ffn="dense", n_heads=2,
-               kv_lora_rank=24, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
-               ffn_size=32, rope_theta=1e4, init_range=0.15, **kw)
+               qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, ffn_size=32,
+               rope_theta=1e4, **kw)
 
 
 def _window_against_full(blk, full_blk=None):
@@ -276,15 +313,22 @@ def _window_against_full(blk, full_blk=None):
     return got, jnp.take_along_axis(full, win[..., None], axis=1)
 
 
+@pytest.mark.parametrize("latent", [24, 120],
+                         ids=["padded-32-to-128", "whole-tile-128"])
 @pytest.mark.parametrize("rank", [0, 24], ids=["full-rank-q", "low-rank-q"])
 @pytest.mark.parametrize("rotate", [False, True], ids=["unrotated", "rope"])
-def test_absorbed_window_is_the_expanded_form(rotate, rank):
+def test_absorbed_window_is_the_expanded_form(rotate, rank, latent):
     """With and without the rotation and the query rank (Kimi's layers are
-    the pair without): the absorbed window over the cached ``[c | RoPE(kr)]``
-    is the expanded attention, rows of unequal length at their own
-    positions."""
-    blk = _block(rope=rotate, q_lora_rank=rank)
+    the pair without), a row stored wider than the latent width (32 in 128
+    lanes, the queries padded to match) and one that is whole tiles as it
+    is: the absorbed window over the cached ``[c | RoPE(kr)]`` is the
+    expanded attention, rows of unequal length at their own positions."""
+    # the wider latent's scores want smaller weights to stay near 0.6
+    blk = _block(rope=rotate, q_lora_rank=rank, kv_lora_rank=latent,
+                 init_range=0.15 if latent == 24 else 0.1)
     p, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    assert blk.init_pool(8)["rows"].shape == (8, 128)
+    assert p["Wdkv"].shape == (64, latent + 8)
     assert ("Wdq" in p, "Wq" in p) == (bool(rank), not rank)
     got, want = _window_against_full(blk)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)

@@ -81,7 +81,10 @@ def _reference_logits(ref, w, prompts, served_tokens, dtype=None):
 def test_prefill_then_decode_matches_the_references_full_forward(ref, served):
     """Prefill and 11 decode steps through the paged path, ragged prompts
     and a padded row in one batch: the logits behind every served token are
-    the reference's (full forward, no cache) to 2e-4."""
+    the reference's (full forward, no cache) to 2e-4. Every slot a stream
+    wrote then holds a latent row in lanes 0-31 and exactly 0 in lanes
+    32-127 of the stored width, as does the rest of the pool (the trash
+    block takes the padding's rows, zero lanes and all)."""
     w, gen = served
     raw = gen._raw_params()
     tokens, lengths, b_real, lens = gen._prep(PROMPTS, 12)
@@ -99,6 +102,12 @@ def test_prefill_then_decode_matches_the_references_full_forward(ref, served):
         pos = pos + 1
     out.append(jnp.argmax(got[-1], -1).astype(jnp.int32))
     gen.pool.release(tables_list, held)
+    rows = np.asarray(gen.pool.pools[3]["rows"])
+    written = [tables_list[i][t // 8] * 8 + t % 8
+               for i, n in enumerate(lens) for t in range(n + 11)]
+    assert rows.shape[1] == 128 and len(set(written)) == sum(lens) + 33
+    assert not rows[:, 32:].any()
+    assert np.abs(rows[written, :32]).sum(axis=1).min() > 0
     got = np.stack([np.asarray(g) for g in got], 1)[:3]       # (3, 12, V)
     served_tokens = np.stack([np.asarray(o) for o in out], 1)[:3].tolist()
     want = np.asarray(_reference_logits(ref, w, PROMPTS, served_tokens))
@@ -576,17 +585,19 @@ def test_the_pools_figures_count_cache_and_not_the_router_counters(served):
     _, gen = served
     pool = gen.pool
     assert sum("moe" in p for p in pool.pools) == 3
-    # one MLA layer: a latent row of kv_lora_rank + qk_rope_head_dim float32
-    assert pool.bytes_per_token() == (24 + 8) * 4
+    # one MLA layer: a latent row of kv_lora_rank + qk_rope_head_dim float32,
+    # stored in whole 128-lane tiles (32 -> 128)
+    assert pool.bytes_per_token() == 128 * 4
     # three KDA layers: (heads, 16, 16) float32 + 3 earlier inputs of q|k|v
     assert pool.bytes_per_stream_state() == 3 * (2 * 16 * 16 + 3 * 3 * 32) * 4
     stats = pool.stats()
     assert stats["dtype_by_kind"] == {"tokens": "float32", "state": "float32"}
     assert stats["bytes_by_kind"] == {
-        "tokens": pool.num_blocks * 8 * 128, "state": 4 * 9600}
+        "tokens": pool.num_blocks * 8 * 128 * 4, "state": 4 * 9600}
     # copy-on-write on a routed layer with rows (MLA + experts): the rows
     # of the block move, the counters stay as they were
     mla = pool.pools[3]
+    assert mla["rows"].shape == (pool.num_slots, 128)
     rows = jnp.arange(mla["rows"].size, dtype=jnp.float32).reshape(
         mla["rows"].shape)
     moe = mla["moe"] + 7
@@ -594,6 +605,38 @@ def test_the_pools_figures_count_cache_and_not_the_router_counters(served):
     assert out["moe"] is moe
     np.testing.assert_array_equal(out["rows"][16:24], rows[8:16])
     np.testing.assert_array_equal(out["rows"][:16], rows[:16])
+
+
+# latent and rope widths -> the width a row is stored at
+STORED = {
+    "both-configurations-576": (512, 64, 640),
+    "the-small-models-32": (24, 8, 128),
+    "one-lane-over-a-tile": (121, 8, 256),
+    "whole-tiles-already-256": (192, 64, 256),
+    "one-whole-tile-128": (120, 8, 128),
+}
+
+
+@pytest.mark.parametrize("case", STORED)
+def test_a_latent_row_is_stored_in_whole_lane_tiles(case):
+    """The pool's minor width is the latent width rounded up to whole
+    128-lane tiles, and the latent width itself where that is whole tiles
+    already; ``Wdkv`` keeps the latent width; ``_mla_rows`` hands back the
+    stored width with the extra lanes exactly zero."""
+    rank, dr, stored = STORED[case]
+    blk = HybridDecoderBlock(hidden_size=16, mixer="mla", ffn="dense",
+                             n_heads=1, kv_lora_rank=rank, qk_nope_dim=8,
+                             qk_rope_dim=dr, v_head_dim=8, ffn_size=8)
+    pool = blk.init_pool(24)["rows"]
+    assert pool.shape == (24, stored) and stored % 128 == 0
+    assert (stored == rank + dr) == ((rank + dr) % 128 == 0)
+    p, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    assert p["Wdkv"].shape == (16, rank + dr)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 16))
+    rows = blk._mla_rows(p, h, jnp.zeros((2, 3), jnp.int32))
+    assert rows.shape == (2, 3, stored)
+    assert np.asarray(rows[..., :rank + dr]).all()
+    assert not np.asarray(rows[..., rank + dr:]).any()
 
 
 @pytest.mark.parametrize("kw,what", [
