@@ -43,6 +43,7 @@ BF16_REL_TOL = 4e-2
 #: the largest value, twice over (docs/KERNELS.md)
 KDA_REL_TOL = 2 ** -7
 KDA_STEP_REL_TOL = 1e-5     # the decode step: float32, no matrix product
+SSM_REL_TOL = 1e-5          # the state-space kernels: float32, element-wise
 
 
 def say(msg: str) -> None:
@@ -378,6 +379,62 @@ def _kda_decode_case(b: int = 16, h: int = 32, d: int = 128,
                 "names are bit-identical")
 
 
+def _ssm_case(b: int = 64, t: int = 256, ch: int = 5120, n: int = 16) -> None:
+    """``selective_scan`` and ``selective_step_paged`` as the serving path
+    calls them on a TPU (one layer of ``jamba2-chat-open``: 64 rows x 256
+    positions, 5,120 channels of 16 states, a pool of 65 slots): each
+    kernel against the XLA form of the same float32 recurrence, with a
+    batch's lengths and rows (a row of length 0, padding rows on slot 0, a
+    finished row that keeps its slot). Element-wise float32 on the vector
+    unit in the same order: ``SSM_REL_TOL``."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import ssm
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 8)
+    x, z = (jax.random.normal(k, (b, t, ch)) for k in ks[:2])
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (b, t, ch)) - 3.0)
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1), (ch, n))
+    bm, cm = (jax.random.normal(k, (b, t, n)) for k in ks[3:5])
+    d, s0 = jnp.ones((ch,)), jnp.zeros((b, n, ch))
+    lengths = jnp.asarray(np.r_[0, 1, 77, 256, np.random.default_rng(0)
+                                .integers(32, 257, b - 4)], jnp.int32)
+    args = (x, dt, a, bm, cm, d, s0, lengths, z)
+    kernel, mosaic = _compile_on_chip(ssm.selective_scan, *args)
+    require(mosaic, "ssm scan: selective_scan on a TPU is a Mosaic kernel")
+    y, s = kernel(*args)
+    y_r, s_r = jax.jit(ssm._selective_scan_xla)(
+        x, dt, a.T, bm, cm, d, s0, lengths, z)
+    errs = rel_err(y, y_r), rel_err(s, s_r)
+    require(max(errs) <= SSM_REL_TOL and not bool(jnp.any(y[0])),
+            f"ssm scan: y and the final state match the XLA form, relative "
+            f"errors {errs[0]:.2e} {errs[1]:.2e}; a row of length 0 reads 0")
+    pool = jax.random.normal(ks[5], (b + 1, n, ch)) * 0.1
+    slots = np.zeros((b,), np.int32)
+    slots[:40] = np.random.default_rng(1).permutation(b)[:40] + 1
+    live = np.arange(b) < 40
+    live[4] = False                             # finished, keeps its slot
+    step = (x[:, :1], dt[:, :1], a, bm[:, :1], cm[:, :1], d, pool,
+            jnp.asarray(slots), jnp.asarray(live)[:, None], z[:, :1])
+    kernel, mosaic = _compile_on_chip(ssm.selective_step_paged, *step)
+    require(mosaic, "ssm step: selective_step_paged on a TPU is a Mosaic "
+            "kernel")
+    y, new = kernel(*step)
+    y_r, s_r = jax.jit(ssm._selective_scan_xla)(
+        x[:, :1], dt[:, :1], a.T, bm[:, :1], cm[:, :1], d, pool[slots], None,
+        z[:, :1])
+    named = slots[live]
+    errs = rel_err(y[live], y_r[live]), rel_err(new[named], s_r[live])
+    require(max(errs) <= SSM_REL_TOL,
+            f"ssm step: y and the named slots match the XLA form, relative "
+            f"errors {errs[0]:.2e} {errs[1]:.2e}")
+    rest = np.setdiff1d(np.arange(b + 1), named)
+    require(bool(jnp.array_equal(new[rest], pool[rest])),
+            f"ssm step: the {len(rest)} slots no live row names are "
+            "bit-identical")
+
+
 def leg_kernels() -> None:
     import jax
     import jax.numpy as jnp
@@ -461,6 +518,7 @@ def leg_kernels() -> None:
         conv_under("exact"), arr(8, 56, 56, 64), arr(3, 3, 64, 64, scale=0.05))
     _kda_prefill_case()
     _kda_decode_case()
+    _ssm_case()
     # stride 2 is a geometry supports() admits and Mosaic (JAX 0.9.0) refuses
     # to lower: forced pallas must say so, never run another path instead
     x2, w2 = arr(8, 56, 56, 256), arr(1, 1, 256, 128, scale=0.05)

@@ -1,7 +1,7 @@
 """Pre-norm decoder layers for served language models: a token embedding
 without position tables, the residual block ``x += mixer(RMSNorm(x)); x +=
 ffn(RMSNorm(x))`` with a choice of mixers and feed-forwards, and a normed,
-untied logits head.
+logits head (its own matrix, or the embedding's: ``tied``).
 
 Mixers (``mixer=``):
 
@@ -28,6 +28,20 @@ Mixers (``mixer=``):
   the absorbed decode stays one pass; without it the dims are carried
   unrotated and the layer sees no positions), and ``q_lora_rank`` makes the
   query low-rank (``RMSNorm(h W_dq) W_uq``; 0 = one full-rank ``Wq``).
+- ``"mamba"`` — the selective state-space mixer of Mamba as Jamba stacks it
+  (ops/ssm.py): ``[x | z] = h W_in``, ``x = silu(causal_conv(x) + b)``,
+  ``[dt | B | C] = x W_x``, each RMS-normed with a scale of its own (Jamba's
+  step), ``dt = softplus(dt W_dt + b_dt)``, the scan over ``d_state`` states
+  a channel of ``expand x hidden`` channels, ``(y silu(z)) W_out``. Its
+  cache is a STATE like KDA's, one slot a stream: the (d_state, channels)
+  float32 states, channels on the lanes, and the convolution's last
+  ``conv_size - 1`` inputs.
+- ``"gqa"`` — softmax attention with ``n_heads`` query heads over
+  ``n_kv_heads`` key/value heads and no positions. The cache is one row a
+  token, ``n_kv_heads x [k | v]`` in the parameters' type (256 numbers at
+  one head of 128: whole lane tiles, the rule above). Prefill attends in the
+  expanded causal form, decode through the same block-chunked pass as the
+  latent rows (ops/attention.grouped_paged_attention).
 
 Feed-forwards (``ffn=``): ``"dense"`` gated SiLU, or ``"moe"``: a sigmoid
 router over ``n_experts`` with a selection bias, the weights of the chosen
@@ -49,6 +63,7 @@ float32.
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +71,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn import moe
 from deeplearning4j_tpu.nn.layers import Layer, register_layer
 from deeplearning4j_tpu.ops import attention as attn_ops
-from deeplearning4j_tpu.ops import kda
+from deeplearning4j_tpu.ops import kda, ssm
 
 F32 = jnp.float32
 #: the minor width of a TPU's (8, 128) tile: a cache row is stored in whole ones
@@ -77,6 +92,14 @@ def _mm(x, w):
 
 def _normal(key, shape, std, dtype, mean=0.0):
     return (mean + std * jax.random.normal(key, shape, F32)).astype(dtype)
+
+
+def _step_bias(key, n: int, dtype):
+    """The inverse softplus of ``n`` step sizes drawn log-uniform in
+    (0.001, 0.1): the bias a state mixer's softplus turns back into them."""
+    step = jnp.exp(jax.random.uniform(key, (n,), F32, jnp.log(1e-3),
+                                      jnp.log(1e-1)))
+    return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
 
 
 def rope(x, positions, theta: float):
@@ -130,22 +153,41 @@ class TokenEmbeddingLayer(Layer):
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class NormedLogitsLayer(Layer):
-    """Final RMSNorm and an untied hidden x vocab head without bias; float32
-    logits (a bfloat16 logit near 8 has steps of 0.03)."""
+    """Final RMSNorm and a hidden x vocab head without bias; float32 logits
+    (a bfloat16 logit near 8 has steps of 0.03). ``tied``: the head is the
+    embedding itself. The layer then owns the norm alone and reads the
+    (vocab, hidden) matrix under ``"E"``, which :meth:`tie` puts there BY
+    REFERENCE: one array on the device, contracted over its own second axis
+    (``n E^T``), no second copy and no transposed one."""
 
     n_in: int = 0
     n_out: int = 0
     eps: float = 1e-5
     init_range: float = 0.02
     param_dtype: str = "float32"
+    tied: bool = False
 
     def initialize(self, key, input_shape):
-        return {"norm": jnp.ones((self.n_in,), self.param_dtype),
-                "W": _normal(key, (self.n_in, self.n_out), self.init_range,
-                             self.param_dtype)}, {}
+        p = {"norm": jnp.ones((self.n_in,), self.param_dtype)}
+        if not self.tied:
+            p["W"] = _normal(key, (self.n_in, self.n_out), self.init_range,
+                             self.param_dtype)
+        return p, {}
+
+    @staticmethod
+    def tie(params, emb_params):
+        """The head's parameters with the embedding's matrix beside the
+        norm: the same array, not a copy."""
+        return dict(params, E=emb_params["word"])
 
     def _logits(self, params, x):
-        return _mm(rms_norm(x, params["norm"], self.eps), params["W"])
+        n = rms_norm(x, params["norm"], self.eps)
+        if not self.tied:
+            return _mm(n, params["W"])
+        e = params["E"]
+        return jax.lax.dot_general(
+            n.astype(e.dtype), e, (((n.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=F32)
 
     def apply(self, params, state, x, *, training=False, key=None, mask=None):
         return self._logits(params, x), state
@@ -154,19 +196,36 @@ class NormedLogitsLayer(Layer):
         return (input_shape[0], self.n_out)
 
 
+class StateWalk(NamedTuple):
+    """What a ``"state"`` mixer says of itself to the serving path
+    (serving/generate.py counts by it and imports no op module)."""
+
+    #: names the counters: ``serving.<name>_prefill_<unit>_live_total``, ...
+    name: str
+    #: what its prefill walks, and how many positions one is
+    unit: str
+    size: int
+    #: whether the prefill's counts are summed over the layers
+    per_layer: bool
+
+
+_STATE_WALKS = {"kda": StateWalk("kda", "chunks", kda.CHUNK, False),
+                "mamba": StateWalk("ssm", "positions", 1, True)}
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class HybridDecoderBlock(Layer):
     """One pre-norm residual block (module doc)."""
 
     hidden_size: int = 0
-    mixer: str = "kda"            # "kda" | "mla"
+    mixer: str = "kda"            # "kda" | "mla" | "mamba" | "gqa"
     ffn: str = "dense"            # "dense" | "moe"
     n_heads: int = 1
     eps: float = 1e-5
     init_range: float = 0.02
     param_dtype: str = "float32"
-    # kda
+    # kda; gqa's heads have the size too, mamba's convolution the length
     head_dim: int = 128           # dk = dv
     conv_size: int = 4
     gate_rank: int = 128          # the low-rank width of the two gates
@@ -178,6 +237,13 @@ class HybridDecoderBlock(Layer):
     q_lora_rank: int = 0          # 0 = one full-rank Wq
     rope: bool = False            # rotate the qk_rope_dim dims by position
     rope_theta: float = 10000.0
+    # mamba
+    d_state: int = 16
+    dt_rank: int = 0
+    expand: int = 2
+    conv_bias: bool = True
+    # gqa
+    n_kv_heads: int = 1
     # ffn
     ffn_size: int = 0             # dense width, or one expert's
     n_experts: int = 0            # the router's width
@@ -194,7 +260,13 @@ class HybridDecoderBlock(Layer):
     def cache_kind(self) -> str:
         """``"state"``: one slot a stream; ``"tokens"``: one row a token
         behind the page tables."""
-        return "state" if self.mixer == "kda" else "tokens"
+        return "state" if self.mixer in _STATE_WALKS else "tokens"
+
+    @property
+    def state_walk(self) -> StateWalk:
+        """Of a ``"state"`` layer: what its prefill walks and what its
+        counters are called (:class:`StateWalk`)."""
+        return _STATE_WALKS[self.mixer]
 
     @property
     def _held(self) -> int:
@@ -213,6 +285,26 @@ class HybridDecoderBlock(Layer):
     def _stored(self) -> int:
         """The width a latent row is stored at: whole lane tiles."""
         return -(-self._row // LANES) * LANES
+
+    @property
+    def _state_prefill(self):
+        """A ``"state"`` mixer's whole-prompt pass: (params, x, mask) ->
+        (mixer output, final state, convolution tail as its slot holds
+        it)."""
+        return self._kda_prefill if self.mixer == "kda" else self._ssm_prefill
+
+    @property
+    def _channels(self) -> int:
+        """The state-space mixer's inner width."""
+        return self.expand * self.hidden_size
+
+    @property
+    def _pool_row(self) -> int:
+        """The width of a ``"tokens"`` row as stored: a grouped-query
+        layer's ``n_kv_heads x [k | v]``, a latent layer's whole tiles."""
+        if self.mixer == "gqa":
+            return self.n_kv_heads * 2 * self.head_dim
+        return self._stored
 
     # ----------------------------------------------------------- parameters
     def initialize(self, key, input_shape):
@@ -235,9 +327,7 @@ class HybridDecoderBlock(Layer):
             # decay rates 1..16 a head, step sizes 0.001..0.1 a channel
             p["A_log"] = jnp.log(jax.random.uniform(
                 next(ks), (h,), F32, 1.0, 16.0)).astype(dt)
-            step = jnp.exp(jax.random.uniform(
-                next(ks), (inner,), F32, jnp.log(1e-3), jnp.log(1e-1)))
-            p["dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+            p["dt_bias"] = _step_bias(next(ks), inner, dt)
         elif self.mixer == "mla":
             h = self.n_heads
             q_out = h * (self.qk_nope_dim + self.qk_rope_dim)
@@ -251,6 +341,24 @@ class HybridDecoderBlock(Layer):
                          Wuq=mat(self.q_lora_rank, q_out))
             else:
                 p["Wq"] = mat(hs, q_out)
+        elif self.mixer == "mamba":
+            ch, n, rk = self._channels, self.d_state, self.dt_rank
+            p.update(Win=mat(hs, 2 * ch),
+                     conv_x=_normal(next(ks), (self.conv_size, ch),
+                                    self.conv_size ** -0.5, dt),
+                     Wx=mat(ch, rk + 2 * n), dt_norm=one(rk), B_norm=one(n),
+                     C_norm=one(n), Wdt=mat(rk, ch), Wout=mat(ch, hs),
+                     D=jnp.ones((ch,), dt))
+            if self.conv_bias:
+                p["conv_bias"] = mat(ch)
+            # Mamba's S4D-real start; step sizes 0.001..0.1 a channel
+            p["A_log"] = jnp.broadcast_to(jnp.log(
+                jnp.arange(1, n + 1, dtype=F32)), (ch, n)).astype(dt)
+            p["dt_bias"] = _step_bias(next(ks), ch, dt)
+        elif self.mixer == "gqa":
+            inner, kv = self._inner, self.n_kv_heads * self.head_dim
+            p.update(Wq=mat(hs, inner), Wk=mat(hs, kv), Wv=mat(hs, kv),
+                     Wo=mat(inner, hs))
         else:
             raise ValueError(f"unknown mixer {self.mixer!r}")
         f = self.ffn_size
@@ -446,15 +554,105 @@ class HybridDecoderBlock(Layer):
                        wukv[..., dn:], preferred_element_type=F32)
         return _mm(o.reshape(b, w, nh * dv), params["Wo"])
 
+    # ---------------------------------------------------------- Mamba mixer
+    def _ssm_inputs(self, params, h, tail):
+        """Normed input (B, T, H) and the convolution's earlier inputs ->
+        the scan's x, dt (B, T, channels), B, C (B, T, d_state), the output
+        gate z, and the projection before the convolution (the next
+        tail)."""
+        ch, n, rk = self._channels, self.d_state, self.dt_rank
+        xz = _mm(h, params["Win"])
+        raw, z = xz[..., :ch], xz[..., ch:]
+        with jax.named_scope("ssm.conv"):
+            x = kda.causal_conv(raw, params["conv_x"].astype(F32), tail)
+            if self.conv_bias:
+                x = x + params["conv_bias"].astype(F32)
+            x = jax.nn.silu(x)
+        with jax.named_scope("ssm.proj"):
+            low = _mm(x, params["Wx"])
+            norm = lambda a, name: rms_norm(a, params[name], self.eps)
+            dt = jax.nn.softplus(
+                _mm(norm(low[..., :rk], "dt_norm"), params["Wdt"])
+                + params["dt_bias"].astype(F32))
+            bm = norm(low[..., rk:rk + n], "B_norm")
+            cm = norm(low[..., rk + n:], "C_norm")
+        return x, dt, bm, cm, z, raw
+
+    def _ssm_rates(self, params):
+        """The decay rates A (channels, d_state) and the skip D."""
+        return (-jnp.exp(params["A_log"].astype(F32)),
+                params["D"].astype(F32))
+
+    def _ssm_prefill(self, params, x, mask):
+        """Whole prompts from an empty state -> (mixer output, final state,
+        the convolution's tail at each row's length, flat as a slot holds
+        it)."""
+        h = rms_norm(x, params["norm1"], self.eps)
+        xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, None)
+        lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
+        s0 = jnp.zeros((x.shape[0], self.d_state, self._channels), F32)
+        rates, skip = self._ssm_rates(params)
+        with jax.named_scope("ssm.scan"):
+            # padding moves no state, and a walk ends with its row's length
+            y, s = ssm.selective_scan(xc, dt, rates, bm, cm, skip, s0,
+                                      lengths, z)
+        tail = kda.conv_tail(raw, lengths, self.conv_size - 1)
+        return _mm(y, params["Wout"]), s, tail.reshape(x.shape[0], -1)
+
+    # -------------------------------------------------- grouped-query mixer
+    def _gqa_qkv(self, params, h):
+        """Normed input -> q (B, T, heads, d), k, v (B, T, kv heads, d) and
+        the cache rows ``n_kv_heads x [k | v]`` (B, T, row)."""
+        b, t, _ = h.shape
+        heads = lambda name, n: _mm(h, params[name]).reshape(
+            b, t, n, self.head_dim)
+        q = heads("Wq", self.n_heads)
+        k, v = heads("Wk", self.n_kv_heads), heads("Wv", self.n_kv_heads)
+        rows = jnp.concatenate([k, v], -1).reshape(b, t, self._pool_row)
+        return q, k, v, rows
+
+    def _gqa_expanded(self, params, h, mask, q_block: int = 256):
+        """Causal softmax over a whole prompt, every query head of a group
+        against the group's one key/value head, a block of queries at a time
+        -> (mixer output, cache rows)."""
+        b, t, _ = h.shape
+        g, d = self.n_kv_heads, self.head_dim
+        q, k, v, rows = self._gqa_qkv(params, h)
+        dt = params["Wq"].dtype
+        q = q.reshape(b, t, g, self.n_heads // g, d).astype(dt)
+        k, v = k.astype(dt), v.astype(dt)
+        k_pos = jnp.arange(t)
+        keep = mask.astype(bool)[:, None, None, None, :]
+
+        def block(q0):
+            qb = jax.lax.dynamic_slice_in_dim(q, q0, q_block, axis=1)
+            s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, k,
+                           preferred_element_type=F32)
+            ok = (k_pos[None, :] <= (q0 + jnp.arange(q_block))[:, None]) & keep
+            p = jax.nn.softmax(jnp.where(ok, s * d ** -0.5, -1e30), -1)
+            return jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(dt), v,
+                              preferred_element_type=F32)
+
+        q_block = min(q_block, t)
+        pad = -t % q_block
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, pad)) + ((0, 0),) * 3)
+        with jax.named_scope("gqa.attend"):
+            o = jax.lax.map(block, jnp.arange(0, t + pad, q_block))
+        o = jnp.moveaxis(o, 0, 1).reshape(b, t + pad, self._inner)[:, :t]
+        return _mm(o, params["Wo"]), rows
+
     # ------------------------------------------------------------- no cache
     def apply(self, params, state, x, *, training=False, key=None, mask=None):
         if mask is None:
             mask = jnp.ones(x.shape[:2], F32)
-        if self.mixer == "kda":
-            a, _, _ = self._kda_prefill(params, x, mask)
+        if self.cache_kind == "state":
+            a, _, _ = self._state_prefill(params, x, mask)
         else:
-            a, _ = self._mla_expanded(
-                params, rms_norm(x, params["norm1"], self.eps), mask)
+            attend = (self._gqa_expanded if self.mixer == "gqa"
+                      else self._mla_expanded)
+            a, _ = attend(params, rms_norm(x, params["norm1"], self.eps),
+                          mask)
         out, _ = self._finish(params, x.astype(F32), a, {},
                               mask.astype(bool))
         return out, state
@@ -462,8 +660,10 @@ class HybridDecoderBlock(Layer):
     # ---------------------------------------------------------- paged cache
     def init_pool(self, num_slots: int):
         """``cache_kind`` ``"state"``: ``num_slots`` stream slots of the KDA
-        state (float32) and the convolutions' tail; ``"tokens"``:
-        ``num_slots`` latent rows in the parameters' type, each of the
+        or state-space state (float32) and the convolutions' tail;
+        ``"tokens"``: ``num_slots`` rows in the parameters' type, a
+        grouped-query layer's ``n_kv_heads x [k | v]`` or a latent layer's
+        of the
         STORED width (the latent width rounded up to whole 128-lane tiles,
         the extra lanes zero), because a TPU lays an (S, R) array whose R is
         not whole tiles out with S on the lanes, and every program that
@@ -476,8 +676,16 @@ class HybridDecoderBlock(Layer):
                                         self.head_dim, self.head_dim), F32),
                     "conv": jnp.zeros((num_slots, self.conv_size - 1,
                                        3 * self._inner), F32)}
+        elif self.mixer == "mamba":
+            # the tail flat, a slot a row of whole lane tiles: with the 3
+            # inputs as an axis of their own the device re-lays the whole
+            # pool around every gather and scatter (the rule above)
+            pool = {"state": jnp.zeros((num_slots, self.d_state,
+                                        self._channels), F32),
+                    "conv": jnp.zeros((num_slots, (self.conv_size - 1)
+                                       * self._channels), F32)}
         else:
-            pool = {"rows": jnp.zeros((num_slots, self._stored),
+            pool = {"rows": jnp.zeros((num_slots, self._pool_row),
                                       self.param_dtype)}
         if self.ffn == "moe":
             pool["moe"] = jnp.zeros((2, len(moe.MOE_STATS) + 1), jnp.int32)
@@ -487,15 +695,17 @@ class HybridDecoderBlock(Layer):
         """Whole prompts (B, T, H). ``where`` is each stream's address in
         this block's cache: flat token slots (B, T) for ``"tokens"``, the
         stream's state slot (B,) for ``"state"`` (0 = the trash slot)."""
-        if self.mixer == "kda":
-            a, s, tail = self._kda_prefill(params, x, mask)
+        if self.cache_kind == "state":
+            a, s, tail = self._state_prefill(params, x, mask)
             pool = dict(pool, state=pool["state"].at[where].set(s),
                         conv=pool["conv"].at[where].set(tail))
         else:
-            a, rows = self._mla_expanded(
-                params, rms_norm(x, params["norm1"], self.eps), mask)
+            attend = (self._gqa_expanded if self.mixer == "gqa"
+                      else self._mla_expanded)
+            a, rows = attend(params, rms_norm(x, params["norm1"], self.eps),
+                             mask)
             pool = dict(pool, rows=pool["rows"].at[where.reshape(-1)].set(
-                rows.reshape(-1, self._stored).astype(pool["rows"].dtype)))
+                rows.reshape(-1, self._pool_row).astype(pool["rows"].dtype)))
         return self._finish(params, x, a, pool, mask.astype(bool))
 
     def decode_window_paged(self, params, x_w, pool, where, positions,
@@ -520,6 +730,34 @@ class HybridDecoderBlock(Layer):
             pool = dict(pool, state=state,
                         conv=pool["conv"].at[where].set(tail))
             a = self._kda_out(params, o, gate)
+        elif self.mixer == "mamba":
+            width = self.conv_size - 1
+            before = pool["conv"][where].reshape(-1, width, self._channels)
+            xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, before)
+            rates, skip = self._ssm_rates(params)
+            with jax.named_scope("ssm.step"):
+                # each live stream's state, stepped in its slot of the pool
+                y, state = ssm.selective_step_paged(
+                    xc, dt, rates, bm, cm, skip, pool["state"], where, live,
+                    z)
+            seen = jnp.concatenate([before, raw], axis=1)
+            tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
+            pool = dict(pool, state=state,
+                        conv=pool["conv"].at[where].set(
+                            tail.reshape(tail.shape[0], -1)))
+            a = _mm(y, params["Wout"])
+        elif self.mixer == "gqa":
+            slots = attn_ops.paged_slots(where, positions, block_size)
+            slots = jnp.where(live, slots, 0)
+            q, _, _, rows = self._gqa_qkv(params, h)
+            pool = dict(pool, rows=pool["rows"].at[slots.reshape(-1)].set(
+                rows.reshape(-1, self._pool_row).astype(pool["rows"].dtype)))
+            with jax.named_scope("gqa.attend"):
+                o = attn_ops.grouped_paged_attention(
+                    jnp.moveaxis(q, 1, 2), pool["rows"], where, positions,
+                    block_size, self.n_kv_heads)
+            a = _mm(jnp.moveaxis(o, 1, 2).reshape(*h.shape[:2], self._inner),
+                    params["Wo"])
         else:
             slots = attn_ops.paged_slots(where, positions, block_size)
             slots = jnp.where(live, slots, 0)

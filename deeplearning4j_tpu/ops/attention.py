@@ -629,3 +629,29 @@ def latent_paged_attention(q_abs, pool, tables, positions, block_size: int,
     o = paged_attention(q_abs.reshape(b, 1, h * w, r), pool, pool, tables,
                         jnp.tile(positions, (1, h)), block_size, scale=scale)
     return o.reshape(b, h, w, r)[..., :v_dim]
+
+
+def grouped_paged_attention(q, pool, tables, positions, block_size: int,
+                            n_kv_heads: int, scale=None):
+    """Decode attention of grouped query heads over paged key/value rows.
+
+    ``pool``: (S, n_kv_heads * 2 * Dh) rows ``n_kv_heads x [k_t | v_t]``, one
+    row a token. ``q``: (B, H, W, Dh), ``H`` a multiple of ``n_kv_heads``;
+    head ``h`` reads key/value head ``h // (H / n_kv_heads)``. The query
+    heads of a group are more queries of the group's one head, whose key
+    AND value is the whole ``[k | v]`` part of the row, as a latent row is
+    both in :func:`latent_paged_attention`: a query is carried as
+    ``[q | 0]``, the zeros meet ``v`` and add 0 to a score, and the last
+    ``Dh`` numbers of what :func:`paged_attention` returns are ``sum_t p_t
+    v_t``. Page table, chunking, trip count and the float32 online softmax
+    are that one pass's; no second walk of the table, no key or value pool
+    sliced out of the rows. -> (B, H, W, Dh)."""
+    b, h, w, dh = q.shape
+    per = h // n_kv_heads
+    if scale is None:
+        scale = dh ** -0.5
+    qz = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+    o = paged_attention(qz.reshape(b, n_kv_heads, per * w, 2 * dh), pool,
+                        pool, tables, jnp.tile(positions, (1, per)),
+                        block_size, scale=scale)
+    return o.reshape(b, h, w, 2 * dh)[..., dh:]

@@ -5,7 +5,8 @@ decode): a decoder-only LM as a ``MultiLayerNetwork`` of an embedding,
 decoder blocks and a per-token logits head — ``BertEmbeddingLayer`` →
 ``TransformerEncoderBlock(causal=True)`` × N → ``RnnOutputLayer``
 (``zoo.bert.Bert(causal=True, task="mlm")``), or ``TokenEmbeddingLayer`` →
-``HybridDecoderBlock`` × N → ``NormedLogitsLayer`` (``zoo.KimiLinear``), or
+``HybridDecoderBlock`` × N → ``NormedLogitsLayer`` (``zoo.KimiLinear``,
+``zoo.Glm4MoeLite``, ``zoo.Jamba``), or
 any layers that meet the BLOCK PROTOCOL below — served by compile-once
 executables:
 
@@ -61,8 +62,12 @@ The block protocol (what ``_decoder_parts`` checks; docs/SERVING.md):
   limits=)`` → ``(x_w, pool)``. ``where`` is the streams' address in that
   layer's cache: for ``"tokens"`` the flat token slots (B, T) in prefill and
   the page tables (B, max_blocks) in a decode window; for ``"state"`` the
-  state slots (B,) in both. A resumed or chunked prefill also needs
-  ``prefill_resume_paged``, the contiguous engine (``paged=False``)
+  state slots (B,) in both. A ``"state"`` layer also says what its prefill
+  walks and what its counters are called (``state_walk``: a name, a unit,
+  the unit's positions, whether counts sum over the layers; KDA walks
+  ``chunks`` of 64 under ``serving.kda_*``, the state-space scan
+  ``positions`` under ``serving.ssm_*``). A resumed or chunked prefill also
+  needs ``prefill_resume_paged``, the contiguous engine (``paged=False``)
   ``init_cache``/``prefill``/``decode_step``;
 - the last layer has ``_logits(params, x)``.
 
@@ -108,7 +113,6 @@ import numpy as np
 from deeplearning4j_tpu.data.bucketing import BucketingPolicy
 from deeplearning4j_tpu.nn.decoder import SelfDraft
 from deeplearning4j_tpu.ops import attention as attn_ops
-from deeplearning4j_tpu.ops import kda as kda_ops
 from deeplearning4j_tpu.serving.paged import (NOT_CACHE, BlockPool,
                                               PoolExhaustedError, PrefixCache,
                                               cache_kind, default_pool_blocks)
@@ -183,10 +187,15 @@ class Generator:
         self.net = net
         self.model_id = str(model_id)
         self.max_length = int(max_length or self.emb.max_position)
-        #: layers that keep a per-stream state; a net with any is
-        #: recurrent (module doc: what it refuses)
-        self._kda_layers = sum(cache_kind(b) == "state" for b in self.blocks)
-        self.recurrent = self._kda_layers > 0
+        #: layers that keep a per-stream state, counted by what each says
+        #: of itself (``state_walk``); a net with any is recurrent (module
+        #: doc: what it refuses)
+        self._state_layers: Dict = {}
+        for blk in self.blocks:
+            if cache_kind(blk) == "state":
+                walk = blk.state_walk
+                self._state_layers[walk] = self._state_layers.get(walk, 0) + 1
+        self.recurrent = bool(self._state_layers)
         if self.recurrent:
             for on, name in ((prefix_cache, "prefix_cache (and its "
                               "copy-on-write)"),
@@ -231,6 +240,11 @@ class Generator:
         # oracle's prefill, and the draft substrate
         self._prefill_jit = jax.jit(self._prefill)
         self._decode_jit = jax.jit(self._decode)
+        # the oracle's forward: the contiguous prefill, or every block's
+        # ``apply`` where the blocks keep no contiguous cache
+        self._oracle_jit = self._prefill_jit if all(
+            hasattr(b, "init_cache") for b in self.blocks) \
+            else jax.jit(self._forward)
         self.pool: Optional[BlockPool] = None
         if self.paged:
             # an AUTO-sized pool (pool_blocks=None) grows on demand
@@ -294,10 +308,10 @@ class Generator:
         #: KV positions the decode/verify steps read, and what they would
         #: read at the declared max_length (pool_stats, _count_kv_read)
         self._kv_read = self._kv_declared = 0
-        #: (row, chunk) pairs the KDA prefills held / declared, a layer
-        self._kda_live = self._kda_declared = 0
-        #: stream states the KDA decode steps moved / the bucket declared
-        self._kda_states_live = self._kda_states_declared = 0
+        #: per kind of state layer: units (chunks, positions) its prefills
+        #: held and declared, stream states its decode steps moved and the
+        #: bucket declared
+        self._walked = {walk: [0, 0, 0, 0] for walk in self._state_layers}
         #: nesting depth of generate() — > 1 while a chunk-yield runs a
         #: nested decode batch; nested runs never grow/reset the pool
         self._depth = 0
@@ -374,6 +388,21 @@ class Generator:
         h_last = x[jnp.arange(b), lengths - 1]
         logits = self.head._logits(params[-1], h_last)
         return logits, caches
+
+    def _forward(self, raw, tokens, lengths):
+        """The full-recompute oracle's forward for blocks without a
+        contiguous cache (nn/decoder.py): every block's ``apply`` over the
+        whole grown sequence -> (next-token logits (B, V), None)."""
+        note_trace("serving.full_forward", tokens, lengths)
+        params = self._params_of(raw)
+        b, t = tokens.shape
+        x, _ = self.emb.apply(params[0], {}, tokens)
+        pad_mask = (jnp.arange(t)[None, :]
+                    < lengths[:, None]).astype(x.dtype)
+        for i, blk in enumerate(self.blocks):
+            x, _ = blk.apply(params[i + 1], {}, x, mask=pad_mask)
+        return self.head._logits(params[-1],
+                                 x[jnp.arange(b), lengths - 1]), None
 
     def _decode(self, raw, caches, tokens, positions):
         """One contiguous-cache autoregressive step: tokens (B,) placed at
@@ -896,8 +925,7 @@ class Generator:
             logits, hidden = self._launch_prefill(raw, tokens, lengths,
                                                   tables)
             n_chunks = 1
-            if self.recurrent:
-                self._count_kda_chunks(batch, t, lens)
+            self._count_state_prefill(batch, t, lens)
         else:
             logits, n_chunks = self._prefill_windowed(
                 raw, tokens, lengths, tables, b_real, lens, starts,
@@ -992,33 +1020,39 @@ class Generator:
         tm.counter("serving.decode_kv_positions_declared_total", declared,
                    model=self.model_id)
 
-    def _count_kda_chunks(self, batch: int, t: int, lens):
-        """One whole prefill onto the counters: the chunks of a recurrent
-        layer's walk that hold a token (``ops/kda.kda_chunked``: a padding
-        row of the bucket has one) against batch x chunks of the bucket,
-        worked out here from the prompt lengths."""
-        live = sum(map(kda_ops.live_chunks, lens)) + batch - len(lens)
-        declared = batch * kda_ops.live_chunks(t)
-        self._kda_live += live
-        self._kda_declared += declared
-        tm.counter("serving.kda_prefill_chunks_live_total", live,
-                   model=self.model_id)
-        tm.counter("serving.kda_prefill_chunks_declared_total", declared,
-                   model=self.model_id)
+    def _count_state_prefill(self, batch: int, t: int, lens):
+        """One whole prefill onto the counters of each kind of state layer
+        (``serving.<name>_prefill_<unit>_live_total`` / ``_declared_total``):
+        the units of its walk that hold a token (chunks of 64 for KDA,
+        positions for the state-space scan; a padding row of the bucket has
+        one) against batch x units of the bucket, worked out here from the
+        prompt lengths and what the block says it walks (``state_walk``)."""
+        for walk, layers in self._state_layers.items():
+            units = lambda n: -(-n // walk.size)
+            times = layers if walk.per_layer else 1
+            live = (sum(map(units, lens)) + batch - len(lens)) * times
+            declared = batch * units(t) * times
+            self._walked[walk][0] += live
+            self._walked[walk][1] += declared
+            stem = f"serving.{walk.name}_prefill_{walk.unit}"
+            tm.counter(stem + "_live_total", live, model=self.model_id)
+            tm.counter(stem + "_declared_total", declared,
+                       model=self.model_id)
 
-    def _count_kda_states(self, batch: int, b_real: int, steps: int):
-        """One batch's decode steps onto the counters: the stream states a
-        step reads and writes in place (``ops/kda.kda_step_paged``: a live
-        row's, in every recurrent layer) against the bucket's rows, which
-        a gather and scatter of the declared batch moved."""
-        live = steps * b_real * self._kda_layers
-        declared = steps * batch * self._kda_layers
-        self._kda_states_live += live
-        self._kda_states_declared += declared
-        tm.counter("serving.kda_decode_states_live_total", live,
-                   model=self.model_id)
-        tm.counter("serving.kda_decode_states_declared_total", declared,
-                   model=self.model_id)
+    def _count_state_decode(self, batch: int, b_real: int, steps: int):
+        """One batch's decode steps onto the counters
+        (``serving.<name>_decode_states_live_total`` / ``_declared_total``):
+        the stream states a step reads and writes in place (a live row's,
+        in every layer of that kind) against the bucket's rows, which a
+        gather and scatter of the declared batch moved."""
+        for walk, layers in self._state_layers.items():
+            live, declared = steps * b_real * layers, steps * batch * layers
+            self._walked[walk][2] += live
+            self._walked[walk][3] += declared
+            stem = f"serving.{walk.name}_decode_states"
+            tm.counter(stem + "_live_total", live, model=self.model_id)
+            tm.counter(stem + "_declared_total", declared,
+                       model=self.model_id)
 
     @staticmethod
     def _moe_totals(pools):
@@ -1099,8 +1133,7 @@ class Generator:
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
         self._count_kv_read(batch, kv_read, len(steps) - 1)
-        if self.recurrent:
-            self._count_kda_states(batch, b_real, len(steps) - 1)
+        self._count_state_decode(batch, b_real, len(steps) - 1)
         self._count_moe()
         stacked = np.stack([np.asarray(s) for s in steps], axis=1)
         return self._trim(stacked, b_real, lens, max_new, eos_id)
@@ -1313,7 +1346,7 @@ class Generator:
         steps = []
         for i in range(max_new_tokens):
             tokens, lengths, b_real, _ = self._prep(grown, 1)
-            logits, _ = self._prefill_jit(raw, tokens, lengths)
+            logits, _ = self._oracle_jit(raw, tokens, lengths)
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
             steps.append(cur)
@@ -1468,15 +1501,14 @@ class Generator:
         s["decode_kv_read_share"] = (
             round(self._kv_read / self._kv_declared, 4)
             if self._kv_declared else None)
-        if self.recurrent:
-            # share of the declared chunks the KDA prefills walked so far
-            s["kda_prefill_chunk_share"] = (
-                round(self._kda_live / self._kda_declared, 4)
-                if self._kda_declared else None)
+        share = lambda part, of: round(part / of, 4) if of else None
+        for walk, (held, declared, moved, rows) in self._walked.items():
+            # share of the declared units the prefills walked so far
+            # (kda_prefill_chunk_share, ssm_prefill_position_share)
+            s[f"{walk.name}_prefill_{walk.unit[:-1]}_share"] = share(
+                held, declared)
             # share of the bucket's rows whose state the decode steps moved
-            s["kda_decode_state_share"] = (
-                round(self._kda_states_live / self._kda_states_declared, 4)
-                if self._kda_states_declared else None)
+            s[f"{walk.name}_decode_state_share"] = share(moved, rows)
         if self.cache is not None:
             s["prefix_cache"] = self.cache.stats()
         if self.prefill_chunk is not None:

@@ -11,6 +11,7 @@ ModelSerializer restore for locally saved weights instead.
 
 from deeplearning4j_tpu.zoo.bert import Bert  # noqa: F401
 from deeplearning4j_tpu.zoo.glm4_moe_lite import Glm4MoeLite  # noqa: F401
+from deeplearning4j_tpu.zoo.jamba import Jamba  # noqa: F401
 from deeplearning4j_tpu.zoo.kimi_linear import KimiLinear  # noqa: F401
 from deeplearning4j_tpu.zoo.unet import DiffusionUNet  # noqa: F401
 from deeplearning4j_tpu.zoo.models import (  # noqa: F401

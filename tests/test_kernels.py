@@ -946,6 +946,73 @@ class TestMosaicCrossLowering:
         assert "tpu_custom_call" not in text
         assert "stablehlo.scatter" in text
 
+    def test_ssm_scan_at_the_cells_shape(self, monkeypatch):
+        """``jamba2-chat-open``'s prefill bucket, one layer: 64 rows x 256
+        positions, 5,120 channels of 16 states. On a TPU the scan is one
+        Mosaic kernel and no (rows, positions, channels, states) array is
+        made; the CPU program of the same call holds no kernel."""
+        from jax import export
+
+        from deeplearning4j_tpu.ops import ssm
+
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        x = f32(64, 256, 5120)
+        avals = (x, x, f32(5120, 16), f32(64, 256, 16), f32(64, 256, 16),
+                 f32(5120), f32(64, 16, 5120), _aval(64, dtype=jnp.int32), x)
+        text = _lower_for_tpu(ssm.selective_scan, *avals)
+        assert text.count("tpu_custom_call") == 1
+        assert "64x256x5120x16" not in text and "64x256x16x5120" not in text
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: ssm.selective_scan(*a)   # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+        assert "64x256x5120x16" not in text and "64x256x16x5120" not in text
+
+    def test_ssm_step_at_the_cells_shape(self, monkeypatch):
+        """``jamba2-chat-open``'s decode bucket, one layer: 64 rows of one
+        token against a pool of 65 slots. On a TPU the step is one Mosaic
+        kernel whose pool result aliases its pool operand, and no gather or
+        scatter of the pool is left beside it; the CPU program of the same
+        call holds no kernel."""
+        from jax import export
+
+        from deeplearning4j_tpu.ops import ssm
+
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        x = f32(64, 1, 5120)
+        avals = (x, x, f32(5120, 16), f32(64, 1, 16), f32(64, 1, 16),
+                 f32(5120), f32(65, 16, 5120), _aval(64, dtype=jnp.int32),
+                 _aval(64, 1, dtype=jnp.bool_), x)
+        text = _lower_for_tpu(ssm.selective_step_paged, *avals)
+        assert text.count("tpu_custom_call") == 1
+        assert "output_operand_aliases" in text
+        assert "stablehlo.gather" not in text
+        assert "stablehlo.scatter" not in text
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: ssm.selective_step_paged(*a)
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+
+    @pytest.mark.parametrize("backend,w,ch", [
+        ("tpu", 1, 192), ("tpu", 2, 256), ("cpu", 1, 256)],
+        ids=["tpu-192-channels", "tpu-window-2", "cpu-256"])
+    def test_ssm_elsewhere_is_the_xla_form(self, monkeypatch, backend, w, ch):
+        """Channels that are not whole 128-lane tiles, a window of more than
+        one token, and any backend but the TPU gather, step and scatter."""
+        from deeplearning4j_tpu.ops import ssm
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        text = _lower_for_tpu(
+            ssm.selective_step_paged, f32(4, w, ch), f32(4, w, ch),
+            f32(ch, 8), f32(4, w, 8), f32(4, w, 8), f32(ch), f32(5, 8, ch),
+            _aval(4, dtype=jnp.int32), _aval(4, w, dtype=jnp.bool_),
+            f32(4, w, ch))
+        assert "tpu_custom_call" not in text
+        assert "stablehlo.scatter" in text
+
     def test_resnet50_forward_reaches_no_kernel(self):
         """The flagship at its default conf on a TPU host: 53 convolutions,
         every one on the exact path — before PR 21, 51 of them routed to

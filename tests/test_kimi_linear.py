@@ -249,11 +249,12 @@ def test_kda_prefill_counters_follow_the_lengths(served):
     gen.generate(PROMPTS, max_new_tokens=2)
     l1, d1 = read()
     assert (l1 - l0, d1 - d0) == (4, 4)
-    gen._count_kda_chunks(16, 1024, [1024, 65, 64, 1])
+    gen._count_state_prefill(16, 1024, [1024, 65, 64, 1])
     l2, d2 = read()
     assert (l2 - l1, d2 - d1) == (16 + 2 + 1 + 1 + 12, 16 * 16)
     share = gen.pool_stats()["kda_prefill_chunk_share"]
-    assert share == round(gen._kda_live / gen._kda_declared, 4)
+    (held, declared, _, _), = gen._walked.values()
+    assert share == round(held / declared, 4)
     assert "dl4j_serving_kda_prefill_chunks_live_total" \
         in tele.prometheus_text()
     from deeplearning4j_tpu.zoo import Bert
@@ -394,7 +395,8 @@ def test_kda_decode_counters_follow_the_live_rows(served):
     gen.generate(PROMPTS, max_new_tokens=1)            # no decode step
     assert read() == [l2, d2]
     share = gen.pool_stats()["kda_decode_state_share"]
-    assert share == round(gen._kda_states_live / gen._kda_states_declared, 4)
+    (_, _, moved, rows), = gen._walked.values()
+    assert share == round(moved / rows, 4)
     assert 0.25 < share <= 0.75
     text = tele.prometheus_text()
     for n in ("live", "declared"):
