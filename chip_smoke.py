@@ -386,7 +386,9 @@ def _ssm_case(b: int = 64, t: int = 256, ch: int = 5120, n: int = 16) -> None:
     kernel against the XLA form of the same float32 recurrence, with a
     batch's lengths and rows (a row of length 0, padding rows on slot 0, a
     finished row that keeps its slot). Element-wise float32 on the vector
-    unit in the same order: ``SSM_REL_TOL``."""
+    unit in the same order: ``SSM_REL_TOL``. Then the convolution's tail
+    beside them (:func:`_conv_step_case`), at this cell's shape and at
+    ``kimiL-chat-open``'s (16 rows, 3 x 12,288 channels, 17 slots)."""
     import jax
     import jax.numpy as jnp
 
@@ -433,6 +435,47 @@ def _ssm_case(b: int = 64, t: int = 256, ch: int = 5120, n: int = 16) -> None:
     require(bool(jnp.array_equal(new[rest], pool[rest])),
             f"ssm step: the {len(rest)} slots no live row names are "
             "bit-identical")
+    _conv_step_case("jamba2-chat-open", ch, slots, live, (b + 1, 3 * ch))
+    slots = np.zeros((16,), np.int32)
+    slots[:10] = np.random.default_rng(2).permutation(16)[:10] + 1
+    live = np.arange(16) < 10
+    live[4] = False                             # finished, keeps its slot
+    _conv_step_case("kimiL-chat-open", 12288, slots, live, (17, 3, 12288))
+
+
+def _conv_step_case(cell: str, ch: int, slots, live, pool_shape) -> None:
+    """``conv_step_paged`` as ``cell``'s decode step calls it on a TPU (one
+    state layer: a row a slot of ``slots``, one token of ``ch`` channels, 4
+    taps, the tail pool as the block's ``init_pool`` shapes it): the kernel
+    against the XLA form (gather, ``causal_conv``, ``conv_tail``, scatter).
+    The same float32 products and sums in the same order: bit-identical, on
+    y of the live rows, on every named slot and on every slot no live row
+    names."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import kda
+
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    raw = jax.random.normal(ks[0], (len(slots), 1, ch))
+    w = jax.random.normal(ks[1], (4, ch)) * 0.5
+    pool = jax.random.normal(ks[2], pool_shape)
+    args = (raw, w, pool, jnp.asarray(slots), jnp.asarray(live)[:, None])
+    kernel, mosaic = _compile_on_chip(kda.conv_step_paged, *args)
+    require(mosaic, f"conv step, {cell}: conv_step_paged on a TPU is a "
+            "Mosaic kernel")
+    (y, new), (y_r, new_r) = kernel(*args), jax.jit(kda._conv_step_xla)(*args)
+    named = slots[live]
+    require(bool(jnp.array_equal(y[live], y_r[live]))
+            and bool(jnp.array_equal(new[named], new_r[named]))
+            and not bool(jnp.array_equal(new[named], pool[named])),
+            f"conv step, {cell}: y of the {int(live.sum())} live rows and "
+            "their slots are the XLA form's, bit for bit")
+    rest = np.setdiff1d(np.arange(pool_shape[0]), named)
+    require(bool(jnp.array_equal(new[rest], pool[rest]))
+            and not bool(jnp.any(y[~live])),
+            f"conv step, {cell}: the {len(rest)} slots no live row names "
+            "are bit-identical, a dead row reads 0")
 
 
 def leg_kernels() -> None:

@@ -415,16 +415,18 @@ class HybridDecoderBlock(Layer):
         return x + y, pool
 
     # ------------------------------------------------------------ KDA mixer
-    def _kda_inputs(self, params, h, tail):
-        """Normed input (B, T, H) and the convolutions' earlier inputs ->
-        q, k, v, g (B, T, heads, d), beta (B, T, heads), the output gate,
-        and the projections before the convolution (the next tail)."""
+    def _kda_inputs(self, params, h, conv):
+        """Normed input (B, T, H) and the short convolution to run, ``conv(raw,
+        w)``: ``kda.causal_conv`` over whole prompts, the decode step's in
+        the streams' slots -> q, k, v, g (B, T, heads, d), beta (B, T,
+        heads), the output gate, and the projections before the convolution
+        (the next tail)."""
         b, t, _ = h.shape
         nh, d = self.n_heads, self.head_dim
         raw = jnp.concatenate([_mm(h, params["W" + n]) for n in "qkv"], -1)
         w = jnp.concatenate([params["conv_" + n] for n in "qkv"],
                             -1).astype(F32)
-        q, k, v = jnp.split(jax.nn.silu(kda.causal_conv(raw, w, tail)), 3, -1)
+        q, k, v = jnp.split(jax.nn.silu(conv(raw, w)), 3, -1)
         heads = lambda a: a.reshape(b, t, nh, d)
         unit = lambda a: a * jax.lax.rsqrt(
             jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
@@ -447,7 +449,8 @@ class HybridDecoderBlock(Layer):
         the convolutions' tail at each row's length)."""
         b, t, _ = x.shape
         h = rms_norm(x, params["norm1"], self.eps)
-        q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, None)
+        q, k, v, g, beta, gate, raw = self._kda_inputs(params, h,
+                                                       kda.causal_conv)
         lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
         s0 = jnp.zeros((b, self.n_heads, self.head_dim, self.head_dim), F32)
         # padding moves no state, and chunks behind a length are not visited
@@ -555,16 +558,17 @@ class HybridDecoderBlock(Layer):
         return _mm(o.reshape(b, w, nh * dv), params["Wo"])
 
     # ---------------------------------------------------------- Mamba mixer
-    def _ssm_inputs(self, params, h, tail):
-        """Normed input (B, T, H) and the convolution's earlier inputs ->
-        the scan's x, dt (B, T, channels), B, C (B, T, d_state), the output
-        gate z, and the projection before the convolution (the next
-        tail)."""
+    def _ssm_inputs(self, params, h, conv):
+        """Normed input (B, T, H) and the short convolution to run, ``conv(raw,
+        w)``: ``kda.causal_conv`` over whole prompts, the decode step's in
+        the streams' slots -> the scan's x, dt (B, T, channels), B, C (B, T,
+        d_state), the output gate z, and the projection before the
+        convolution (the next tail)."""
         ch, n, rk = self._channels, self.d_state, self.dt_rank
         xz = _mm(h, params["Win"])
         raw, z = xz[..., :ch], xz[..., ch:]
         with jax.named_scope("ssm.conv"):
-            x = kda.causal_conv(raw, params["conv_x"].astype(F32), tail)
+            x = conv(raw, params["conv_x"].astype(F32))
             if self.conv_bias:
                 x = x + params["conv_bias"].astype(F32)
             x = jax.nn.silu(x)
@@ -588,7 +592,7 @@ class HybridDecoderBlock(Layer):
         the convolution's tail at each row's length, flat as a slot holds
         it)."""
         h = rms_norm(x, params["norm1"], self.eps)
-        xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, None)
+        xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, kda.causal_conv)
         lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
         s0 = jnp.zeros((x.shape[0], self.d_state, self._channels), F32)
         rates, skip = self._ssm_rates(params)
@@ -718,33 +722,29 @@ class HybridDecoderBlock(Layer):
         live = jnp.ones(positions.shape, bool) if limits is None \
             else positions <= limits[:, None]
         h = rms_norm(x_w, params["norm1"], self.eps)
+        stepped = {}    # a "state" block's two pools, each stepped in place
+
+        def conv(raw, w):
+            # each live stream's tail, read and written in its slot
+            y, stepped["conv"] = kda.conv_step_paged(raw, w, pool["conv"],
+                                                     where, live)
+            return y
+
         if self.mixer == "kda":
-            before = pool["conv"][where]
-            q, k, v, g, beta, gate, raw = self._kda_inputs(params, h, before)
-            # each live stream's state, stepped in its slot of the pool
-            o, state = kda.kda_step_paged(q, k, v, g, beta, pool["state"],
-                                          where, live)
-            width = self.conv_size - 1
-            seen = jnp.concatenate([before, raw], axis=1)
-            tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
-            pool = dict(pool, state=state,
-                        conv=pool["conv"].at[where].set(tail))
+            q, k, v, g, beta, gate, _ = self._kda_inputs(params, h, conv)
+            # and its state, in the same slot of the state pool
+            o, stepped["state"] = kda.kda_step_paged(
+                q, k, v, g, beta, pool["state"], where, live)
+            pool = dict(pool, **stepped)
             a = self._kda_out(params, o, gate)
         elif self.mixer == "mamba":
-            width = self.conv_size - 1
-            before = pool["conv"][where].reshape(-1, width, self._channels)
-            xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, before)
+            xc, dt, bm, cm, z, _ = self._ssm_inputs(params, h, conv)
             rates, skip = self._ssm_rates(params)
             with jax.named_scope("ssm.step"):
-                # each live stream's state, stepped in its slot of the pool
-                y, state = ssm.selective_step_paged(
+                y, stepped["state"] = ssm.selective_step_paged(
                     xc, dt, rates, bm, cm, skip, pool["state"], where, live,
                     z)
-            seen = jnp.concatenate([before, raw], axis=1)
-            tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
-            pool = dict(pool, state=state,
-                        conv=pool["conv"].at[where].set(
-                            tail.reshape(tail.shape[0], -1)))
+            pool = dict(pool, **stepped)
             a = _mm(y, params["Wout"])
         elif self.mixer == "gqa":
             slots = attn_ops.paged_slots(where, positions, block_size)

@@ -33,6 +33,15 @@ Three forms of the same recurrence live here:
 
 A token with ``beta = 0`` and ``g = 0`` leaves the state as it was: that is
 how padded positions and finished rows are kept from moving a live state.
+
+The short convolution in front of a state mixer (KDA's q, k, v; ops/ssm.py's
+x) lives here once: :func:`causal_conv` over whole prompts,
+:func:`conv_tail` for what a decode step needs of them, and
+:func:`conv_step_paged`, the decode step on the streams' slots of a tail
+pool: on a TPU, one token a row and channels in whole lane tiles, one
+Pallas kernel (``conv_step``) that reads a live stream's last K-1 inputs,
+convolves and writes the new tail back in place; everywhere else gather,
+``causal_conv``, ``conv_tail``, scatter.
 """
 
 from __future__ import annotations
@@ -390,10 +399,11 @@ def _kda_chunked_pallas(q, k, v, g, beta, state, lengths=None,
     return o[:, :t].reshape(b, t, h, dv), s
 
 
-def _kernel_shapes(q, v) -> bool:
-    """Where the Pallas kernels run: a TPU, heads in whole 128-lane tiles."""
-    return (jax.default_backend() == "tpu" and q.shape[-1] % 128 == 0
-            and v.shape[-1] % 128 == 0)
+def _kernel_shapes(*arrays) -> bool:
+    """Where the Pallas kernels run: a TPU, every array's last dim (a head,
+    the convolution's channels) in whole 128-lane tiles."""
+    return (jax.default_backend() == "tpu"
+            and all(a.shape[-1] % 128 == 0 for a in arrays))
 
 
 def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
@@ -530,3 +540,150 @@ def conv_tail(x, lengths, width: int):
     idx = lengths[:, None] - width + jnp.arange(width)[None, :]  # (B, width)
     got = jnp.take_along_axis(x, jnp.maximum(idx, 0)[..., None], axis=1)
     return jnp.where((idx >= 0)[..., None], got, 0)
+
+
+# -- the convolution's decode step in place in the tail pool -----------------
+TAIL_ROWS = 8   # rows of one tile: slots of a flat pool's block, rows of x
+
+
+def _taps(seen, w_ref):
+    """``sum_j w[j] seen[j]`` in :func:`causal_conv`'s order."""
+    y = seen[0] * w_ref[0:1, :]
+    for j in range(1, len(seen)):
+        y = y + seen[j] * w_ref[j:j + 1, :]
+    return y
+
+
+def _conv_slots_kernel(x_ref, live_ref, w_ref, t_in, y_ref, t_out, *, c):
+    """Grid (group of TAIL_ROWS slots) over a flat pool: a tile of it holds
+    a lane tile of 8 slots, so the group is read whole, its live slots
+    stepped on whole tiles (``x_ref``: their rows' inputs, in slot order)
+    and written back whole, a slot no live row names as it was read."""
+    live = live_ref[:, 0:1] != 0
+    width = w_ref.shape[0] - 1
+    seen = [t_in[:, j * c:(j + 1) * c] for j in range(width)] + [x_ref[...]]
+    y_ref[...] = jnp.where(live, _taps(seen, w_ref), 0.0)
+    for j in range(width):
+        t_out[:, j * c:(j + 1) * c] = jnp.where(live, seen[j + 1], seen[j])
+
+
+def _conv_rows_kernel(slot_ref, x_ref, w_ref, t_in, y_ref, t_out):
+    """Grid (block of TAIL_ROWS rows, row of the block) over a pool of
+    (slots, K-1, C): the tail block is the row's slot, read and written in
+    place; a dead row (slot 0) keeps the trash slot as it is and reads 0."""
+    from jax.experimental import pallas as pl
+
+    r = pl.program_id(1)
+    live = slot_ref[pl.program_id(0) * TAIL_ROWS + r] != 0
+    at = pl.ds(r, 1)
+    width = w_ref.shape[0] - 1
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        y_ref[at, :] = jnp.zeros((1, y_ref.shape[1]), y_ref.dtype)
+        t_out[...] = t_in[...]
+
+    @pl.when(live)
+    def _():
+        seen = [t_in[0, j:j + 1, :] for j in range(width)] + [x_ref[at, :]]
+        y_ref[at, :] = _taps(seen, w_ref)
+        for j in range(width):
+            t_out[0, j:j + 1, :] = seen[j + 1]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _conv_step_pallas(raw, w, pool, slots, interpret: bool = False):
+    """One token a row on the slots of a tail pool, in place: ``raw`` (B,
+    C), ``w`` (K, C), ``pool`` (slots, K-1, C) or flat (slots, (K-1) C)
+    float32, ``slots`` (B,) with every dead row on the trash slot 0 -> (y
+    (B, C), pool); a dead row's y is 0. The pool is aliased input to output
+    and walked as the device tiles it. (slots, K-1, C) is tiled a slot: the
+    tail's block index is ``slots[row]`` for input and output alike, a live
+    stream's tail moves once each way and no other slot is touched. A flat
+    pool is tiled 8 slots a tile (Mosaic takes no block and no copy of one
+    row of it), so it is walked 8 slots a grid step, every group read and
+    written back whole, the rows' inputs gathered into slot order before
+    the kernel and y back into row order behind it: its cost goes by the
+    pool's slots, not by the live rows. Jitted, so that a program of 26
+    such layers lowers the kernel once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, c = raw.shape
+    kk = w.shape[0]
+    raw, w, slots = raw.astype(f32), w.astype(f32), slots.astype(jnp.int32)
+    call = functools.partial(
+        pl.pallas_call, input_output_aliases={3: 1}, interpret=interpret,
+        name="conv_step")
+    if pool.ndim == 2:
+        n = -(-pool.shape[0] // TAIL_ROWS) * TAIL_ROWS
+        named = (jnp.arange(n)[:, None] == slots[None, :]) \
+            & (slots != 0)[None, :]                         # (slot, row)
+        live = jnp.broadcast_to(
+            jnp.any(named, axis=1).astype(jnp.int32)[:, None], (n, 128))
+        block = lambda width: pl.BlockSpec((TAIL_ROWS, width),
+                                           lambda g: (g, 0))
+        y, pool = call(
+            functools.partial(_conv_slots_kernel, c=c),
+            grid=(n // TAIL_ROWS,),
+            in_specs=[block(c), block(128),
+                      pl.BlockSpec((kk, c), lambda g: (0, 0)),
+                      block(pool.shape[1])],
+            out_specs=[block(c), block(pool.shape[1])],
+            out_shape=[jax.ShapeDtypeStruct((n, c), f32),
+                       jax.ShapeDtypeStruct(pool.shape, f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+        )(raw[jnp.argmax(named, axis=1)], live, w, pool)
+        return y[slots], pool
+    pad = -b % TAIL_ROWS
+    block = pl.BlockSpec((TAIL_ROWS, c), lambda i, r, s: (i, 0))
+    tails = pl.BlockSpec((1,) + pool.shape[1:],
+                         lambda i, r, s: (s[i * TAIL_ROWS + r], 0, 0))
+    y, pool = call(
+        _conv_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=((b + pad) // TAIL_ROWS, TAIL_ROWS),
+            in_specs=[block, pl.BlockSpec((kk, c), lambda i, r, s: (0, 0)),
+                      tails],
+            out_specs=[block, tails]),
+        out_shape=[jax.ShapeDtypeStruct((b + pad, c), f32),
+                   jax.ShapeDtypeStruct(pool.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+    )(jnp.pad(slots, (0, pad)), jnp.pad(raw, ((0, pad), (0, 0))), w, pool)
+    return y[:b], pool
+
+
+def _conv_step_xla(raw, w, pool, slots, live):
+    """:func:`conv_step_paged` as XLA programs: the rows' tails gathered,
+    :func:`causal_conv`, :func:`conv_tail` of what each row has now seen,
+    and the scatter back (a token that is not live leaves its row's tail as
+    it was)."""
+    width = w.shape[0] - 1
+    before = pool[slots].reshape(raw.shape[0], width, raw.shape[2])
+    seen = jnp.concatenate([before, raw], axis=1)
+    tail = conv_tail(seen, width + jnp.sum(live, axis=1), width)
+    return (causal_conv(raw, w, before),
+            pool.at[slots].set(tail.reshape((-1,) + pool.shape[1:])))
+
+
+def conv_step_paged(raw, w, pool, slots, live):
+    """The decode window's convolution on the streams' slots of a tail
+    pool: ``raw`` (B, W, C) the projections before the convolution, ``w``
+    (K, C), ``pool`` (slots, K-1, C) or flat (slots, (K-1) C) float32,
+    ``slots`` (B,), ``live`` (B, W) bool -> (y (B, W, C), pool). ``y`` is
+    ``causal_conv(raw, w, tail)`` before any bias or activation, and a
+    stream's new tail its last K-1 inputs with the live tokens of the
+    window behind them. On a TPU, with a window of one token and the
+    channels in whole 128-lane tiles (the state kernels' predicate), each
+    live row's tail is read from its slot and written back there by one
+    kernel (:func:`_conv_step_pallas`) and a dead row names the trash slot;
+    everywhere else the rows are gathered, convolved and scattered
+    (:func:`_conv_step_xla`)."""
+    if raw.shape[1] == 1 and _kernel_shapes(raw):
+        y, pool = _conv_step_pallas(raw[:, 0], w, pool,
+                                    jnp.where(live[:, 0], slots, 0))
+        return y[:, None], pool
+    return _conv_step_xla(raw, w, pool, slots, live)

@@ -279,6 +279,194 @@ def test_a_dead_row_and_a_finished_row_move_no_state():
     np.testing.assert_allclose(y, want_y, atol=1e-4)
 
 
+# ------------------------------------- the convolution's tail in the pool
+RANKS = {"flat": lambda n, width, c: (n, width * c),       # Jamba's slots
+         "slot-major": lambda n, width, c: (n, width, c)}  # Kimi's
+
+
+def _tail_inputs(key, b, w, c, kk, n_slots, rank):
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (b, w, c)),
+            jax.random.normal(ks[1], (kk, c)),
+            jax.random.normal(ks[2], RANKS[rank](n_slots, kk - 1, c)))
+
+
+def _gather_conv_scatter(raw, w, pool, slots, live):
+    """The five ops ``decode_window_paged`` ran before the pool entry: the
+    oracle."""
+    width = w.shape[0] - 1
+    before = pool[slots].reshape(raw.shape[0], width, raw.shape[2])
+    seen = jnp.concatenate([before, raw], axis=1)
+    tail = kda.conv_tail(seen, width + jnp.sum(live, axis=1), width)
+    return (kda.causal_conv(raw, w, before),
+            pool.at[slots].set(tail.reshape((-1,) + pool.shape[1:])))
+
+
+@pytest.mark.parametrize("rows", [11, 16], ids=["11-rows", "16-rows"])
+@pytest.mark.parametrize("rank", sorted(RANKS))
+def test_the_tail_kernel_is_the_five_ops_on_the_named_slots(rank, rows):
+    """Live rows step their own slot in place, in a pool of either rank; a
+    dead row (slot 0) reads 0 and writes nothing; every slot no live row
+    names, the trash slot among them, is bit-identical."""
+    raw, w, pool = _tail_inputs(jax.random.PRNGKey(7), rows, 1, 256, 4, 20,
+                                rank)
+    slots = jnp.asarray([3, 0, 5, 7, 0, 1, 2, 9, 10, 11, 13, 4, 6, 8, 12,
+                         0][:rows])
+    y, got = kda._conv_step_pallas(raw[:, 0], w, pool, slots, interpret=True)
+    want_y, want = _gather_conv_scatter(raw, w, pool, slots,
+                                       (slots != 0)[:, None])
+    live = np.asarray(slots) != 0
+    np.testing.assert_allclose(y[live], want_y[live, 0], atol=1e-6)
+    named = np.asarray(slots)[live]
+    np.testing.assert_allclose(got[named], want[named], atol=1e-6)
+    assert float(jnp.abs(got[named] - pool[named]).max()) > 0.01
+    untouched = np.setdiff1d(np.arange(20), named)
+    np.testing.assert_array_equal(got[untouched], pool[untouched])
+    assert not np.asarray(y)[~live].any()
+
+
+def _on_the_chips_branch(monkeypatch):
+    """Steer the pool entry to the TPU's branch, the kernel itself through
+    the interpreter -> the slots each call was given."""
+    calls, kernel = [], kda._conv_step_pallas
+
+    def spy(raw, w, pool, slots):
+        calls.append(np.asarray(slots).tolist())
+        return kernel(raw, w, pool, slots, interpret=True)
+
+    monkeypatch.setattr(kda, "_conv_step_pallas", spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return calls
+
+
+@pytest.mark.parametrize("rank", sorted(RANKS))
+def test_a_dead_row_and_a_finished_row_move_no_tail(monkeypatch, rank):
+    """On the kernel's path and off it (a window of two): a token that is
+    not live leaves its stream's tail where it was, and the trash slot and
+    its neighbours' too."""
+    calls = _on_the_chips_branch(monkeypatch)
+    slots = jnp.asarray([2, 4, 0, 1])
+    for w, live in ((1, [[True], [False], [False], [True]]),
+                    (2, [[True, True], [True, False], [False, False],
+                         [False, False]])):
+        raw, wt, pool = _tail_inputs(jax.random.PRNGKey(w), 4, w, 128, 4, 6,
+                                     rank)
+        live = jnp.asarray(live)
+        y, got = kda.conv_step_paged(raw, wt, pool, slots, live)
+        want_y, want = _gather_conv_scatter(raw, wt, pool, slots, live)
+        rows = np.asarray(live[:, 0])
+        np.testing.assert_allclose(y[rows], want_y[rows], atol=1e-6)
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        still = [s for s, l in zip([2, 4, 0, 1], rows) if not l] + [3, 5]
+        np.testing.assert_array_equal(got[jnp.asarray(still)],
+                                      pool[jnp.asarray(still)])
+    assert calls == [[2, 0, 0, 1]]      # the window of two is the XLA form's
+    # after one live token of two, the tail is the old one moved up by one
+    t = lambda a: np.asarray(a[4]).reshape(3, 128)
+    np.testing.assert_array_equal(t(got)[:2], t(pool)[1:])
+    np.testing.assert_array_equal(t(got)[2], raw[1, 0])
+
+
+@pytest.mark.parametrize("rank", sorted(RANKS))
+def test_128_steps_from_a_prefills_tail_are_the_whole_convolution(rank):
+    """Rows of their own prompt lengths (one shorter than the tail): the
+    prefill's tail in the slots, then 128 kernel steps a token at a time,
+    against ``causal_conv`` over each row's whole sequence."""
+    lens, slots = np.asarray([9, 2, 30]), jnp.asarray([2, 0, 1])
+    x, w, pool = _tail_inputs(jax.random.PRNGKey(11), 3, 30 + 128, 128, 4,
+                              4, rank)
+    whole = kda.causal_conv(x, w)
+    tail = kda.conv_tail(x, jnp.asarray(lens), 3)
+    start = pool.at[slots].set(tail.reshape((-1,) + pool.shape[1:]))
+    step = jax.jit(lambda raw, p: kda._conv_step_pallas(raw, w, p, slots,
+                                                        interpret=True))
+    got = start
+    for i in range(128):
+        y, got = step(x[np.arange(3), lens + i], got)
+        for r in (0, 2):
+            np.testing.assert_allclose(y[r], whole[r, lens[r] + i],
+                                       atol=1e-5)
+        assert not np.asarray(y[1]).any()
+    np.testing.assert_array_equal(got[jnp.asarray([0, 3])],
+                                  start[jnp.asarray([0, 3])])
+    ends = kda.conv_tail(x, jnp.asarray(lens + 128), 3)
+    np.testing.assert_array_equal(
+        got[jnp.asarray([2, 1])].reshape(2, 3, 128), ends[jnp.asarray([0, 2])])
+
+
+def test_the_tail_entry_takes_the_kernel_where_the_chip_would(monkeypatch):
+    """One token a row and channels in whole lane tiles go to the kernel
+    with the dead rows on slot 0; a window of two, odd widths and every
+    other backend gather, convolve and scatter, bit for bit what
+    ``decode_window_paged`` did before."""
+    slots = jnp.asarray([5, 0, 2, 4], jnp.int32)
+    for w, c in ((1, 128), (2, 128), (1, 96)):      # a CPU: the XLA form
+        raw, wt, pool = _tail_inputs(jax.random.PRNGKey(c + w), 4, w, c, 4,
+                                     6, "flat")
+        live = jnp.asarray([[True] * w, [False] * w, [True] + [False] * (w - 1),
+                            [True] * w])
+        got = jax.jit(kda.conv_step_paged)(raw, wt, pool, slots, live)
+        want = jax.jit(_gather_conv_scatter)(raw, wt, pool, slots, live)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+    calls = _on_the_chips_branch(monkeypatch)
+    raw, wt, pool = _tail_inputs(jax.random.PRNGKey(2), 4, 1, 128, 4, 6,
+                                 "flat")
+    live = jnp.asarray([[True], [False], [False], [True]])
+    y, got = kda.conv_step_paged(raw, wt, pool, slots, live)
+    assert calls == [[5, 0, 0, 4]]
+    want_y, want = _gather_conv_scatter(raw, wt, pool, slots, live)
+    np.testing.assert_allclose(y[jnp.asarray([0, 3])],
+                               want_y[jnp.asarray([0, 3])], atol=1e-6)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    for w, c in ((1, 96), (2, 128)):    # stay off it, on a TPU too
+        raw, wt, pool = _tail_inputs(jax.random.PRNGKey(3), 4, w, c, 4, 6,
+                                     "flat")
+        kda.conv_step_paged(raw, wt, pool, slots, jnp.ones((4, w), bool))
+    assert len(calls) == 1
+
+
+def _kernels_a_step_takes(monkeypatch, blk, window, backend):
+    """The names of the in-place kernels one ``decode_window_paged`` of
+    ``blk`` reaches on ``backend``, each through the interpreter."""
+    taken = []
+
+    def through(module, name):
+        kernel = getattr(module, name)
+        monkeypatch.setattr(module, name, lambda *a: (
+            taken.append(name), kernel(*a, interpret=True))[1])
+
+    through(kda, "_conv_step_pallas")
+    through(kda, "_kda_decode_pallas")
+    through(ssm, "_selective_step_pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    params, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, window, blk.hidden_size))
+    at = jnp.asarray([[4], [0], [2]]) + jnp.arange(window)[None, :]
+    out, pool = blk.decode_window_paged(
+        params, x, blk.init_pool(5), jnp.asarray([1, 0, 3]), at, 8,
+        limits=jnp.asarray([9, -1, 9]))
+    assert bool(jnp.isfinite(out).all())
+    return sorted(taken)
+
+
+@pytest.mark.parametrize("case,hidden,window,backend,want", [
+    ("whole-tiles-one-token", 64, 1, "tpu",
+     ["_conv_step_pallas", "_selective_step_pallas"]),
+    ("a-window-of-two", 64, 2, "tpu", []),
+    ("96-channels", 48, 1, "tpu", []),
+    ("a-cpu", 64, 1, "cpu", [])])
+def test_the_state_kernel_and_conv_step_are_taken_under_one_predicate(
+        monkeypatch, case, hidden, window, backend, want):
+    """A state-space layer's decode step visits a live row's slot in both
+    pools or gathers and scatters both: the series that count the slots a
+    step visits (``ssm_decode_states_*``) cannot count one without the
+    other."""
+    blk = HybridDecoderBlock(hidden_size=hidden, mixer="mamba", d_state=8,
+                             dt_rank=8, expand=2, ffn_size=32)
+    assert _kernels_a_step_takes(monkeypatch, blk, window, backend) == want
+
+
 def test_a_reused_slot_starts_from_zeros_and_neighbours_keep_theirs(served):
     """A stream's answer does not depend on who held its slot before, on
     the rows beside it, or on a neighbour that ends early."""
@@ -302,12 +490,20 @@ def test_a_reused_slot_starts_from_zeros_and_neighbours_keep_theirs(served):
 
 def test_the_convolution_and_its_tail_exist_once():
     """Both state mixers read ops/kda.py's; ops/ssm.py copies neither."""
-    assert not hasattr(ssm, "causal_conv") and not hasattr(ssm, "conv_tail")
+    assert not any(hasattr(ssm, n) for n in ("causal_conv", "conv_tail",
+                                             "conv_step_paged"))
     import inspect
 
-    src = inspect.getsource(HybridDecoderBlock._ssm_inputs) \
-        + inspect.getsource(HybridDecoderBlock._ssm_prefill)
+    src = inspect.getsource(HybridDecoderBlock._ssm_prefill)
     assert "kda.causal_conv" in src and "kda.conv_tail" in src
+    # a mixer runs the convolution it is handed; the decode step hands both
+    # state mixers the one pool entry
+    for inputs in (HybridDecoderBlock._ssm_inputs,
+                   HybridDecoderBlock._kda_inputs):
+        assert "conv(raw, " in inspect.getsource(inputs).split('"""')[2]
+    step = inspect.getsource(HybridDecoderBlock.decode_window_paged)
+    assert step.count("kda.conv_step_paged(") == 1
+    assert 'pool["conv"][' not in step and 'pool["conv"].at[' not in step
     gen_src = inspect.getsource(inspect.getmodule(Generator))
     assert "ops import kda" not in gen_src and "ops.kda" not in gen_src
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 9, 6))

@@ -1013,6 +1013,56 @@ class TestMosaicCrossLowering:
         assert "tpu_custom_call" not in text
         assert "stablehlo.scatter" in text
 
+    @pytest.mark.parametrize("cell,rows,c,pool", [
+        ("jamba2-chat-open", 64, 5120, (65, 3 * 5120)),
+        ("kimiL-chat-open", 16, 12288, (17, 3, 12288))])
+    def test_conv_step_at_the_cells_shape(self, monkeypatch, cell, rows, c,
+                                          pool):
+        """One state layer's tail pool of each serving cell, as its
+        ``init_pool`` makes it: Jamba's flat (a tile of it holds 8 slots, so
+        the kernel walks groups of 8 and Pallas would refuse a one-row
+        block), Kimi's a slot a block. On a TPU the step is one Mosaic
+        kernel whose pool result aliases its pool operand and no scatter
+        is left beside it; the CPU program of the same call holds no
+        kernel."""
+        from jax import export
+
+        from deeplearning4j_tpu.ops import kda
+
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        avals = (f32(rows, 1, c), f32(4, c), f32(*pool),
+                 _aval(rows, dtype=jnp.int32),
+                 _aval(rows, 1, dtype=jnp.bool_))
+        text = _lower_for_tpu(kda.conv_step_paged, *avals)
+        assert text.count("tpu_custom_call") == 1
+        assert "output_operand_aliases" in text
+        assert "stablehlo.scatter" not in text
+        # the rows' inputs into slot order and y back: of a flat pool only
+        assert ('"stablehlo.gather"' in text) == (len(pool) == 2)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: kda.conv_step_paged(*a)  # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+
+    @pytest.mark.parametrize("backend,w,c", [
+        ("tpu", 1, 192), ("tpu", 2, 256), ("cpu", 1, 256)],
+        ids=["tpu-192-channels", "tpu-window-2", "cpu-256"])
+    def test_conv_step_elsewhere_is_the_xla_form(self, monkeypatch, backend,
+                                                 w, c):
+        """Channels that are not whole 128-lane tiles, a window of more than
+        one token, and any backend but the TPU gather, convolve and
+        scatter."""
+        from deeplearning4j_tpu.ops import kda
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        f32 = lambda *shape: _aval(*shape, dtype=jnp.float32)
+        text = _lower_for_tpu(
+            kda.conv_step_paged, f32(4, w, c), f32(4, c), f32(5, 3 * c),
+            _aval(4, dtype=jnp.int32), _aval(4, w, dtype=jnp.bool_))
+        assert "tpu_custom_call" not in text
+        assert "stablehlo.scatter" in text
+
     def test_resnet50_forward_reaches_no_kernel(self):
         """The flagship at its default conf on a TPU host: 53 convolutions,
         every one on the exact path — before PR 21, 51 of them routed to

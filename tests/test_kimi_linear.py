@@ -371,6 +371,154 @@ def test_the_pool_entry_takes_the_kernel_where_the_chip_would(monkeypatch):
     assert len(calls) == 1
 
 
+# ---------------------------------- the convolutions' tail in the pool
+def _tail_inputs(key, b, w, c, n_slots):
+    """Projections (B, W, C), the q | k | v convolutions' weights (4, C) and
+    a tail pool as a KDA block's ``init_pool`` makes it, (slots, 3, C)."""
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (b, w, c)),
+            jax.random.normal(ks[1], (4, c)),
+            jax.random.normal(ks[2], (n_slots, 3, c)))
+
+
+def _gather_conv_scatter(raw, w, pool, slots, live):
+    """The five ops ``decode_window_paged`` ran before the pool entry: the
+    oracle."""
+    before = pool[slots]
+    seen = jnp.concatenate([before, raw], axis=1)
+    tail = kda.conv_tail(seen, 3 + jnp.sum(live, axis=1), 3)
+    return kda.causal_conv(raw, w, before), pool.at[slots].set(tail)
+
+
+@pytest.mark.parametrize("rows", DECODE_ROWS)
+def test_the_tail_kernel_is_the_five_ops_on_the_named_slots(rows):
+    """The kernel under the interpreter on a KDA block's pool: y of the live
+    rows and their slots are gather, ``causal_conv``, ``conv_tail``,
+    scatter to 1e-6; every slot the step does not name, every slot a dead
+    row names and the trash slot are bit-identical; a dead row's y is 0."""
+    slots, live = DECODE_ROWS[rows]
+    raw, w, pool = _tail_inputs(jax.random.PRNGKey(len(slots)), len(slots),
+                                1, 3 * 128, 7)
+    slots, live = jnp.asarray(slots, jnp.int32), np.asarray(live)
+    y, new = kda._conv_step_pallas(raw[:, 0], w, pool,
+                                   jnp.where(live, slots, 0), interpret=True)
+    want_y, want = _gather_conv_scatter(raw, w, pool, slots,
+                                        jnp.asarray(live)[:, None])
+    named = np.asarray(slots)[live]
+    np.testing.assert_allclose(y[live], want_y[live, 0], atol=1e-6)
+    np.testing.assert_allclose(new[named], want[named], atol=1e-6)
+    np.testing.assert_array_equal(new[named, :2], pool[named, 1:])
+    assert not np.asarray(y[~live]).any()
+    rest = np.asarray([i for i in range(7) if i not in set(named.tolist())])
+    np.testing.assert_array_equal(new[rest], pool[rest])
+
+
+def test_128_steps_from_a_prefills_tail_are_the_whole_convolution():
+    """Rows of their own prompt lengths (one shorter than the tail): the
+    prefill's tail in the slots, then 128 kernel steps a token at a time,
+    against ``causal_conv`` over each row's whole sequence."""
+    lens, slots = np.asarray([9, 2, 30]), jnp.asarray([2, 0, 1])
+    x, w, pool = _tail_inputs(jax.random.PRNGKey(11), 3, 30 + 128, 128, 4)
+    whole = kda.causal_conv(x, w)
+    start = pool.at[slots].set(kda.conv_tail(x, jnp.asarray(lens), 3))
+    step = jax.jit(lambda raw, p: kda._conv_step_pallas(raw, w, p, slots,
+                                                        interpret=True))
+    got = start
+    for i in range(128):
+        y, got = step(x[np.arange(3), lens + i], got)
+        for r in (0, 2):
+            np.testing.assert_allclose(y[r], whole[r, lens[r] + i],
+                                       atol=1e-5)
+        assert not np.asarray(y[1]).any()
+    np.testing.assert_array_equal(got[jnp.asarray([0, 3])],
+                                  start[jnp.asarray([0, 3])])
+    ends = kda.conv_tail(x, jnp.asarray(lens + 128), 3)
+    np.testing.assert_array_equal(got[jnp.asarray([2, 1])],
+                                  ends[jnp.asarray([0, 2])])
+
+
+@pytest.mark.parametrize("case,w,c", [("96-channels", 1, 96),
+                                      ("a-window-of-two", 2, 384),
+                                      ("one-token-on-a-cpu", 1, 384)])
+def test_the_tail_entry_off_the_kernels_path_is_the_five_ops(case, w, c):
+    """Odd widths, a window of two tokens, and any shape off the TPU take
+    the XLA form, bit for bit what ``decode_window_paged`` did before; a
+    finished row's tail and the slots nobody names stay as they were."""
+    raw, wt, pool = _tail_inputs(jax.random.PRNGKey(w), 4, w, c, 6)
+    slots = jnp.asarray([5, 0, 2, 4], jnp.int32)
+    live = jnp.asarray([[True] * w, [False] * w, [False] * w,
+                        [True] + [False] * (w - 1)])
+    got = jax.jit(kda.conv_step_paged)(raw, wt, pool, slots, live)
+    want = jax.jit(_gather_conv_scatter)(raw, wt, pool, slots, live)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(got[1][jnp.asarray([1, 2, 3])],
+                                  pool[jnp.asarray([1, 2, 3])])
+
+
+def test_the_tail_entry_takes_the_kernel_where_the_chip_would(monkeypatch):
+    """Steered to the TPU's branch (the kernel itself through the
+    interpreter): one token a row at whole lane tiles goes to the kernel
+    with the dead rows on slot 0, and the result is the XLA form's."""
+    calls = []
+    kernel = kda._conv_step_pallas
+
+    def spy(raw, w, pool, slots):
+        calls.append(np.asarray(slots).tolist())
+        return kernel(raw, w, pool, slots, interpret=True)
+
+    monkeypatch.setattr(kda, "_conv_step_pallas", spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    raw, wt, pool = _tail_inputs(jax.random.PRNGKey(2), 4, 1, 384, 6)
+    slots = jnp.asarray([5, 0, 2, 4], jnp.int32)
+    live = jnp.asarray([[True], [False], [False], [True]])
+    y, new = kda.conv_step_paged(raw, wt, pool, slots, live)
+    assert calls == [[5, 0, 0, 4]]
+    want_y, want = _gather_conv_scatter(raw, wt, pool, slots, live)
+    keep = np.asarray(live[:, 0])
+    np.testing.assert_allclose(y[keep], want_y[keep], atol=1e-6)
+    np.testing.assert_allclose(new, want, atol=1e-6)
+    np.testing.assert_array_equal(new[jnp.asarray([0, 1, 2, 3])],
+                                  pool[jnp.asarray([0, 1, 2, 3])])
+    # odd widths and a wider window stay off it, on a TPU too
+    for w, c in ((1, 96), (2, 384)):
+        a = _tail_inputs(jax.random.PRNGKey(3), 4, w, c, 6)
+        kda.conv_step_paged(*a, slots, jnp.ones((4, w), bool))
+    assert len(calls) == 1
+
+
+@pytest.mark.parametrize("case,head_dim,window,backend,want", [
+    ("whole-tiles-one-token", 128, 1, "tpu",
+     ["_conv_step_pallas", "_kda_decode_pallas"]),
+    ("a-window-of-two", 128, 2, "tpu", []),
+    ("heads-of-16", 16, 1, "tpu", []),
+    ("a-cpu", 128, 1, "cpu", [])])
+def test_the_state_kernel_and_conv_step_are_taken_under_one_predicate(
+        monkeypatch, case, head_dim, window, backend, want):
+    """A KDA layer's decode step visits a live row's slot in both pools or
+    gathers and scatters both: the series that count the slots a step
+    visits (``kda_decode_states_*``) cannot count one without the other."""
+    taken = []
+
+    def through(name):
+        kernel = getattr(kda, name)
+        monkeypatch.setattr(kda, name, lambda *a: (
+            taken.append(name), kernel(*a, interpret=True))[1])
+
+    through("_conv_step_pallas")
+    through("_kda_decode_pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    blk = HybridDecoderBlock(hidden_size=32, mixer="kda", n_heads=1,
+                             head_dim=head_dim, gate_rank=8, ffn_size=32)
+    params, _ = blk.initialize(jax.random.PRNGKey(0), None)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, window, 32))
+    at = jnp.asarray([[4], [0], [2]]) + jnp.arange(window)[None, :]
+    out, pool = blk.decode_window_paged(
+        params, x, blk.init_pool(5), jnp.asarray([1, 0, 3]), at, 8,
+        limits=jnp.asarray([9, -1, 9]))
+    assert bool(jnp.isfinite(out).all()) and sorted(taken) == want
+
+
 def test_kda_decode_counters_follow_the_live_rows(served):
     """Host arithmetic, no device fetch: a batch of three streams in a
     bucket of four moves 3 of 4 declared states a KDA layer a decode step;
