@@ -812,8 +812,11 @@ class Generator:
         greedy (deterministic); otherwise categorical sampling from
         ``key`` (default PRNGKey(0)) through the plain per-token loop.
         ``trace=True`` (a head-sampled serving batch) emits prefill /
-        ``decode_token`` / ``verify`` spans — the per-token ruler of
-        docs/OBSERVABILITY.md#request-tracing--slos. ``stats`` (a dict,
+        ``verify`` spans (docs/OBSERVABILITY.md#request-tracing--slos).
+        On a serving worker it also marks the worker's phases ``launch``
+        (the prefill is out), ``wait`` (the last decode launch returned)
+        and ``drain`` (the first fetch of the last step's output returned:
+        ``t_done``), without moving a fetch. ``stats`` (a dict,
         filled in place) receives ``draft_accept_rate`` per row and the
         batch ``spec_accept_rate`` when speculating, plus
         ``prefix_hit_rate`` / ``resumed_positions`` / ``prefill_chunks``
@@ -895,6 +898,7 @@ class Generator:
         for a self-draft, else None)."""
         out = self._prefill_paged_jit(raw, self.pool.pools, tokens, lengths,
                                       tables)
+        tm.phase("serving.generate.launch")
         self.pool.pools = out[-1]
         tm.counter("serving.prefill_positions_total",
                    int(tokens.shape[0]) * int(tokens.shape[1]),
@@ -986,6 +990,7 @@ class Generator:
                 raw, self.pool.pools, jnp.asarray(window),
                 jnp.asarray(cols), tables, limits,
                 jnp.asarray(last_idx))
+            tm.phase("serving.generate.launch")
             self.pool.pools = pools
             if in_chunk.any():
                 # keep the device rows; host-gather only at the end
@@ -1064,13 +1069,15 @@ class Generator:
         """One batch's router counts onto ``serving.moe_*_total``: the
         device accumulators wrap (int32), so the host adds differences
         modulo 2**32. One small program and one fetch a batch, launched
-        behind the last decode step."""
+        behind the last decode step: that fetch is the batch's first of an
+        output of its last step, so its return starts ``drain``."""
         if not self._moe_layers:
             return
         from deeplearning4j_tpu.nn.moe import MOE_STATS
 
         now = np.asarray(self._moe_totals_jit(self.pool.pools)).astype(
             np.uint32)
+        tm.phase("serving.generate.drain")  # t_done on an expert net
         seen = self._moe_seen if self._moe_seen is not None \
             else np.zeros_like(now)
         self._moe_seen = now
@@ -1121,21 +1128,20 @@ class Generator:
                     break  # every live stream finished: free blocks early
             if i == max_new - 1:
                 break
-            t_dt = time.time_ns() if tele else 0
             logits, pools = self._decode_paged_jit(
                 raw, self.pool.pools, tables, cur, positions, limits)
             self.pool.pools = pools
             kv_read += self._kv_positions_read(batch, longest + i)
-            if tele:
-                tele.event_deferred("serving.generate.decode_token", t_dt,
-                                    time.time_ns(), step=i + 1, batch=batch)
             positions = positions + 1
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
+        tm.phase("serving.generate.wait")
         self._count_kv_read(batch, kv_read, len(steps) - 1)
         self._count_state_decode(batch, b_real, len(steps) - 1)
         self._count_moe()
-        stacked = np.stack([np.asarray(s) for s in steps], axis=1)
+        fetched = [np.asarray(s) for s in steps]
+        tm.phase("serving.generate.drain")  # t_done on a dense net
+        stacked = np.stack(fetched, axis=1)
         return self._trim(stacked, b_real, lens, max_new, eos_id)
 
     def _generate_speculative(self, tokens, lengths, tables, b_real, lens,
@@ -1267,8 +1273,11 @@ class Generator:
                         "serving.generate.mtp_draft", t_md, time.time_ns(),
                         batch=batch, window=w, round=rounds)
             pos_np = pos_np + m
+        # every round fetched its verify above: launch holds those waits
+        tm.phase("serving.generate.wait")
         self._count_kv_read(batch, kv_read, rounds)
         self._count_moe()
+        tm.phase("serving.generate.drain")
         if mtp is not None:
             tm.counter("serving.mtp_draft_proposed_total",
                        int(accept_den[:b_real].sum()), model=self.model_id)
@@ -1303,6 +1312,7 @@ class Generator:
 
         t_pf = time.time_ns() if tele else 0
         logits, caches = self._prefill_jit(raw, tokens, lengths)
+        tm.phase("serving.generate.launch")
         if tele:
             tele.event_deferred("serving.generate.prefill", t_pf,
                                 time.time_ns(), batch=batch,
@@ -1315,16 +1325,15 @@ class Generator:
             steps.append(cur)
             if i == max_new_tokens - 1:
                 break
-            t_dt = time.time_ns() if tele else 0
             logits, caches = self._decode_jit(raw, caches, cur,
                                               positions)
-            if tele:
-                tele.event_deferred("serving.generate.decode_token", t_dt,
-                                    time.time_ns(), step=i + 1, batch=batch)
             positions = positions + 1
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
-        stacked = np.stack([np.asarray(s) for s in steps], axis=1)
+        tm.phase("serving.generate.wait")
+        fetched = [np.asarray(s) for s in steps]
+        tm.phase("serving.generate.drain")
+        stacked = np.stack(fetched, axis=1)
         return self._trim(stacked, b_real, lens, max_new_tokens, eos_id)
 
     def generate_full_recompute(self, prompts: Sequence[Sequence[int]],

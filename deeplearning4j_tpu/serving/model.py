@@ -272,6 +272,9 @@ class ServingModel:
                                           model=self.model_id, **args)
 
     def _execute_classify(self, payloads, _trace=False, **opts):
+        # on a serving worker: launch from the first forward, drain once
+        # every output is on the host (each chunk is fetched as it is run,
+        # so launch holds the device's time and wait is empty)
         if opts:
             raise ValueError(f"classify takes no options, got {opts}")
         n = sum(int(np.shape(p)[0]) for p in payloads)
@@ -289,7 +292,9 @@ class ServingModel:
             if _trace:
                 self._emit("serving.exec.pad", t0, rows=n)
             t1 = time.time_ns() if _trace else 0
+            tm.phase("serving.generate.launch")
             out = self.inference.output(xs)  # plans the chunks inside
+            tm.phase("serving.generate.drain")
             if _trace:
                 self._emit("serving.exec.device", t1, rows=n, padded=padded)
         else:
@@ -309,6 +314,7 @@ class ServingModel:
             if _trace:
                 self._emit("serving.exec.pad", t0, rows=n, padded=padded)
             t1 = time.time_ns() if _trace else 0
+            tm.phase("serving.generate.launch")
             if self._qforward is not None:
                 raw = self._qp.args()
                 chunks = [np.asarray(self._qforward(
@@ -318,6 +324,7 @@ class ServingModel:
                 chunks = [np.asarray(self.net.output(chunk))[:take]
                           for chunk, take in padded_chunks]
             out = np.concatenate(chunks, axis=0)
+            tm.phase("serving.generate.drain")
             if _trace:
                 self._emit("serving.exec.device", t1, rows=n,
                            padded=padded, chunks=len(plan))

@@ -29,7 +29,10 @@ counters, queue-depth gauge, batch-occupancy and latency histograms,
 p50/p99 latency gauges (combined AND split by ``lane``), and
 ``serving.recompiles_total`` — the count of XLA traces serving has caused
 since warmup, asserted 0 in steady state (tests/test_serving.py,
-tests/test_paged_decode.py).
+tests/test_paged_decode.py). The worker thread's time is cut into seven
+phases, every batch (``WAIT_WORK`` .. ``RESPOND``: a live span and a
+seconds counter each), and the host's turnaround between two batches is
+counted (docs/OBSERVABILITY.md#worker-phases).
 
 Request-scope observability (docs/OBSERVABILITY.md#request-tracing--slos):
 every request carries a ``request_id`` (the HTTP layer honors/echoes
@@ -49,6 +52,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import itertools
 import dataclasses
 import os
@@ -88,6 +92,17 @@ DEFAULT_TRACE_SAMPLE = 0.02
 
 #: a completed request slower than this is "slow" and always traced
 SLOW_REQUEST_MS = 100.0
+
+#: the worker thread's phases, in the order a batch passes them; together
+#: they tile the thread's time (docs/OBSERVABILITY.md#worker-phases). Each
+#: is a live span and a ``<group>_<leaf>_seconds_total`` counter
+#: (util/telemetry.py PhaseTrack); the middle four are marked by the model
+#: (serving/generate.py, serving/model.py)
+WAIT_WORK, FILL, PREP, LAUNCH, WAIT, DRAIN, RESPOND = (
+    "serving.worker.wait_work", "serving.worker.fill",
+    "serving.generate.prep", "serving.generate.launch",
+    "serving.generate.wait", "serving.generate.drain",
+    "serving.batch.respond")
 
 _sample_cache: Tuple[Optional[str], float] = ("\x00unset", DEFAULT_TRACE_SAMPLE)
 
@@ -295,6 +310,7 @@ class BatchScheduler:
         self.flight = FlightRecorder(capacity=flight_capacity)
         self._traced: list = []  # staged sampled requests (flat tuples)
         self._trace_dropped = 0
+        self._t_done_ns: Optional[int] = None  # the last batch's t_done
         _SCHEDULERS.add(self)
 
     # ------------------------------------------------------ request tracing
@@ -659,8 +675,21 @@ class BatchScheduler:
         return rows
 
     def _loop(self):
+        # the worker's time as seven phases (WAIT_WORK .. RESPOND): the
+        # loop marks the first two, _run_batch prep and respond, the model
+        # the three between
+        phases = tm.start_phases(model=self.model_id)
+        self._t_done_ns = None
+        try:
+            self._serve()
+        finally:
+            phases.stop()
+
+    def _serve(self):
         while True:
             with self._cv:
+                if not any(self._queues[l] for l in self.lanes):
+                    tm.phase(WAIT_WORK)
                 while not self._stop \
                         and not any(self._queues[l] for l in self.lanes):
                     self._cv.wait(timeout=0.1)
@@ -675,31 +704,24 @@ class BatchScheduler:
                 self._current_batch = batch  # the watchdog fails these
                                              # loudly if the loop dies
             # max-wait window: keep admitting until the batch is full or
-            # max_wait_ms has passed since it opened (continuous batching).
-            # The whole cycle (fill wait + execute) is one worker-thread
-            # span, so the trace's serving-<model> row shows where the
-            # worker's time goes between batches.
+            # max_wait_ms has passed since it opened (continuous batching)
+            tm.phase(FILL)
             t_open = time.monotonic()
             deadline = t_open + self.max_wait_ms / 1e3
             try:
-                with tm.span("serving.worker.batch_cycle",
-                             model=self.model_id) as cycle:
-                    while True:
-                        with self._cv:
-                            rows = self._fill_batch_locked(batch)
-                            if rows >= self.max_batch:
-                                break
-                            remaining = deadline - time.monotonic()
-                            if remaining <= 0:
-                                break
-                            self._cv.wait(timeout=remaining)
-                    if hasattr(cycle, "args"):  # not the disabled no-op
-                        cycle.args["requests"] = len(batch)
-                        cycle.args["rows"] = rows
-                    self._run_batch(batch)
-                    # every future resolved (result or handled error): the
-                    # watchdog must not re-fail them if the loop dies later
-                    self._current_batch = None
+                while True:
+                    with self._cv:
+                        rows = self._fill_batch_locked(batch)
+                        if rows >= self.max_batch:
+                            break
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            break
+                        self._cv.wait(timeout=remaining)
+                self._run_batch(batch)
+                # every future resolved (result or handled error): the
+                # watchdog must not re-fail them if the loop dies later
+                self._current_batch = None
             finally:
                 with self._cv:
                     self._inflight = 0
@@ -735,8 +757,12 @@ class BatchScheduler:
                 self._fill_batch_locked(inner)
                 outer = self._current_batch
                 self._current_batch = inner
+            # the inner batch's time counts to the outer's phase in
+            # progress (launch: its first prefill window is out)
+            track = tm.current_phases()
             try:
-                self._run_batch(inner)
+                with track.suspended() if track else contextlib.nullcontext():
+                    self._run_batch(inner)
             finally:
                 with self._cv:
                     self._current_batch = outer
@@ -744,6 +770,67 @@ class BatchScheduler:
         if ran:
             tm.counter("serving.prefill_yield_preemptions_total", ran,
                        model=self.model_id)
+
+    def _count_turnaround(self, head: _Request):
+        """The time the device waited on the host between the last batch
+        and this one: this batch's prefill launch less the later of the
+        last batch's ``t_done`` (the end of its ``wait`` phase) and this
+        head's submit, so that a wait for an arrival counts under
+        ``wait_work`` instead. A batch that ran no decode to its end
+        breaks the pair; a nested (yield-hook) batch has no track."""
+        track = tm.current_phases()
+        if track is None:
+            return
+        launch = track.at.pop(LAUNCH, None)
+        done, self._t_done_ns = self._t_done_ns, track.at.pop(DRAIN, None)
+        if launch is None or done is None:
+            return
+        tm.counter("serving.batch_turnaround_seconds_total",
+                   (launch - max(done, head.t_submit_ns)) / 1e9,
+                   model=self.model_id)
+        tm.counter("serving.batch_turnarounds_total", model=self.model_id)
+
+    def _fail_batch(self, batch: List[_Request], e: Exception,
+                    tracing: bool):
+        if isinstance(e, ShedError):
+            # an EXECUTE-time shed (paged-pool exhaustion): a first-class
+            # 429 with its own cause, NOT a server error — the riders'
+            # futures carry the ShedError (the HTTP layer answers 429 +
+            # Retry-After), the per-lane shed counters and flight-recorder
+            # cause record it, and the breaker never hears about it (the
+            # model is healthy; the pool is full — r13 shed contract)
+            err_ns = time.time_ns()
+            reason = getattr(e, "shed_reason", "shed")
+            for req in batch:
+                req.t_exec1_ns = err_ns
+                self._shed(req, e, reason)
+            return
+        # a bad request fails its batch, never the worker
+        # (ParallelInference contract)
+        err_ns = time.time_ns()
+        for req in batch:
+            req.t_exec1_ns = err_ns
+            if req.future.set_running_or_notify_cancel():
+                req.future.set_exception(e)
+            self.counts["errors"] += 1
+            self.lane_counts[req.lane]["errors"] += 1
+            tm.counter("serving.request_errors_total",
+                       model=self.model_id, lane=req.lane)
+            # errors are always kept (tracing permitting)
+            self._flight_record(req, "error", cause=repr(e)[:200],
+                                end_ns=err_ns, traced=tracing)
+            if tracing:
+                self._stage_spans(req, "error", end_ns=err_ns)
+        tm.counter("serving.batch_errors_total", model=self.model_id)
+        if self.breaker is not None and not isinstance(
+                e, (KeyError, TypeError, ValueError)):
+            # one failed batch = one breaker outcome: enough of these in a
+            # row fast-fails instead of queueing more doomed work
+            # (resilience.CircuitBreaker). The client-shaped family (the
+            # server's HTTP 400 mapping) is excluded — a buggy client's
+            # malformed payloads must not open the breaker and 503 a
+            # healthy model for everyone else
+            self.breaker.record_error()
 
     def _run_batch(self, batch: List[_Request]):
         t0 = time.monotonic()
@@ -772,116 +859,88 @@ class BatchScheduler:
         if (batch[0].lane != self.lanes[0]
                 and getattr(self.model, "supports_chunked_prefill", False)):
             extra["_yield"] = self._drain_priority_once
+        # the fill ends before serving.batch opens, so that the span nests
+        # the execute phases and closes before respond starts
+        tm.phase(None)
+        failure = None
         with tm.span("serving.batch", model=self.model_id,
                      requests=len(batch), lane=batch[0].lane):
+            tm.phase(PREP)
             try:
                 results, stats = self.model.execute(
                     [r.payload for r in batch], _trace=trace_batch,
                     _step=seq, **extra, **batch[0].opts)
-            except ShedError as e:
-                # an EXECUTE-time shed (paged-pool exhaustion): a
-                # first-class 429 with its own cause, NOT a server error —
-                # the riders' futures carry the ShedError (the HTTP layer
-                # answers 429 + Retry-After), the per-lane shed counters
-                # and flight-recorder cause record it, and the breaker
-                # never hears about it (the model is healthy; the pool is
-                # full — r13 shed contract, new cause)
-                err_ns = time.time_ns()
-                reason = getattr(e, "shed_reason", "shed")
-                for req in batch:
-                    req.t_exec1_ns = err_ns
-                    self._shed(req, e, reason)
-                return
-            except Exception as e:  # a bad request fails its batch, never
-                err_ns = time.time_ns()  # the worker (ParallelInference
-                for req in batch:        # contract)
-                    req.t_exec1_ns = err_ns
-                    if req.future.set_running_or_notify_cancel():
-                        req.future.set_exception(e)
-                    self.counts["errors"] += 1
-                    self.lane_counts[req.lane]["errors"] += 1
-                    tm.counter("serving.request_errors_total",
+            except Exception as e:  # noqa: BLE001 — answered below
+                failure = e
+            tm.phase(None)
+        tm.phase(RESPOND)
+        self._count_turnaround(batch[0])
+        if failure is not None:
+            self._fail_batch(batch, failure, tracing)
+            return
+        if self.breaker is not None:
+            self.breaker.record_success()
+        self._batches_since_crash += 1
+        if self._restarts and \
+                self._batches_since_crash >= self.restart_reset_batches:
+            # a sustained healthy run pays the crash budget back: the
+            # watchdog bounds crash LOOPS, not lifetime crashes
+            self._restarts = 0
+        exec1_ns = time.time_ns()
+        now = time.monotonic()
+        padded = stats.get("padded_rows")
+        decode_s = stats.get("decode_seconds")
+        decode_toks = stats.get("decode_tokens")
+        accept_rates = stats.get("draft_accept_rate")  # per rider, or None
+        hit_rate = stats.get("prefix_hit_rate")        # batch-level
+        resumed = stats.get("resumed_positions")       # per rider
+        chunks = stats.get("prefill_chunks")
+        lane_done: collections.Counter = collections.Counter()
+        for ridx, (req, res) in enumerate(zip(batch, results)):
+            req.t_exec1_ns = exec1_ns
+            if req.future.set_running_or_notify_cancel():
+                req.future.set_result(res)
+            lat = now - req.t_enqueue
+            self.latencies.add(lat)
+            self.lane_latencies[req.lane].add(lat)
+            with self._ts_lock:
+                self._completed_ts.append(now)
+            self.counts["completed"] += 1
+            self.lane_counts[req.lane]["completed"] += 1
+            lane_done[req.lane] += 1
+            tm.observe("serving.request_latency_seconds", lat,
+                       model=self.model_id, lane=req.lane)
+            tps = None
+            if decode_s and decode_toks:
+                # per-request decode throughput: this request's tokens
+                # over the batch's decode wall (incl. prefill)
+                try:
+                    tps = len(res) / decode_s
+                except TypeError:
+                    tps = None
+                if tps is not None:
+                    tm.observe("serving.decode_tokens_per_sec", tps,
                                model=self.model_id, lane=req.lane)
-                    # errors are always kept (tracing permitting)
-                    self._flight_record(req, "error", cause=repr(e)[:200],
-                                        end_ns=err_ns, traced=tracing)
-                    if tracing:
-                        self._stage_spans(req, "error", end_ns=err_ns)
-                tm.counter("serving.batch_errors_total", model=self.model_id)
-                if self.breaker is not None and not isinstance(
-                        e, (KeyError, TypeError, ValueError)):
-                    # one failed batch = one breaker outcome: enough of
-                    # these in a row fast-fails instead of queueing more
-                    # doomed work (resilience.CircuitBreaker). The
-                    # client-shaped family (the server's HTTP 400 mapping)
-                    # is excluded — a buggy client's malformed payloads
-                    # must not open the breaker and 503 a healthy model
-                    # for everyone else
-                    self.breaker.record_error()
-                return
-            if self.breaker is not None:
-                self.breaker.record_success()
-            self._batches_since_crash += 1
-            if self._restarts and \
-                    self._batches_since_crash >= self.restart_reset_batches:
-                # a sustained healthy run pays the crash budget back: the
-                # watchdog bounds crash LOOPS, not lifetime crashes
-                self._restarts = 0
-            exec1_ns = time.time_ns()
-            now = time.monotonic()
-            padded = stats.get("padded_rows")
-            decode_s = stats.get("decode_seconds")
-            decode_toks = stats.get("decode_tokens")
-            accept_rates = stats.get("draft_accept_rate")  # per rider, or None
-            hit_rate = stats.get("prefix_hit_rate")        # batch-level
-            resumed = stats.get("resumed_positions")       # per rider
-            chunks = stats.get("prefill_chunks")
-            lane_done: collections.Counter = collections.Counter()
-            for ridx, (req, res) in enumerate(zip(batch, results)):
-                req.t_exec1_ns = exec1_ns
-                if req.future.set_running_or_notify_cancel():
-                    req.future.set_result(res)
-                lat = now - req.t_enqueue
-                self.latencies.add(lat)
-                self.lane_latencies[req.lane].add(lat)
-                with self._ts_lock:
-                    self._completed_ts.append(now)
-                self.counts["completed"] += 1
-                self.lane_counts[req.lane]["completed"] += 1
-                lane_done[req.lane] += 1
-                tm.observe("serving.request_latency_seconds", lat,
-                           model=self.model_id, lane=req.lane)
-                tps = None
-                if decode_s and decode_toks:
-                    # per-request decode throughput: this request's tokens
-                    # over the batch's decode wall (incl. prefill)
-                    try:
-                        tps = len(res) / decode_s
-                    except TypeError:
-                        tps = None
-                    if tps is not None:
-                        tm.observe("serving.decode_tokens_per_sec", tps,
-                                   model=self.model_id, lane=req.lane)
-                rate = (accept_rates[ridx]
-                        if accept_rates and ridx < len(accept_rates)
-                        else None)
-                rpos = (resumed[ridx]
-                        if resumed and ridx < len(resumed) else None)
-                keep = tracing and (req.sampled
-                                    or lat * 1e3 > SLOW_REQUEST_MS)
-                self._flight_record(req, "ok", end_ns=exec1_ns,
-                                    bucket=padded, traced=keep,
-                                    tokens_per_sec=tps,
-                                    draft_accept_rate=rate,
-                                    prefix_hit_rate=hit_rate,
-                                    resumed_position=rpos,
-                                    prefill_chunks=chunks)
-                if keep:
-                    self._stage_spans(
-                        req, "ok" if req.sampled else "slow",
-                        bucket=padded, tokens_per_sec=tps, end_ns=exec1_ns,
-                        draft_accept_rate=rate, prefix_hit_rate=hit_rate,
-                        resumed_position=rpos, prefill_chunks=chunks)
+            rate = (accept_rates[ridx]
+                    if accept_rates and ridx < len(accept_rates)
+                    else None)
+            rpos = (resumed[ridx]
+                    if resumed and ridx < len(resumed) else None)
+            keep = tracing and (req.sampled
+                                or lat * 1e3 > SLOW_REQUEST_MS)
+            self._flight_record(req, "ok", end_ns=exec1_ns,
+                                bucket=padded, traced=keep,
+                                tokens_per_sec=tps,
+                                draft_accept_rate=rate,
+                                prefix_hit_rate=hit_rate,
+                                resumed_position=rpos,
+                                prefill_chunks=chunks)
+            if keep:
+                self._stage_spans(
+                    req, "ok" if req.sampled else "slow",
+                    bucket=padded, tokens_per_sec=tps, end_ns=exec1_ns,
+                    draft_accept_rate=rate, prefix_hit_rate=hit_rate,
+                    resumed_position=rpos, prefill_chunks=chunks)
         # one counter bump per lane per batch, not per request — registry
         # lock acquisitions on the worker are GIL time stolen from other
         # models' workers (the mixed-bench finding; see _LatencyWindow.add)

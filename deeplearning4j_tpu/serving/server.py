@@ -296,9 +296,17 @@ def _make_handler(server: ModelServer):
         def do_POST(self):
             # read the body FIRST, on every path — an unread body would
             # desynchronize the persistent (HTTP/1.1) connection and the
-            # next request on the socket would parse garbage
-            n = int(self.headers.get("Content-Length", 0))
-            raw = self.rfile.read(n) if n else b""
+            # next request on the socket would parse garbage. A live span
+            # (and, under a profile, a TraceAnnotation beside the worker's
+            # phases: a phase stretched by a handler holding the GIL shows
+            # it); a body that is no JSON is answered 400 below
+            with tm.span("serving.http.parse"):
+                n = int(self.headers.get("Content-Length", 0))
+                raw = self.rfile.read(n) if n else b""
+                try:
+                    body = json.loads(raw or b"{}")
+                except ValueError as e:
+                    body = e
             parts = self.path.strip("/").split("/")
             if parts == ["admin", "drain"]:
                 # admin verb for a front tier / orchestrator that cannot
@@ -326,7 +334,8 @@ def _make_handler(server: ModelServer):
                     headers=[("Retry-After", "10")] + rid_hdr)
                 return
             try:
-                body = json.loads(raw or b"{}")
+                if isinstance(body, ValueError):
+                    raise body
                 if verb == "infer":
                     resp = server._handle_infer(model_id, body,
                                                 request_id=rid)
@@ -338,8 +347,9 @@ def _make_handler(server: ModelServer):
                 else:
                     resp = server._handle_generate(model_id, body,
                                                    request_id=rid)
-                resp["request_id"] = rid
-                self._send_json(200, resp, headers=rid_hdr)
+                with tm.span("serving.http.write"):
+                    resp["request_id"] = rid
+                    self._send_json(200, resp, headers=rid_hdr)
             except UnknownModelError as e:
                 self._send_json(404, {"error": f"unknown model {e}"},
                                 headers=rid_hdr)

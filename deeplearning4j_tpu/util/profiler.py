@@ -196,147 +196,51 @@ def device_trace(logdir: str):
 
 
 # ---------------------------------------------------------------------------
-# XPlane parsing (no tensorboard_plugin_profile / TF xplane_pb2 dependency)
+# XPlane readers, on JAX's own (``jax.profiler.ProfileData``): the reader
+# chipbench/trace.py uses, so the program and the benchmark read a trace
+# one way
 # ---------------------------------------------------------------------------
-# The .xplane.pb files jax.profiler writes follow tsl/profiler/protobuf/
-# xplane.proto. Only the containment chain XSpace.planes(1) -> XPlane
-# {name=2, lines=3, event_metadata=4} -> XLine {name=2, events=4} -> XEvent
-# {metadata_id=1, duration_ps=3} is needed for device-time totals, so a
-# minimal protobuf wire-format reader keeps the roof-proof recipe
-# self-contained (the TF builds in this image ship no xplane_pb2).
+def _xplane_lines(logdir: str):
+    """Yield (plane name, line name, [(name, start_ns, dur_ns)]) over every
+    *.xplane.pb under ``logdir``."""
+    import glob as _glob
 
+    from jax.profiler import ProfileData
 
-def _wire_iter(buf: bytes):
-    """Yield (field_number, wire_type, value) over one protobuf message.
-    value: int for varint(0)/fixed(1,5), bytes for length-delimited(2)."""
-    i, n = 0, len(buf)
-    while i < n:
-        key = 0
-        shift = 0
-        while True:
-            b = buf[i]
-            i += 1
-            key |= (b & 0x7F) << shift
-            if not b & 0x80:
-                break
-            shift += 7
-        field, wt = key >> 3, key & 7
-        if wt == 0:  # varint
-            v = 0
-            shift = 0
-            while True:
-                b = buf[i]
-                i += 1
-                v |= (b & 0x7F) << shift
-                if not b & 0x80:
-                    break
-                shift += 7
-            yield field, wt, v
-        elif wt == 1:  # 64-bit
-            yield field, wt, int.from_bytes(buf[i:i + 8], "little")
-            i += 8
-        elif wt == 2:  # length-delimited
-            ln = 0
-            shift = 0
-            while True:
-                b = buf[i]
-                i += 1
-                ln |= (b & 0x7F) << shift
-                if not b & 0x80:
-                    break
-                shift += 7
-            yield field, wt, buf[i:i + ln]
-            i += ln
-        elif wt == 5:  # 32-bit
-            yield field, wt, int.from_bytes(buf[i:i + 4], "little")
-            i += 4
-        else:
-            raise ValueError(f"unsupported protobuf wire type {wt}")
-
-
-def parse_xplane(path: str) -> List[dict]:
-    """Parse one .xplane.pb into ``[{'name': plane, 'lines': [{'name': line,
-    'events': [(name, dur_ps, offset_ps)]}]}]``. Event names resolve through
-    the plane's event_metadata table; the offset (XEvent.offset_ps, line-
-    relative) lets consumers dedupe NESTED events on one thread line —
-    the cost-attribution grouper only counts outermost matches."""
-    with open(path, "rb") as f:
-        space = f.read()
-    planes = []
-    for field, wt, val in _wire_iter(space):
-        if field != 1 or wt != 2:
-            continue
-        name, lines, meta = "", [], {}
-        for pf, pwt, pv in _wire_iter(val):
-            if pf == 2 and pwt == 2:
-                name = pv.decode("utf-8", "replace")
-            elif pf == 3 and pwt == 2:
-                lines.append(pv)
-            elif pf == 4 and pwt == 2:  # map entry: key=1, value=2(XEventMetadata)
-                k, mname = None, ""
-                for mf, mwt, mv in _wire_iter(pv):
-                    if mf == 1 and mwt == 0:
-                        k = mv
-                    elif mf == 2 and mwt == 2:
-                        for ef, ewt, ev in _wire_iter(mv):
-                            if ef == 1 and ewt == 0 and k is None:
-                                k = ev
-                            elif ef == 2 and ewt == 2:
-                                mname = ev.decode("utf-8", "replace")
-                if k is not None:
-                    meta[k] = mname
-        parsed_lines = []
-        for lbuf in lines:
-            lname, events = "", []
-            for lf, lwt, lv in _wire_iter(lbuf):
-                if lf == 2 and lwt == 2:
-                    lname = lv.decode("utf-8", "replace")
-                elif lf == 4 and lwt == 2:
-                    mid, dur, off = 0, 0, 0
-                    for ef, ewt, ev in _wire_iter(lv):
-                        if ef == 1 and ewt == 0:
-                            mid = ev
-                        elif ef == 2 and ewt == 0:
-                            off = ev
-                        elif ef == 3 and ewt == 0:
-                            dur = ev
-                    events.append((meta.get(mid, f"#{mid}"), dur, off))
-            parsed_lines.append({"name": lname, "events": events})
-        planes.append({"name": name, "lines": parsed_lines})
-    return planes
+    for p in _glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True):
+        for plane in ProfileData.from_file(p).planes:
+            for line in plane.lines:
+                yield plane.name, line.name, [
+                    (e.name, float(e.start_ns), float(e.duration_ns))
+                    for e in line.events]
 
 
 def xplane_device_ms(logdir: str, plane_substr: str = "/device:",
                      by_name: bool = False):
     """Total device-busy milliseconds summed over every *.xplane.pb under
     ``logdir`` for planes whose name contains ``plane_substr`` (XLA device
-    planes are '/device:TPU:0'-style; pass '/host:' for host traces). Sums
-    top-level event durations per line and takes the busiest line per plane
-    (device planes put one op stream per line; nested tracing appears on
-    separate lines and must not be double-counted). ``by_name=True`` adds a
-    per-event-name breakdown dict."""
-    import glob as _glob
-
-    paths = _glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                       recursive=True)
-    total_ps = 0
-    names: Dict[str, int] = defaultdict(int)
-    for p in paths:
-        for plane in parse_xplane(p):
-            if plane_substr not in plane["name"]:
-                continue
-            best = 0
-            best_events: list = []
-            for line in plane["lines"]:
-                s = sum(e[1] for e in line["events"])
-                if s > best:
-                    best, best_events = s, line["events"]
-            total_ps += best
-            for n, d, _off in best_events:
-                names[n] += d
-    ms = total_ps / 1e9
+    planes are '/device:TPU:0'-style; pass '/host:' for host traces): the
+    plane's ``XLA Ops`` line (one event per operation) where it has one,
+    else its busiest line (nested tracing appears on separate lines and
+    must not be double-counted). ``by_name=True`` adds a per-event-name
+    breakdown dict."""
+    per_plane: Dict[str, Dict[str, list]] = defaultdict(dict)
+    for plane, line, events in _xplane_lines(logdir):
+        if plane_substr in plane:
+            per_plane[plane][line] = events
+    total_ns = 0.0
+    names: Dict[str, float] = defaultdict(float)
+    for lines in per_plane.values():
+        events = lines.get("XLA Ops") or max(
+            lines.values(), key=lambda ev: sum(e[2] for e in ev),
+            default=[])
+        for n, _s, d in events:
+            total_ns += d
+            names[n] += d
+    ms = total_ns / 1e6
     if by_name:
-        return ms, {k: v / 1e9 for k, v in
+        return ms, {k: v / 1e6 for k, v in
                     sorted(names.items(), key=lambda kv: -kv[1])}
     return ms
 
@@ -350,29 +254,24 @@ def xplane_mapped_ms(logdir: str, resolve) -> Dict[Any, float]:
 
     Dedup: on one thread line the CPU backend nests spans (a ``call`` thunk
     wraps the fused kernel's own span); only the OUTERMOST *mapped* event of
-    any overlap chain is counted, so wrapped kernels are never billed twice.
-    The interval walk uses XEvent offsets, which are line-relative — lines
-    are independent, which is exactly the granularity needed."""
-    import glob as _glob
-
+    any overlap chain is counted, so wrapped kernels are never billed
+    twice. Lines are independent, which is exactly the granularity
+    needed."""
     totals: Dict[Any, float] = defaultdict(float)
-    for p in _glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                        recursive=True):
-        for plane in parse_xplane(p):
-            for line in plane["lines"]:
-                mapped = []
-                for name, dur, off in line["events"]:
-                    key = resolve(name)
-                    if key is not None:
-                        # sort key: by start, LONGEST first on ties, so the
-                        # outermost event of an equal-offset chain wins
-                        mapped.append((off, -dur, key))
-                mapped.sort()
-                covered_end = -1
-                for off, neg_dur, key in mapped:
-                    if off >= covered_end:  # outermost of this overlap chain
-                        totals[key] += -neg_dur / 1e9
-                        covered_end = off - neg_dur
+    for _plane, _line, events in _xplane_lines(logdir):
+        mapped = []
+        for name, start, dur in events:
+            key = resolve(name)
+            if key is not None:
+                # sort key: by start, LONGEST first on ties, so the
+                # outermost event of an equal-start chain wins
+                mapped.append((start, -dur, key))
+        mapped.sort()
+        covered_end = float("-inf")
+        for start, neg_dur, key in mapped:
+            if start >= covered_end:  # outermost of this overlap chain
+                totals[key] += -neg_dur / 1e6
+                covered_end = start - neg_dur
     return dict(totals)
 
 

@@ -24,11 +24,24 @@ the shared measurement substrate:
   subsystem having to push.
 - **Health registry**: util/health.py monitors publish named pass/fail
   checks; ``/healthz`` aggregates them.
+- **Program spans on the device trace's clock**: a live span
+  (:func:`span`, :func:`step_span`, a :class:`PhaseTrack` phase) also
+  enters a ``jax.profiler.TraceAnnotation`` of its name while a profile is
+  being taken, so it lands on the host planes of the ``.xplane.pb`` beside
+  the device's programs. Deferred spans (:meth:`Telemetry.event_deferred`,
+  the staged request spans) are recorded after the fact and stay in the
+  Chrome export only.
+- **Phase tracks**: one thread's time cut into consecutive phases, each a
+  live span plus a cumulative seconds counter (the serving worker's seven
+  phases, serving/scheduler.py).
 
 Overhead stance: every hook is gated on :func:`enabled` (one attribute
-read); a span costs two ``time.time_ns`` calls plus one locked append.
-What the hooks cost a step on the chip has not been measured (PERF.md
-section 7, the ``tracing`` issue). The span buffer is a bounded
+read); a span costs two ``time.time_ns`` calls, one TraceMe check and one
+locked append. Measured on the chip's host with no profile running
+(PERF.md section 6, PR 39): the serving worker's instruments (seven
+phases, the pause around ``serving.batch``, the turnaround's counters)
+21.9 us a batch, a live span about 10 us (two a request on the HTTP
+threads), 1.3 us a batch with telemetry off. The span buffer is a bounded
 ring (``max_events``) so week-long training cannot leak host memory —
 drops are themselves counted (``telemetry.events_dropped_total``).
 
@@ -37,9 +50,11 @@ Env knob: ``DL4J_TPU_TELEMETRY=0`` disables all recording (config.py).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -59,6 +74,25 @@ _TRACE_EPOCH_NS = time.time_ns()
 def trace_epoch_ns() -> int:
     """The process's shared Chrome-trace time origin (wall ns)."""
     return _TRACE_EPOCH_NS
+
+
+#: set in a forked child (mp-ETL worker, datavec/executor.py), which must
+#: not call into JAX: its spans skip the TraceAnnotation bridge
+_in_forked_child = False
+
+
+def _annotate(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, entered, while a profile is
+    being taken; else None. One TraceMe check when no profile runs, and
+    nothing in a process that has not imported JAX or is a forked child."""
+    if _in_forked_child:
+        return None
+    prof = sys.modules.get("jax._src.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    ann = prof.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 # Histogram bucket bounds in SECONDS (most observed values are durations);
 # exponential-ish ladder from 0.5 ms to 60 s, +Inf implicit.
@@ -497,9 +531,10 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """Context manager recording one 'X' event; nesting tracked through a
-    thread-local stack so child spans carry ``parent`` attribution."""
+    thread-local stack so child spans carry ``parent`` attribution. Also
+    a TraceAnnotation of its name while a profile is being taken."""
 
-    __slots__ = ("_t", "name", "args", "t0")
+    __slots__ = ("_t", "name", "args", "t0", "_ann")
 
     def __init__(self, tele: Telemetry, name: str, args: dict):
         self._t = tele
@@ -509,15 +544,110 @@ class _Span:
     def __enter__(self):
         self.t0 = time.time_ns()
         self._t._span_stack().append(self.name)
+        self._ann = _annotate(self.name)
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         t1 = time.time_ns()
         stack = self._t._span_stack()
         if stack and stack[-1] == self.name:
             stack.pop()
         self._t.event(self.name, self.t0, t1, **self.args)
         return False
+
+
+class PhaseTrack:
+    """One thread's time as consecutive phases (the serving worker's, see
+    serving/scheduler.py). :meth:`mark` ends the phase in progress and
+    starts the next at ONE clock read, so the phases tile the thread's
+    time: each is a live span of its name (and a TraceAnnotation while a
+    profile is being taken) and adds its seconds to a counter named from
+    it (``serving.worker.fill`` -> ``serving.worker_fill_seconds_total``).
+    ``mark(None)`` ends the span in progress and starts none, so that an
+    enclosing span can close between two phases; the time until the next
+    mark counts to the next phase's counter. ``at`` holds when each phase
+    last started (wall ns). Code anywhere below the owning thread marks
+    through :func:`phase`; a thread without a track marks nothing."""
+
+    __slots__ = ("_t", "labels", "at", "_name", "_t0", "_count0", "_ann",
+                 "_who", "_keys")
+
+    def __init__(self, tele: Telemetry, labels: dict):
+        self._t = tele
+        self.labels = labels
+        self.at: Dict[str, int] = {}
+        self._name: Optional[str] = None
+        self._t0 = 0
+        self._count0: Optional[int] = None
+        self._ann = None
+        th = threading.current_thread()
+        self._who = (os.getpid(), th.ident, th.name)
+        self._keys: Dict[str, tuple] = {}   # phase -> its counter's key
+
+    def mark(self, name: Optional[str]):
+        if name is not None and name == self._name:
+            return
+        t = time.time_ns()
+        if self._name is not None:
+            self._close(t)
+            self._count0 = t
+        elif self._count0 is None:
+            self._count0 = t
+        self._name = name
+        if name is not None:
+            self._t0 = self.at[name] = t
+            self._ann = _annotate(name)
+
+    def _close(self, t: int):
+        """The span and the counter of the phase in progress, under one
+        registry lock (a phase is closed eight times a batch)."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        tele, name = self._t, self._name
+        key = self._keys.get(name)
+        if key is None:
+            key = self._keys[name] = (_phase_counter(name),
+                                      _labels_key(self.labels))
+        pid, tid, tname = self._who
+        args = dict(self.labels)
+        stack = tele._span_stack()
+        if stack:
+            args["parent"] = stack[-1]
+        ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
+              "tname": tname, "ts": self._t0, "dur": t - self._t0,
+              "args": args}
+        with tele._lock:
+            tele._append(ev)
+            tele.counters[key] = tele.counters.get(key, 0.0) + \
+                (t - self._count0) / 1e9
+
+    def stop(self):
+        """End the phase in progress and take the track off its thread."""
+        if self._name is not None and self._t.enabled:
+            self._close(time.time_ns())
+        self._name = None
+        if getattr(self._t._tls, "phases", None) is self:
+            self._t._tls.phases = None
+
+    @contextlib.contextmanager
+    def suspended(self):
+        """The owning thread marks nothing inside (a nested batch run by
+        the scheduler's chunked-prefill yield counts to the phase in
+        progress)."""
+        tls = self._t._tls
+        tls.phases = None
+        try:
+            yield
+        finally:
+            tls.phases = self
+
+
+def _phase_counter(name: str) -> str:
+    group, _, leaf = name.rpartition(".")
+    return f"{group}_{leaf}_seconds_total"
 
 
 # ---------------------------------------------------------------- module API
@@ -554,6 +684,31 @@ def instant(name: str, **args):
     Telemetry.get_instance().instant(name, **args)
 
 
+def start_phases(**labels) -> PhaseTrack:
+    """A :class:`PhaseTrack` for the calling thread (replacing any), its
+    counters labelled ``labels``."""
+    tele = Telemetry.get_instance()
+    track = tele._tls.phases = PhaseTrack(tele, labels)
+    return track
+
+
+def current_phases() -> Optional[PhaseTrack]:
+    """The calling thread's phase track, or None."""
+    return getattr(Telemetry.get_instance()._tls, "phases", None)
+
+
+def phase(name: Optional[str]):
+    """Start phase ``name`` (None: end the one in progress) on the calling
+    thread's track; nothing without a track or with telemetry off (no
+    clock read)."""
+    tele = Telemetry._instance
+    if tele is None or not tele.enabled:
+        return
+    track = getattr(tele._tls, "phases", None)
+    if track is not None:
+        track.mark(name)
+
+
 def set_health(check: str, ok: bool, detail: str = ""):
     Telemetry.get_instance().set_health(check, ok, detail)
 
@@ -563,9 +718,11 @@ class _StepSpan:
     markers: if the dispatch retraced, two sub-spans are emitted whose
     durations come from jax.monitoring (jaxpr trace / backend compile), so
     the merged trace shows WHERE a ragged shape paid compile inside the
-    training loop. Costs two counter reads on the hot path."""
+    training loop. Costs two counter reads on the hot path. A
+    TraceAnnotation of its name while a profile is being taken, like
+    :class:`_Span`."""
 
-    __slots__ = ("name", "args", "_w", "_tr0", "_j0", "_c0", "t0")
+    __slots__ = ("name", "args", "_w", "_tr0", "_j0", "_c0", "t0", "_ann")
 
     def __init__(self, name: str, args: dict):
         self.name = name
@@ -582,12 +739,15 @@ class _StepSpan:
         self._j0 = w.jaxpr_trace_seconds
         self._c0 = w.backend_compile_seconds
         self.t0 = time.time_ns()
+        self._ann = _annotate(self.name)
         return self
 
     def __exit__(self, *exc):
         w = self._w
         if w is None:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         t1 = time.time_ns()
         tele = Telemetry.get_instance()
         tele.event(self.name, self.t0, t1, **self.args)
@@ -791,7 +951,10 @@ def _after_fork_child():
     """Forked children (mp-ETL workers) inherit the parent's registry by
     memory image: re-arm the lock (the parent may have held it mid-fork)
     and clear inherited spans so a worker ships only its OWN events — its
-    PID attribution is then correct by construction."""
+    PID attribution is then correct by construction. The child's spans
+    skip the TraceAnnotation bridge: it must not call into JAX."""
+    global _in_forked_child
+    _in_forked_child = True
     t = Telemetry._instance
     if t is not None:
         t._lock = threading.Lock()

@@ -269,3 +269,45 @@ class TestStats:
         finally:
             tele.enabled = was
             tele.reset()
+
+
+class TestXplaneReaders:
+    """util/profiler.py's readers stand on JAX's ProfileData, the reader
+    the benchmark's chipbench/trace.py uses (PR 39 took out the
+    hand-written protobuf parser)."""
+
+    SMALL = "chipbench/testdata/small.xplane.pb"
+
+    def _logdir(self, tmp_path):
+        import os
+        import shutil
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        shutil.copy(os.path.join(root, self.SMALL), tmp_path)
+        return str(tmp_path), os.path.join(root, self.SMALL)
+
+    def test_device_ms_is_the_benchmarks_op_time(self, tmp_path):
+        from chipbench import trace
+        from deeplearning4j_tpu.util.profiler import xplane_device_ms
+
+        logdir, path = self._logdir(tmp_path)
+        # without the host planes no cb:window clips: every op counts whole
+        planes = [p for p in trace.read_planes(path)
+                  if not p["name"].startswith("/host:")]
+        op_s = trace.reduce_trace(planes)["op_s"]
+        ms, by_name = xplane_device_ms(logdir, by_name=True)
+        assert ms > 0
+        assert ms == pytest.approx(sum(op_s.values()) * 1e3, rel=1e-9)
+        assert sum(by_name.values()) == pytest.approx(ms, rel=1e-9)
+
+    def test_mapped_ms_counts_the_outermost_of_nested_events(self, tmp_path):
+        from chipbench import trace
+        from deeplearning4j_tpu.util.profiler import xplane_mapped_ms
+
+        logdir, path = self._logdir(tmp_path)
+        # the benchmark's cb:step spans nest in its cb:window span on one
+        # host line: one key for both counts the window alone
+        got = xplane_mapped_ms(logdir, lambda n: "cb" if n.startswith("cb:")
+                               else None)
+        window_s = trace.reduce_trace(trace.read_planes(path))["window_s"]
+        assert got == {"cb": pytest.approx(window_s * 1e3, rel=1e-9)}

@@ -118,9 +118,11 @@ class TestRequestIdsAndPhaseSpans:
 
     def test_worker_thread_rows_and_nesting(self, dense_model,
                                             _clean_registry):
-        """ISSUE 12 satellite: scheduler worker spans land on a
-        model-id-named thread row in write_chrome_trace(), nesting the
-        request phase spans (extends the r10 one-timebase merge test)."""
+        """ISSUE 12 satellite, PR 39's phases: the worker's phase spans
+        land on a model-id-named thread row in write_chrome_trace(), one
+        of each a batch; serving.batch nests the execute phases and
+        parents the request phase spans (extends the r10 one-timebase
+        merge test)."""
         sched = BatchScheduler(dense_model, max_wait_ms=1.0).start()
         sched.submit(_x()).result(timeout=30)
         sched.drain(timeout=10)
@@ -130,14 +132,36 @@ class TestRequestIdsAndPhaseSpans:
                 if e.get("name") == "thread_name"}
         assert "serving-dense" in rows
         worker_tid = rows["serving-dense"]
-        cycle = [e for e in evs if e["name"] == "serving.worker.batch_cycle"]
-        batch = [e for e in evs if e["name"] == "serving.batch"]
-        compute = [e for e in evs if e["name"] == "serving.request.compute"]
-        assert cycle and batch and compute
-        assert all(e["tid"] == worker_tid for e in cycle + batch + compute)
-        assert cycle[0]["args"]["requests"] == 1
-        # nesting chain: batch under the cycle, request phases under batch
-        assert batch[0]["args"]["parent"] == "serving.worker.batch_cycle"
+        named = lambda n: [e for e in evs if e["name"] == n]
+        batch = named("serving.batch")
+        compute = named("serving.request.compute")
+        # a classify batch has no decode to wait on: its forward and
+        # fetch are one launch
+        inner = ["serving.generate.prep", "serving.generate.launch",
+                 "serving.generate.drain"]
+        outer = ["serving.worker.fill", "serving.batch.respond"]
+        assert len(batch) == 1 and compute
+        for name in inner + outer:
+            assert len(named(name)) == 1, name
+        assert not named("serving.generate.wait")
+        assert all(e["tid"] == worker_tid
+                   for n in inner + outer + ["serving.batch",
+                                             "serving.request.compute"]
+                   for e in named(n))
+        # nesting: execute phases under the batch, in order, inside it;
+        # fill before it, respond after it, on the top level
+        b0, b1 = batch[0]["ts"], batch[0]["ts"] + batch[0]["dur"]
+        starts = [named(n)[0]["ts"] for n in inner]
+        assert starts == sorted(starts)
+        for n in inner:
+            e = named(n)[0]
+            assert e["args"]["parent"] == "serving.batch"
+            assert b0 <= e["ts"] and e["ts"] + e["dur"] <= b1
+        for n in outer:
+            assert "parent" not in named(n)[0]["args"]
+        assert named("serving.worker.fill")[0]["ts"] < b0
+        assert named("serving.batch.respond")[0]["ts"] >= b1
+        assert "parent" not in batch[0]["args"]
         assert compute[0]["args"]["parent"] == "serving.batch"
         # exported trace is Perfetto-loadable and relative-timed
         assert json.loads(json.dumps(trace))["traceEvents"]
@@ -167,18 +191,31 @@ class TestDecodeTracing:
         model.warmup()
         return model
 
-    def test_prefill_and_per_token_decode_spans(self, gen_model,
-                                                _clean_registry):
+    def test_prefill_span_and_execute_phases(self, gen_model,
+                                             _clean_registry):
+        """The prefill span keeps its args and times the launch; the
+        batch's four execute phases come once each, in order, under
+        serving.batch (PR 39 retired the per-token enqueue span)."""
         sched = BatchScheduler(gen_model, max_wait_ms=1.0).start()
         toks = sched.submit(np.asarray([1, 2, 3], np.int32),
                             lane="batch", max_new_tokens=5).result(timeout=60)
         sched.drain(timeout=10)
         assert len(toks) == 5
         prefill = _events(_clean_registry, "serving.generate.prefill")
-        steps = _events(_clean_registry, "serving.generate.decode_token")
         assert len(prefill) == 1
-        assert len(steps) == 4  # max_new_tokens - 1 decode steps
-        assert [e["args"]["step"] for e in steps] == [1, 2, 3, 4]
+        assert prefill[0]["args"]["prefix_hit"] is False
+        assert prefill[0]["args"]["chunks"] == 1
+        phases = [_events(_clean_registry, f"serving.generate.{n}")
+                  for n in ("prep", "launch", "wait", "drain")]
+        assert [len(p) for p in phases] == [1, 1, 1, 1]
+        assert all(p[0]["args"]["parent"] == "serving.batch"
+                   for p in phases)
+        # consecutive: each starts where the one before ended
+        for a, b in zip(phases, phases[1:]):
+            assert a[0]["ts"] + a[0]["dur"] == b[0]["ts"]
+        # the prefill is launched inside prep; launch starts once it is out
+        assert prefill[0]["ts"] >= phases[0][0]["ts"]
+        assert prefill[0]["ts"] + prefill[0]["dur"] >= phases[1][0]["ts"]
 
     def test_tokens_per_sec_per_request(self, gen_model, _clean_registry):
         sched = BatchScheduler(gen_model, max_wait_ms=1.0).start()

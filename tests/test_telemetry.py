@@ -105,6 +105,38 @@ class TestRegistry:
         # inner completed first and sits inside outer's window
         assert by_name["inner"]["ts"] >= by_name["outer"]["ts"]
 
+    def test_live_spans_reach_a_profile_a_forked_child_skips_them(
+            self, _clean_registry, tmp_path, monkeypatch):
+        """PR 39: span() and step_span() enter a TraceAnnotation of their
+        name while a profile is taken, so they land on a host plane of the
+        .xplane.pb; deferred spans do not; a forked child (mp-ETL) never
+        calls into JAX, and its registry still records the spans."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        def profiled(tag):
+            jax.profiler.start_trace(str(tmp_path / tag))
+            try:
+                with tm.span("t.live"):
+                    with tm.step_span("t.step"):
+                        pass
+                _clean_registry.event_deferred("t.deferred", 0, 1)
+            finally:
+                jax.profiler.stop_trace()
+            path, = glob.glob(str(tmp_path / tag / "**" / "*.xplane.pb"),
+                              recursive=True)
+            return {e.name for p in ProfileData.from_file(path).planes
+                    if p.name.startswith("/host:")
+                    for ln in p.lines for e in ln.events}
+
+        seen = profiled("parent")
+        assert {"t.live", "t.step"} <= seen and "t.deferred" not in seen
+        monkeypatch.setattr(tm, "_in_forked_child", True)
+        assert not {"t.live", "t.step"} & profiled("child")
+        names = [e["name"] for e in _clean_registry.drain_events()]
+        assert names.count("t.live") == names.count("t.step") == 2
+
     def test_merge_events_keeps_foreign_pids(self, _clean_registry):
         tele = _clean_registry
         fake = [{"name": "etl.transform_chunk", "ph": "X", "pid": 99999,
