@@ -263,9 +263,9 @@ class TransformerEncoderBlock(Layer):
     # side in one row — and each stream's page table (``tables``,
     # (B, max_blocks)) names the blocks behind its logical positions.
     # Projections, sublayer math and the attention mask are the SAME code
-    # the contiguous path runs; the attention itself walks the table block
-    # chunk by block chunk as far as the longest stream reaches
-    # (ops/attention.paged_attention), so paged decode gives the
+    # the contiguous path runs; the attention itself walks the page table
+    # as far as the streams reach (ops/attention.paged_attention), so
+    # paged decode gives the
     # contiguous decode's tokens, and its logits to the rounding of a
     # float32 sum (tests/test_paged_decode.py).
 

@@ -23,6 +23,7 @@ TPU-native design:
 from __future__ import annotations
 
 import functools
+import sys
 from typing import Optional
 
 import jax
@@ -532,6 +533,214 @@ def paged_chunk_blocks(batch: int, max_blocks: int, block_size: int) -> int:
     return max(1, min(positions // int(block_size), int(max_blocks)))
 
 
+#: Query rows (window tokens x query heads over one K/V row) the kernel
+#: holds in one tile; a wider window, a resumed prefill's chunk, takes the
+#: XLA pass.
+PAGED_QUERY_ROWS = 256
+#: Positions the kernel contracts at a time: that many positions' blocks
+#: of a stream's page table, each copied from HBM on its own, so a chunk
+#: re-reads nothing.
+PAGED_STEP_POSITIONS = 128
+
+
+def paged_step_blocks(max_blocks: int, block_size: int) -> int:
+    """Blocks the paged attention kernel contracts at a time (a chunk):
+    ``PAGED_STEP_POSITIONS`` positions' worth, at most the whole table."""
+    return max(1, min(PAGED_STEP_POSITIONS // int(block_size),
+                      int(max_blocks)))
+
+
+def paged_kernel_engages(query_rows: int, pool, block_size: int) -> bool:
+    """Whether :func:`paged_attention` runs as the Pallas kernel
+    (:func:`_paged_attention_pallas`) for ``query_rows`` query rows (window
+    tokens x query heads: ``W * H`` in every layout that calls it) over
+    ``pool`` (S, row): a TPU, rows of float32 or bfloat16 in whole 128-lane
+    tiles, a block of whole sublane tiles, and the rows within one query
+    tile. From static shapes and the platform only, so the host that counts
+    what a step read asks the same question the trace does."""
+    dtype = jnp.dtype(pool.dtype)
+    return (jax.default_backend() == "tpu"
+            and dtype in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
+            and pool.shape[-1] % 128 == 0
+            and int(block_size) * dtype.itemsize % 32 == 0
+            and int(query_rows) <= PAGED_QUERY_ROWS)
+
+
+#: Pallas' interpreter of Mosaic GPU kernels, which ``pallas_call``
+#: imports with itself and falls back from when it cannot be imported.
+_MOSAIC_GPU_INTERPRETER = (
+    "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call")
+
+
+def _import_pallas():
+    """``(pallas, pallas.tpu)``, imported on first use. On a TPU the Mosaic
+    GPU interpreter is left out of the import: no TPU program runs it, and
+    it is 0.64 of the import's 1.01 s on a CPU host (1.2 s in all on the
+    chip's), which a serving cell whose only kernel is this one pays in its
+    set-up."""
+    if jax.default_backend() == "tpu":
+        sys.modules.setdefault(_MOSAIC_GPU_INTERPRETER, None)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl, pltpu
+
+
+def _paged_kernel(tables_ref, last_ref, pos_ref, q_ref, *refs, pages, bs,
+                  width, heads, scale, shared):
+    """One stream a grid step. ``refs``: the K pool and the V pool unless K
+    is V (both left in HBM), the output, the two-slot VMEM buffers of a
+    chunk of ``pages`` blocks for K (and V), their DMA semaphores, and the
+    float32 running max, sum and accumulator. The stream's chunks are a
+    ``fori_loop`` as long as its own table (``last_ref[b]``, its last live
+    page, -1 for a stream that holds none): chunk ``c + 1``'s blocks are
+    copied while chunk ``c`` is contracted, and a page past the stream's
+    last is read from the trash block, masked. ``q_ref`` holds the stream's
+    query rows ``(token, head)``, head minor, each row's head alone in its
+    own lanes (zeros elsewhere), so one product scores every head against
+    the whole row and the zero lanes add 0."""
+    pl, pltpu = _import_pallas()
+    n = 1 if shared else 2              # K, or K and V
+    pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
+    sem, m_s, l_s, acc = refs[2 * n + 1:]
+    b = pl.program_id(0)
+    last = last_ref[b]
+    exact = bufs[0].dtype == jnp.float32
+    dot = functools.partial(
+        lax.dot_general, preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST if exact else lax.Precision.DEFAULT)
+
+    def copy(c, slot, n, i):
+        p = c * pages + n
+        blk = jnp.where(p <= last, tables_ref[b, jnp.minimum(p, width - 1)],
+                        0)
+        return pltpu.make_async_copy(pools[i].at[pl.ds(blk * bs, bs)],
+                                     bufs[i].at[slot, pl.ds(n * bs, bs)],
+                                     sem.at[i, slot])
+
+    def each_block(c, slot, act):
+        def one(n, carry):
+            for i in range(len(pools)):
+                act(copy(c, slot, n, i))
+            return carry
+        lax.fori_loop(0, pages, one, 0)
+
+    m_s[...] = jnp.full_like(m_s, _NEG_BIG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc[...] = jnp.zeros_like(acc)
+    chunks = (last + pages) // pages
+
+    @pl.when(chunks > 0)
+    def _first():
+        each_block(0, 0, lambda cp: cp.start())
+
+    def chunk(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < chunks)
+        def _next():
+            each_block(c + 1, 1 - slot, lambda cp: cp.start())
+
+        each_block(c, slot, lambda cp: cp.wait())
+        k = bufs[0][slot]                                # (keys, L)
+        v = k if shared else bufs[1][slot]
+        s = dot(q_ref[0].astype(k.dtype), k, (((1,), (1,)), ((), ()))) * scale
+        k_pos = c * (pages * bs) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos <= pos_ref[0], s, _NEG_BIG)
+        m_prev = m_s[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.where(s <= _NEG_BIG / 2, 0.0, jnp.exp(s - m_new))
+        l_s[...] = jnp.broadcast_to(
+            corr * l_s[:, 0:1] + jnp.sum(p, axis=1, keepdims=True), l_s.shape)
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        acc[...] = acc[...] * corr + dot(p.astype(v.dtype), v,
+                                         (((1,), (0,)), ((), ())))
+        return carry
+
+    lax.fori_loop(0, chunks, chunk, 0)
+    l = l_s[:, 0:1]
+    out = acc[...] / jnp.where(l == 0.0, 1.0, l)
+    if heads > 1:
+        # row (token, head) keeps its own head's lanes; the heads of a
+        # token are summed into its one output row, one nonzero a lane
+        rows, lanes = out.shape
+        mine = (lax.broadcasted_iota(jnp.int32, out.shape, 1)
+                // (lanes // heads)
+                == lax.broadcasted_iota(jnp.int32, out.shape, 0) % heads)
+        out = jnp.sum(jnp.where(mine, out, 0.0).reshape(
+            rows // heads, heads, lanes), axis=1)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_size", "scale", "interpret"))
+def _paged_attention_pallas(q, k_pool, v_pool, tables, positions,
+                            block_size: int, scale: float,
+                            interpret: bool = False):
+    """:func:`paged_attention` as one kernel: the arguments and result of
+    the XLA pass, ``v_pool`` None where K is V (one pool read once for
+    both). Grid (stream,): the pools stay in HBM and a stream's own blocks
+    are copied to VMEM by hand, ``PAGED_STEP_POSITIONS`` positions at a
+    time (one DMA a block, the next chunk's in flight while this one is
+    contracted), as far as its last live page (``max(positions[b]) //
+    block_size``) and no further, so a stream's dead blocks are neither
+    fetched nor computed and every live block crosses from HBM to VMEM
+    once. A stream whose table starts on the trash block holds no block (a
+    bucket's padding row: block 0 is never handed to a stream) and reads
+    nothing; its result is zeros. The page tables and each stream's last
+    page are scalar-prefetched; the running (max, sum, accumulator) lives
+    in VMEM in float32. Float32 rows are contracted at ``HIGHEST``,
+    bfloat16 rows in bfloat16 with float32 accumulation, as the XLA pass's
+    products are. Jitted, so that a program of many layers traces and
+    lowers the kernel once."""
+    pl, pltpu = _import_pallas()
+    b, heads, w, dh = q.shape
+    lanes = heads * dh
+    bs = int(block_size)
+    width = tables.shape[1]
+    pages = paged_step_blocks(width, bs)
+    rows = w * heads
+    if heads == 1:
+        qr = q[:, 0]
+    else:   # (token, head) rows, each head alone in its own lanes
+        qt = jnp.swapaxes(q, 1, 2)[:, :, :, None, :]
+        qr = (qt * jnp.eye(heads, dtype=q.dtype)[:, :, None]).reshape(
+            b, rows, lanes)
+    pos = jnp.repeat(positions.astype(jnp.int32), heads, axis=1)[..., None]
+    last = jnp.where(  # each stream's last live page, -1 if it holds none
+        tables[:, 0] == 0, -1,
+        jnp.clip(jnp.max(positions, axis=1) // bs, 0, width - 1)).astype(
+            jnp.int32)
+
+    per_stream = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda i, tab, lst: (i,) + (0,) * len(shape))
+    pools = [k_pool] + ([] if v_pool is None else [v_pool])
+    o = pl.pallas_call(
+        functools.partial(_paged_kernel, pages=pages, bs=bs, width=width,
+                          heads=heads, scale=float(scale),
+                          shared=v_pool is None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[per_stream(rows, 1), per_stream(rows, lanes)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=per_stream(w, lanes),
+            scratch_shapes=[pltpu.VMEM((2, pages * bs, lanes), p.dtype)
+                            for p in pools]
+            + [pltpu.SemaphoreType.DMA((len(pools), 2)),
+               pltpu.VMEM((rows, 128), jnp.float32),
+               pltpu.VMEM((rows, 128), jnp.float32),
+               pltpu.VMEM((rows, lanes), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, w, lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_attention",
+    )(tables.astype(jnp.int32), last, pos, qr, *pools)
+    if heads == 1:
+        return o[:, None]
+    return jnp.swapaxes(o.reshape(b, w, heads, dh), 1, 2)
+
+
 def paged_slots(tables, positions, block_size: int):
     """Flat pool slot of each logical position: ``tables`` (B, max_blocks)
     int32 page tables, ``positions`` (B, W) int32 -> (B, W) int32,
@@ -558,23 +767,45 @@ def paged_attention(q, k_pool, v_pool, tables, positions, block_size: int,
     ``p <= positions[b, w]`` (the causal-over-cache rule, identical to the
     contiguous ``decode_step``).
 
-    The pass walks the table in chunks of :func:`paged_chunk_blocks` whole
-    blocks and stops after the chunk that holds the largest position of
-    the batch: a ``fori_loop`` whose trip count is data, so ONE program
-    serves every context length and reads what the longest stream holds,
-    not the declared maximum. Each turn gathers whole blocks, contracts
-    ``q`` against them in the pool's own [b, k, h, d] layout and folds
-    them into a float32 running (max, sum, accumulator) through
-    :func:`online_softmax_update`. Every live key is attended in float32;
-    against the dense softmax over all positions only the order of the
-    sums differs (tokens identical, logits to rounding: docs/SERVING.md).
+    On a TPU, where :func:`paged_kernel_engages` says so (rows in whole
+    lane tiles, a window of at most ``PAGED_QUERY_ROWS`` query rows), it is
+    one Pallas kernel, :func:`_paged_attention_pallas`: each stream walks
+    its own blocks only and each live block is copied to VMEM once.
+    Anywhere else the XLA pass, :func:`_paged_attention_xla`, walks the
+    table in chunks as far as the batch's longest stream. One program
+    serves every context length either way, and every live key is
+    attended in float32; against the dense softmax over all positions only
+    the order of the sums differs (tokens identical, logits to rounding:
+    docs/SERVING.md).
 
     Shared-prefix note (serving/paged.py): several rows of ``tables`` may
     hold the same physical blocks (a refcounted prefix-cache hit). The
-    gather is read-only and position-masked per stream, so sharing is
-    invisible here — K/V rows at position ``p`` are a pure function of
-    the token prefix up to ``p``, which is what made the blocks
-    shareable."""
+    read is position-masked per stream, so sharing is invisible here —
+    K/V rows at position ``p`` are a pure function of the token prefix up
+    to ``p``, which is what made the blocks shareable. A stream whose
+    table starts on the trash block holds nothing (a bucket's padding
+    row): the kernel reads nothing for it and returns zeros there, the XLA
+    pass whatever the trash block holds; the caller discards that row."""
+    b, h, w, dh = q.shape
+    if scale is None:
+        scale = 1.0 / (dh ** 0.5)
+    if paged_kernel_engages(h * w, k_pool, block_size):
+        return _paged_attention_pallas(
+            q, k_pool, None if v_pool is k_pool else v_pool, tables,
+            positions, int(block_size), float(scale))
+    return _paged_attention_xla(q, k_pool, v_pool, tables, positions,
+                                block_size, scale)
+
+
+def _paged_attention_xla(q, k_pool, v_pool, tables, positions,
+                         block_size: int, scale: float):
+    """:func:`paged_attention` as XLA programs: the table walked in chunks
+    of :func:`paged_chunk_blocks` whole blocks, stopping after the chunk
+    that holds the largest position of the batch (a ``fori_loop`` whose
+    trip count is data). Each turn gathers whole blocks, contracts ``q``
+    against them in the pool's own [b, k, h, d] layout and folds them into
+    a float32 running (max, sum, accumulator) through
+    :func:`online_softmax_update`."""
     b, h, w, dh = q.shape
     bs = int(block_size)
     width = tables.shape[1]
@@ -584,8 +815,6 @@ def paged_attention(q, k_pool, v_pool, tables, positions, block_size: int,
     tables = jnp.pad(tables, ((0, 0), (0, n_chunks * cb - width)))  # trash
     kb = jnp.asarray(k_pool).reshape(-1, bs, h * dh)  # whole blocks
     vb = jnp.asarray(v_pool).reshape(-1, bs, h * dh)
-    if scale is None:
-        scale = 1.0 / (dh ** 0.5)
     qf = jnp.asarray(q, jnp.float32)
     turns = jnp.minimum(jnp.max(positions) // chunk + 1, n_chunks)
 
@@ -619,12 +848,13 @@ def latent_paged_attention(q_abs, pool, tables, positions, block_size: int,
     ``q_abs``: (B, H, W, R) queries already carried into that row space,
     ``[qc_h W_uk,h^T | qr_h]``. Every head attends the same rows, so the
     heads are W more queries of one head whose key is the row and whose
-    value is the row too: :func:`paged_attention` runs as it is, page table,
-    chunking, trip count and float32 online softmax shared with the
-    per-head K/V pools, and the first ``v_dim`` numbers of what it returns
-    are ``sum_t p_t c_t``, (B, H, W, v_dim). The caller expands that
-    through ``W_uv``. ``scale`` is the expanded form's (1/sqrt of the
-    per-head query width), not 1/sqrt(R)."""
+    value is the row too: :func:`paged_attention` runs as it is, its walk
+    of the page table and its float32 online softmax shared with the
+    per-head K/V pools (the kernel reads the one pool once for both), and
+    the first ``v_dim`` numbers of what it returns are ``sum_t p_t c_t``,
+    (B, H, W, v_dim). The caller expands that through ``W_uv``. ``scale``
+    is the expanded form's (1/sqrt of the per-head query width), not
+    1/sqrt(R)."""
     b, h, w, r = q_abs.shape
     o = paged_attention(q_abs.reshape(b, 1, h * w, r), pool, pool, tables,
                         jnp.tile(positions, (1, h)), block_size, scale=scale)
@@ -643,8 +873,8 @@ def grouped_paged_attention(q, pool, tables, positions, block_size: int,
     both in :func:`latent_paged_attention`: a query is carried as
     ``[q | 0]``, the zeros meet ``v`` and add 0 to a score, and the last
     ``Dh`` numbers of what :func:`paged_attention` returns are ``sum_t p_t
-    v_t``. Page table, chunking, trip count and the float32 online softmax
-    are that one pass's; no second walk of the table, no key or value pool
+    v_t``. The walk of the page table and the float32 online softmax are
+    that one call's; no second walk of the table, no key or value pool
     sliced out of the rows. -> (B, H, W, Dh)."""
     b, h, w, dh = q.shape
     per = h // n_kv_heads

@@ -14,13 +14,15 @@ executables:
   round up to ``seq_buckets``; the prompt's K/V scatter into the paged
   block pool through each stream's page table (serving/paged.py).
 - **decode_step** — one token per call over the page table: scatter the
-  new token's K/V at its slot, then attend ``k_pos <= position`` in one
-  block-chunked pass that stops where the batch's longest stream ends
-  (ops/attention.paged_attention). The page table and the trip count
-  are DATA, not shape, so ONE executable (per batch bucket) serves every
-  mix of context lengths with zero steady-state recompiles — and the
-  pool is shared, so memory scales with actual tokens, not ``streams ×
-  max_length`` (the ``concurrent_streams_per_device`` headline).
+  new token's K/V at its slot, then attend ``k_pos <= position`` over
+  the page table (ops/attention.paged_attention: on a TPU one kernel in
+  which each stream walks its own blocks, elsewhere a block-chunked pass
+  that stops where the batch's longest stream ends). The page table and
+  the positions are DATA, not shape, so ONE executable (per batch
+  bucket) serves every mix of context lengths with zero steady-state
+  recompiles — and the pool is shared, so memory scales with actual
+  tokens, not ``streams × max_length`` (the
+  ``concurrent_streams_per_device`` headline).
 - **verify** — the speculative-decoding window: a small DRAFT net
   (``Bert(causal=True)`` tiny, loaded per-model via the router) proposes
   ``spec_tokens`` greedy tokens one cheap step at a time; the TARGET
@@ -1002,16 +1004,39 @@ class Generator:
         logits = jnp.stack([final[i] for i in range(batch)])
         return logits, n_chunks
 
-    def _kv_positions_read(self, batch: int, last_pos: int) -> int:
-        """KV positions one paged step reads per K and V pool of a layer
-        when its largest query position is ``last_pos``: batch x chunk x
-        turns, the trip count ``ops/attention.paged_attention`` takes from
-        the positions, worked out here from the same static shapes."""
+    def _kv_kernel(self, window: int) -> bool:
+        """Whether a paged step of ``window`` tokens a stream attends
+        through the Pallas kernel (``ops/attention.paged_kernel_engages``),
+        asked of the first layer that keeps token rows: its query heads and
+        the rows of its pool."""
+        for blk, pool in zip(self.blocks, self.pool.pools):
+            if cache_kind(blk) == "tokens":
+                rows = pool["k"] if "k" in pool else pool["rows"]
+                return attn_ops.paged_kernel_engages(
+                    window * blk.n_heads, rows, self.block_size)
+        return False
+
+    def _kv_positions_read(self, first, b_real: int, steps: int,
+                           kernel: bool) -> int:
+        """KV positions ``steps`` paged steps read per K and V pool of a
+        layer, ``first`` (B,) each row's largest query position at the first
+        of them, one more at each step after, the first ``b_real`` rows
+        live. The Pallas kernel copies each live row's own blocks up to its
+        position in whole chunks of ``paged_step_blocks`` (the trash block
+        for a page past the row's last) and nothing for a padding row, which
+        holds no block. The XLA pass reads batch x chunk x turns, the trip
+        count it takes from the largest position. Worked out once a batch
+        from the same static shapes."""
         bs = self.block_size
         width = self.pool.max_blocks_per_stream
-        cb = attn_ops.paged_chunk_blocks(batch, width, bs)
-        turns = min(last_pos // (cb * bs) + 1, -(-width // cb))
-        return batch * cb * bs * turns
+        last = np.asarray(first)[:, None] + np.arange(steps)  # (B, steps)
+        if kernel:
+            own = np.minimum(last[:b_real] // bs, width - 1) + 1
+            pages = attn_ops.paged_step_blocks(width, bs)
+            return int((-(-own // pages)).sum()) * pages * bs
+        cb = attn_ops.paged_chunk_blocks(len(last), width, bs)
+        turns = np.minimum(last.max(axis=0) // (cb * bs) + 1, -(-width // cb))
+        return len(last) * cb * bs * int(turns.sum())
 
     def _count_kv_read(self, batch: int, read: int, steps: int):
         """One batch's decode/verify steps onto the counters: what they
@@ -1114,8 +1139,6 @@ class Generator:
                                       lens, starts, cow, pending, tele,
                                       stats, yield_hook)
         positions = lengths
-        longest = max(lens)  # the batch's largest position, step 0
-        kv_read = 0
         steps = []
         done = np.zeros(b_real, bool)
         key, sub = jax.random.split(key)
@@ -1131,12 +1154,15 @@ class Generator:
             logits, pools = self._decode_paged_jit(
                 raw, self.pool.pools, tables, cur, positions, limits)
             self.pool.pools = pools
-            kv_read += self._kv_positions_read(batch, longest + i)
             positions = positions + 1
             key, sub = jax.random.split(key)
             cur = self._sample(logits, temperature, sub)
         tm.phase("serving.generate.wait")
-        self._count_kv_read(batch, kv_read, len(steps) - 1)
+        first = np.ones(batch, np.int64)  # every row's position, step 0:
+        first[:b_real] = lens             # a padding row holds one token
+        n = len(steps) - 1
+        self._count_kv_read(batch, self._kv_positions_read(
+            first, b_real, n, self._kv_kernel(1)), n)
         self._count_state_decode(batch, b_real, len(steps) - 1)
         self._count_moe()
         fetched = [np.asarray(s) for s in steps]
@@ -1193,6 +1219,7 @@ class Generator:
         unfinished = lambda: not done.all() and any(
             len(emitted[i]) < max_new for i in range(b_real) if not done[i])
         rounds = kv_read = 0
+        kernel = self._kv_kernel(w)
         while unfinished():
             rounds += 1
             positions = jnp.asarray(np.minimum(pos_np,
@@ -1223,7 +1250,8 @@ class Generator:
                 raw, self.pool.pools, tables, window, positions, limits)
             self.pool.pools = pools
             kv_read += self._kv_positions_read(
-                batch, min(int(pos_np.max()), self.max_length - 1) + w - 1)
+                np.minimum(pos_np, self.max_length - 1) + w - 1, b_real, 1,
+                kernel)
             g = np.asarray(jnp.argmax(glogits, axis=-1))  # (B, w) host
             win = np.asarray(window)
             # accept the longest prefix the draft got right: window[j] is

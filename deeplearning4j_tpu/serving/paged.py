@@ -14,10 +14,10 @@ This module replaces that with the vLLM-style paged layout:
 - **A page table per stream** — the host-side list of physical block ids
   backing logical positions ``[0, ceil((len + max_new) / block_size) *
   block_size)``. The decode executable takes the table as data
-  ``(B, max_blocks)`` and walks it in chunks of whole blocks as far as
-  the batch's longest stream reaches, so ONE executable (per batch
-  bucket) serves every mix of context lengths with zero recompiles —
-  context length is a value, not a shape.
+  ``(B, max_blocks)`` and walks it as far as the streams reach
+  (ops/attention.paged_attention), so ONE executable (per batch bucket)
+  serves every mix of context lengths with zero recompiles — context
+  length is a value, not a shape.
 - **All-or-nothing admission** — :meth:`BlockPool.reserve` either hands a
   batch every block its streams need for the WHOLE generation (prompt +
   ``max_new_tokens``, so a stream can never run out mid-decode) or raises

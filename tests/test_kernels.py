@@ -712,6 +712,160 @@ class TestFlashPaddingMask:
 
 
 # ---------------------------------------------------------------------------
+# paged decode attention: the Pallas kernel against the XLA pass
+# ---------------------------------------------------------------------------
+
+PA_BS, PA_WIDTH, PA_ROWS = 16, 12, 6       # a table of 192 positions
+
+#: layout -> (K/V heads in a row, query rows a head and window token,
+#: head width, pool dtype, K is V): BERT's per-head K and V pools, a latent
+#: row that is key and value for 5 query heads, two grouped [k | v] heads
+#: of 64 for 3 query heads each
+PA_LAYOUTS = {"per-head": (2, 1, 64, jnp.float32, False),
+              "latent": (1, 5, 128, jnp.bfloat16, True),
+              "grouped": (2, 3, 128, jnp.bfloat16, True)}
+
+
+def _paged_kernel_case(layout, w, seed=0):
+    """One layer's paged attention in ``layout``'s shapes, as the callers
+    hand it to ``paged_attention``: row 0 at position 0 (its window's last
+    at ``w - 1``), row 1 at the table's last slot, rows 2 and 3 sharing
+    their first three physical blocks, row 4's table past its own blocks
+    on the trash block 0, row 5 anywhere; blocks in shuffled order."""
+    rng = np.random.default_rng(seed)
+    heads, per, dh, dtype, shared = PA_LAYOUTS[layout]
+    lanes = heads * dh
+    n = PA_ROWS * PA_WIDTH + 1
+    ids = rng.permutation(np.arange(1, n))
+    tables = ids.reshape(PA_ROWS, PA_WIDTH).astype(np.int32)
+    tables[3, :3] = tables[2, :3]
+    last = np.asarray([w - 1, PA_WIDTH * PA_BS - 1, 70, 100, 37,
+                       rng.integers(w, PA_WIDTH * PA_BS)])
+    tables[4, 37 // PA_BS + 1:] = 0
+    pos = (last[:, None] - (w - 1) + np.arange(w)[None, :]).astype(np.int32)
+    pool = lambda: jnp.asarray(rng.normal(size=(n * PA_BS, lanes)), dtype)
+    pk = pool()
+    pv = pk if shared else pool()
+    q = jnp.asarray(rng.normal(size=(PA_ROWS, heads, per * w, dh)),
+                    jnp.float32)
+    return (q, pk, pv, jnp.asarray(tables),
+            jnp.asarray(np.tile(pos, (1, per))), dh ** -0.5)
+
+
+def _paged_tolerance(pool, want):
+    """1e-6 where the rows are float32; bfloat16's rounding of the query
+    and the weights (the chip's one-pass products) where they are not."""
+    if pool.dtype == jnp.float32:
+        return 1e-6
+    return 2.0 ** -7 * float(jnp.max(jnp.abs(want)))
+
+
+class TestPagedAttentionKernel:
+    """``ops/attention._paged_attention_pallas`` in the Pallas interpreter
+    against the XLA pass it replaces on a TPU, in the three layouts its
+    callers hand it: per-head K and V pools (BERT), a latent row that is
+    key and value at once (GLM, Kimi), grouped ``[k | v]`` rows (Jamba)."""
+
+    @pytest.mark.parametrize("w", [1, 4])
+    @pytest.mark.parametrize("layout", list(PA_LAYOUTS))
+    def test_matches_the_xla_pass(self, layout, w):
+        q, pk, pv, tables, pos, scale = _paged_kernel_case(layout, w)
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        want = attn_ops._paged_attention_xla(q, pk, pv, tables, pos, PA_BS,
+                                             scale)
+        got = attn_ops._paged_attention_pallas(
+            q, pk, None if pv is pk else pv, tables, pos, PA_BS, scale,
+            interpret=True)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=_paged_tolerance(pk, want), rtol=0)
+
+    @pytest.mark.parametrize("layout", list(PA_LAYOUTS))
+    def test_each_layout_entry_reaches_the_kernel(self, monkeypatch,
+                                                  layout):
+        """Through the entry each caller uses, with the dispatch steered
+        to the kernel: the same numbers as the XLA pass, and a pool that is
+        K and V at once handed to the kernel once."""
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        heads, per, dh, _, _ = PA_LAYOUTS[layout]
+        q, pk, pv, tables, pos, scale = _paged_kernel_case(layout, 1, seed=1)
+        b = q.shape[0]
+        if layout == "per-head":
+            call = lambda: attn_ops.paged_attention(  # noqa: E731
+                q, pk, pv, tables, pos, PA_BS)
+        elif layout == "latent":
+            call = lambda: attn_ops.latent_paged_attention(  # noqa: E731
+                q.reshape(b, per, 1, dh), pk, tables, pos[:, :1], PA_BS,
+                dh // 2, scale)
+        else:
+            call = lambda: attn_ops.grouped_paged_attention(  # noqa: E731
+                q[..., :dh // 2].reshape(b, heads * per, 1, dh // 2), pk,
+                tables, pos[:, :1], PA_BS, heads)
+        want = call()
+        seen = []
+        kernel = attn_ops._paged_attention_pallas
+
+        def interpreted(*args, **kw):
+            seen.append(args[2] is None)
+            return kernel(*args, interpret=True, **kw)
+
+        monkeypatch.setattr(attn_ops, "paged_kernel_engages",
+                            lambda *a: True)
+        monkeypatch.setattr(attn_ops, "_paged_attention_pallas", interpreted)
+        got = call()
+        assert seen == [layout != "per-head"]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=_paged_tolerance(pk, want), rtol=0)
+
+    def test_each_stream_walks_only_its_own_blocks(self):
+        """NaN in every block past each row's own last one: the kernel's
+        answer is finite and unchanged, where the XLA pass, which walks
+        every row as far as the batch's longest stream, turns NaN x 0 into
+        NaN."""
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        q, pk, pv, tables, pos, scale = _paged_kernel_case("per-head", 1)
+        run = lambda k, v: np.asarray(  # noqa: E731
+            attn_ops._paged_attention_pallas(q, k, v, tables, pos, PA_BS,
+                                             scale, interpret=True))
+        clean = run(pk, pv)
+        own = np.asarray(pos).max(axis=1) // PA_BS
+        pk2, pv2 = np.asarray(pk).copy(), np.asarray(pv).copy()
+        for row, tab in enumerate(np.asarray(tables)):
+            for blk in tab[own[row] + 1:]:
+                if blk:
+                    pk2[blk * PA_BS:(blk + 1) * PA_BS] = np.nan
+                    pv2[blk * PA_BS:(blk + 1) * PA_BS] = np.nan
+        got = run(jnp.asarray(pk2), jnp.asarray(pv2))
+        np.testing.assert_array_equal(got, clean)
+        assert np.isnan(np.asarray(attn_ops._paged_attention_xla(
+            q, jnp.asarray(pk2), jnp.asarray(pv2), tables, pos, PA_BS,
+            scale))).any()
+
+
+    @pytest.mark.parametrize("layout", list(PA_LAYOUTS))
+    def test_a_stream_that_holds_no_block_reads_nothing(self, layout):
+        """A bucket's padding row, its table all on the trash block: the
+        kernel computes nothing for it and returns zeros there, where the
+        XLA pass attends what the trash block holds, and every other row's
+        answer is the XLA pass's."""
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        q, pk, pv, tables, pos, scale = _paged_kernel_case(layout, 1)
+        tables = tables.at[5].set(0)
+        want = attn_ops._paged_attention_xla(q, pk, pv, tables, pos, PA_BS,
+                                             scale)
+        got = np.asarray(attn_ops._paged_attention_pallas(
+            q, pk, None if pv is pk else pv, tables, pos, PA_BS, scale,
+            interpret=True))
+        assert (got[5] == 0).all() and (np.asarray(want)[5] != 0).any()
+        np.testing.assert_allclose(got[:5], np.asarray(want)[:5],
+                                   atol=_paged_tolerance(pk, want), rtol=0)
+
+
+# ---------------------------------------------------------------------------
 # per-dtype peak FLOPs + optimizer update share (satellites)
 # ---------------------------------------------------------------------------
 
@@ -776,6 +930,17 @@ class TestPeakFlopsTable:
 # ---------------------------------------------------------------------------
 # Pallas -> Mosaic cross-lowering (no chip needed)
 # ---------------------------------------------------------------------------
+
+
+#: Each serving cell's paged decode attention: layout, bucket rows, declared
+#: positions, window (BERT's speculative verify takes 4 tokens a stream).
+PA_CELLS = [("per-head", 8, 512, 1), ("per-head", 8, 256, 1),
+            ("per-head", 32, 256, 1), ("per-head", 8, 512, 4),
+            ("glm-latent", 32, 1152, 1), ("kimi-latent", 16, 1120, 1),
+            ("grouped", 64, 384, 1)]
+PA_CELL_IDS = ["bertL-8x512", "bertL-8x256", "bertL-32x256",
+               "bertL-verify-4", "glm47f-32x1152", "kimiL-16x1120",
+               "jamba2-64x384"]
 
 
 def _lower_for_tpu(fn, *avals):
@@ -1062,6 +1227,134 @@ class TestMosaicCrossLowering:
             _aval(4, dtype=jnp.int32), _aval(4, w, dtype=jnp.bool_))
         assert "tpu_custom_call" not in text
         assert "stablehlo.scatter" in text
+
+    @staticmethod
+    def _paged_cell(layout, rows, positions, window, sharding=None):
+        """One attention layer of a serving cell's decode bucket through
+        the entry its block calls: the call and its arguments' shapes."""
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
+        aval = lambda *s, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+            s, dtype, sharding=sharding)
+        width = positions // 16
+        slots = (rows * width + 1) * 16
+        tables = aval(rows, width, dtype=i32)
+        pos = aval(rows, window, dtype=i32)
+        if layout == "per-head":        # BERT-large: 16 heads of 64, float32
+            pool = aval(slots, 1024, dtype=f32)
+            fn = lambda q, k, v, t, p: attn_ops.paged_attention(  # noqa
+                q, k, v, t, p, 16)
+            return fn, (aval(rows, 16, window, 64, dtype=f32), pool, pool,
+                        tables, pos)
+        if layout == "grouped":         # Jamba: 20 query heads, one [k | v]
+            fn = lambda q, r, t, p: attn_ops.grouped_paged_attention(  # noqa
+                q, r, t, p, 16, 1)
+            return fn, (aval(rows, 20, window, 128, dtype=f32),
+                        aval(slots, 256, dtype=bf16), tables, pos)
+        heads = 20 if layout == "glm-latent" else 32  # over 640-wide rows
+        fn = lambda q, r, t, p: attn_ops.latent_paged_attention(  # noqa
+            q, r, t, p, 16, 512, 0.0884)
+        return fn, (aval(rows, heads, window, 640, dtype=f32),
+                    aval(slots, 640, dtype=bf16), tables, pos)
+
+    @pytest.mark.parametrize("layout,rows,positions,window", PA_CELLS,
+                             ids=PA_CELL_IDS)
+    def test_paged_attention_at_the_cells_shape(self, monkeypatch, layout,
+                                                rows, positions, window):
+        """On a TPU one Mosaic kernel and no gather of the pool; the CPU
+        program of the same call is the XLA pass and holds no kernel."""
+        from jax import export
+
+        fn, avals = self._paged_cell(layout, rows, positions, window)
+        text = _lower_for_tpu(fn, *avals)
+        assert text.count("tpu_custom_call") == 1
+        assert "stablehlo.gather" not in text
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: fn(*a)                   # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+        assert "stablehlo.while" in text
+
+    @pytest.fixture(scope="class")
+    def v5e(self):
+        """One chip of a described v5e, for a compile by the TPU's own
+        compiler. Described in a fixture, never at import: one process at a
+        time may load the TPU's library, and a test that cannot describe it
+        skips."""
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means "not here"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the cache and cannot
+        # be read back without one
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+
+    @pytest.mark.parametrize("layout,rows,positions,window", PA_CELLS,
+                             ids=PA_CELL_IDS)
+    def test_paged_attention_compiles_for_a_v5e(self, v5e, layout, rows,
+                                                positions, window):
+        """The same call compiled, not only lowered: Mosaic refuses a block
+        that is not aligned to the tiling, or a kernel that asks for more
+        fast memory than it may use, here. Nothing runs, so a compile that
+        passes says nothing about results or times."""
+        fn, avals = self._paged_cell(layout, rows, positions, window, v5e)
+        compiled = jax.jit(fn).lower(*avals).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+    @pytest.mark.parametrize("backend,w,lanes", [
+        ("tpu", 32, 1024), ("tpu", 1, 96), ("cpu", 1, 1024)],
+        ids=["tpu-window-32", "tpu-96-lanes", "cpu"])
+    def test_paged_attention_elsewhere_is_the_xla_form(self, monkeypatch,
+                                                       backend, w, lanes):
+        """A window of more query rows than one tile (a resumed prefill's
+        chunk), rows that are not whole 128-lane tiles, and any backend but
+        the TPU walk the table in the XLA pass."""
+        from deeplearning4j_tpu.ops import attention as attn_ops
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        f32 = jnp.float32
+        heads = lanes // 64 if lanes % 64 == 0 else 1
+        pool = _aval(33 * 16, lanes, dtype=f32)
+        text = _lower_for_tpu(
+            lambda q, k, v, t, p: attn_ops.paged_attention(q, k, v, t, p, 16),
+            _aval(4, heads, w, lanes // heads, dtype=f32), pool, pool,
+            _aval(4, 8, dtype=jnp.int32), _aval(4, w, dtype=jnp.int32))
+        assert "tpu_custom_call" not in text
+        assert "stablehlo.while" in text
+
+    @pytest.mark.parametrize("layers", [2, 4])
+    def test_a_decode_program_lowers_one_attention_kernel(self, layers):
+        """The set-up guard: a paged decoder's whole decode step, lowered
+        for a TPU, holds ONE attention kernel body however many layers call
+        it. A kernel that is traced and lowered once per layer (an unjitted
+        ``pallas_call``) costs every start a lowering per layer and
+        program, even when the compiled program comes from the cache."""
+        from deeplearning4j_tpu.serving import Generator
+        from deeplearning4j_tpu.zoo.bert import Bert
+
+        net = Bert.tiny(causal=True, task="mlm", vocab_size=43,
+                        max_length=64, hidden_dropout=0.0,
+                        n_layers=layers).init()
+        gen = Generator(net, paged=True, block_size=16, batch_buckets=(4,),
+                        prefill_buckets=(16,))
+        shapes = lambda t: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
+        i32 = lambda *shape: _aval(*shape, dtype=jnp.int32)  # noqa: E731
+        text = _lower_for_tpu(
+            gen._decode_paged, shapes(gen._raw_params()),
+            shapes(gen.pool.pools), i32(4, gen.pool.max_blocks_per_stream),
+            i32(4), i32(4), i32(4))
+        assert text.count('name = "paged_attention"') == 1
+        assert text.count("tpu_custom_call") == 1
 
     def test_resnet50_forward_reaches_no_kernel(self):
         """The flagship at its default conf on a TPU host: 53 convolutions,

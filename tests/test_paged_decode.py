@@ -295,6 +295,40 @@ class TestDecodeReadsWhatStreamsHold:
         share = gen_long.pool_stats()["decode_kv_read_share"]
         assert share == round((3 * 64 + 5 * 256) / (8 * 256), 4)
 
+    def test_counter_counts_the_path_that_runs(self, gen_long, monkeypatch):
+        """On the Pallas kernel's path a step reads what the kernel copies:
+        each live row's own blocks up to its position in whole chunks of 8
+        blocks (of 16 here), nothing for a padding row; on the XLA pass's,
+        batch x chunk x turns. Both are counted once a batch, over its
+        steps. The kernel runs on a TPU only, so the helper is asked with
+        each path's flag, and a batch is counted with the kernel's flag
+        set; the flag is the dispatch's own predicate."""
+        last = np.asarray([0, 15, 16, 100, 200] + [1] * (L_BATCH - 5))
+        read = gen_long._kv_positions_read
+        # own blocks 1, 1, 2, 7, 13: one chunk each, the 200 row two
+        assert read(last, 5, 1, kernel=True) == (8 * 4 + 16) * L_BS
+        # over 30 steps the 100 row reaches a second chunk at 128 and 129
+        assert read(last, 5, 30, kernel=True) == (
+            8 * 3 * 30 + (8 * 28 + 16 * 2) + 16 * 30) * L_BS
+        assert read(last, 5, 1, kernel=False) \
+            == L_BATCH * 64 * 4                      # four turns of 64
+        # four turns while the largest position is below 256, at 200..229
+        assert read(last, 5, 30, kernel=False) == L_BATCH * 64 * 4 * 30
+        assert read(last, 5, 0, kernel=True) == 0
+        assert not gen_long._kv_kernel(1)            # not a TPU
+        r0, d0 = _kv_counters()
+        monkeypatch.setattr(gen_long, "_kv_kernel", lambda w: True)
+        gen_long.generate([[1, 2], [3] * 200], max_new_tokens=4)
+        r1, d1 = _kv_counters()
+        assert d1 - d0 == 3 * L_BATCH * L_MAXLEN      # 3 decode steps
+        # positions 2-4 one chunk, 200-202 in block 12: two
+        assert r1 - r0 == 3 * (8 + 16) * L_BS
+        monkeypatch.undo()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert gen_long._kv_kernel(1)     # 2 heads over 128 float32 lanes
+        assert gen_long._kv_kernel(128)
+        assert not gen_long._kv_kernel(129)   # 258 query rows: one tile less
+
     def test_long_context_tokens_match_contiguous(self, gen_long):
         """Four turns deep: the chunked pass still gives the contiguous
         cache's tokens, ragged rows beside a long one."""
