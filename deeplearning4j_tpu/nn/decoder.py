@@ -1,7 +1,9 @@
-"""Pre-norm decoder layers for served language models: a token embedding
-without position tables, the residual block ``x += mixer(RMSNorm(x)); x +=
-ffn(RMSNorm(x))`` with a choice of mixers and feed-forwards, and a normed,
-logits head (its own matrix, or the embedding's: ``tied``).
+"""Decoder layers for served language models: a token embedding without
+position tables, the residual block with a choice of mixers and
+feed-forwards, and a normed logits head (its own matrix, or the
+embedding's: ``tied``). The block is pre-norm, ``x += mixer(RMSNorm(x)); x
++= ffn(RMSNorm(x))``, or, with ``norm="post"``, OLMo 2's reordered norm
+(arXiv:2501.00656): ``x += RMSNorm(mixer(x)); x += RMSNorm(ffn(x))``.
 
 Mixers (``mixer=``):
 
@@ -9,6 +11,15 @@ Mixers (``mixer=``):
   attention. Its cache is a STATE, one slot a stream: the (heads, dk, dv)
   float32 matrix and the last ``conv_size - 1`` inputs of the short
   convolutions. Whatever the context length, the state has one size.
+- ``"gdn"`` — the Gated DeltaNet (arXiv:2412.06464) as flash-linear-
+  attention's ``GatedDeltaNet`` lays it out: the same delta rule and cache
+  as ``"kda"`` through the same code, with keys of ``head_dim`` and values
+  of ``value_dim``, a decay of one value a head (``-exp(A_log)
+  softplus(x W_a + dt_bias)``), a full-rank output gate ``silu(x W_g)``,
+  and ``beta = 2 sigmoid(x W_b)`` in (0, 2): negative eigenvalues
+  (arXiv:2411.12537). What differs is a :class:`DeltaRule`. A slot holds
+  its states in whole lane tiles, two 192-wide heads side by side
+  (ops/kda.py ``state_pack``).
 - ``"mla"`` — multi-head latent attention: the cache is one row ``[c_t |
   kr_t]`` a token for all heads (``kv_lora_rank + qk_rope_dim`` numbers, in
   the parameters' type), behind the same page tables as a per-head K/V
@@ -41,7 +52,9 @@ Mixers (``mixer=``):
   token, ``n_kv_heads x [k | v]`` in the parameters' type (256 numbers at
   one head of 128: whole lane tiles, the rule above). Prefill attends in the
   expanded causal form, decode through the same block-chunked pass as the
-  latent rows (ops/attention.grouped_paged_attention).
+  latent rows (ops/attention.grouped_paged_attention). ``qk_norm``
+  RMS-norms the whole query and key projections before the heads are split
+  (OLMo 2), the key before it is cached.
 
 Feed-forwards (``ffn=``): ``"dense"`` gated SiLU, or ``"moe"``: a sigmoid
 router over ``n_experts`` with a selection bias, the weights of the chosen
@@ -56,8 +69,8 @@ cache. :class:`NextTokenModule` is a model's own next-token-prediction
 (MTP) head: one more block fed the main stack's last hidden state, which
 serving/generate.py takes as a self-draft. Numbers: parameters in
 their own type (bfloat16 when served), matrix products accumulate in
-float32, the residual stream, norms, softmax, router and KDA state are
-float32.
+float32, the residual stream, norms, softmax, router and delta-rule state
+are float32.
 """
 
 from __future__ import annotations
@@ -210,25 +223,48 @@ class StateWalk(NamedTuple):
 
 
 _STATE_WALKS = {"kda": StateWalk("kda", "chunks", kda.CHUNK, False),
+                "gdn": StateWalk("gdn", "chunks", kda.CHUNK, False),
                 "mamba": StateWalk("ssm", "positions", 1, True)}
+
+
+class DeltaRule(NamedTuple):
+    """What a delta-rule mixer makes of its input beside q, k and v."""
+
+    #: one decay a head (``W_a``, (hidden, heads)), or one a channel
+    #: through the low-rank ``W_f1 W_f2``
+    decay_per_head: bool
+    #: the output gate: full-rank ``W_g``, or low-rank ``W_g1 W_g2``
+    full_rank_gate: bool
+    #: the gate's activation
+    gate: object
+    #: the top of ``beta``'s range: 2 lets an eigenvalue of the
+    #: transition go negative
+    beta_top: float
+
+
+_DELTA_RULES = {"kda": DeltaRule(False, False, jax.nn.sigmoid, 1.0),
+                "gdn": DeltaRule(True, True, jax.nn.silu, 2.0)}
 
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class HybridDecoderBlock(Layer):
-    """One pre-norm residual block (module doc)."""
+    """One residual block (module doc)."""
 
     hidden_size: int = 0
-    mixer: str = "kda"            # "kda" | "mla" | "mamba" | "gqa"
+    mixer: str = "kda"            # "kda" | "gdn" | "mla" | "mamba" | "gqa"
     ffn: str = "dense"            # "dense" | "moe"
+    norm: str = "pre"             # "pre" | "post" (OLMo 2's reordered norm)
     n_heads: int = 1
     eps: float = 1e-5
     init_range: float = 0.02
     param_dtype: str = "float32"
-    # kda; gqa's heads have the size too, mamba's convolution the length
-    head_dim: int = 128           # dk = dv
+    # a delta rule's keys; gqa's heads have the size too, mamba's
+    # convolution the length
+    head_dim: int = 128
+    value_dim: int = 0            # a delta rule's values (0: head_dim)
     conv_size: int = 4
-    gate_rank: int = 128          # the low-rank width of the two gates
+    gate_rank: int = 128          # the low-rank width of kda's two gates
     # mla
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
@@ -244,6 +280,7 @@ class HybridDecoderBlock(Layer):
     conv_bias: bool = True
     # gqa
     n_kv_heads: int = 1
+    qk_norm: bool = False         # RMSNorm of the whole q and k projections
     # ffn
     ffn_size: int = 0             # dense width, or one expert's
     n_experts: int = 0            # the router's width
@@ -277,6 +314,17 @@ class HybridDecoderBlock(Layer):
         return self.n_heads * self.head_dim
 
     @property
+    def _dv(self) -> int:
+        """A delta-rule head's value width."""
+        return self.value_dim or self.head_dim
+
+    @property
+    def _pack(self) -> int:
+        """Delta-rule heads a state slot lays side by side on its lanes
+        (ops/kda.py ``state_pack``)."""
+        return kda.state_pack(self.n_heads, self._dv)
+
+    @property
     def _row(self) -> int:
         """The latent width: what ``Wdkv`` makes and every byte count reads."""
         return self.kv_lora_rank + self.qk_rope_dim
@@ -291,7 +339,8 @@ class HybridDecoderBlock(Layer):
         """A ``"state"`` mixer's whole-prompt pass: (params, x, mask) ->
         (mixer output, final state, convolution tail as its slot holds
         it)."""
-        return self._kda_prefill if self.mixer == "kda" else self._ssm_prefill
+        return self._ssm_prefill if self.mixer == "mamba" \
+            else self._kda_prefill
 
     @property
     def _channels(self) -> int:
@@ -313,21 +362,31 @@ class HybridDecoderBlock(Layer):
         mat = lambda *shape: _normal(next(ks), shape, r, dt)
         one = lambda n: _normal(next(ks), (n,), r, dt, mean=1.0)
         p = {"norm1": one(hs), "norm2": one(hs)}
-        if self.mixer == "kda":
-            inner, h = self._inner, self.n_heads
-            for n in "qkv":
-                p["W" + n] = mat(hs, inner)
-                p["conv_" + n] = _normal(next(ks), (self.conv_size, inner),
+        if self.mixer in _DELTA_RULES:
+            rule, h = _DELTA_RULES[self.mixer], self.n_heads
+            inner, values = self._inner, h * self._dv
+            for n, width in (("q", inner), ("k", inner), ("v", values)):
+                p["W" + n] = mat(hs, width)
+                p["conv_" + n] = _normal(next(ks), (self.conv_size, width),
                                          self.conv_size ** -0.5, dt)
-            p.update(Wf1=mat(hs, self.gate_rank),
-                     Wf2=mat(self.gate_rank, inner),
-                     Wb=mat(hs, h), Wg1=mat(hs, self.gate_rank),
-                     Wg2=mat(self.gate_rank, inner),
-                     o_norm=one(self.head_dim), Wo=mat(inner, hs))
-            # decay rates 1..16 a head, step sizes 0.001..0.1 a channel
+            if rule.decay_per_head:
+                p["Wa"] = mat(hs, h)
+            else:
+                p.update(Wf1=mat(hs, self.gate_rank),
+                         Wf2=mat(self.gate_rank, inner))
+            p["Wb"] = mat(hs, h)
+            if rule.full_rank_gate:
+                p["Wg"] = mat(hs, values)
+            else:
+                p.update(Wg1=mat(hs, self.gate_rank),
+                         Wg2=mat(self.gate_rank, values))
+            p.update(o_norm=one(self._dv), Wo=mat(values, hs))
+            # decay rates 1..16 a head, step sizes 0.001..0.1 a channel (a
+            # head, where the decay is one a head)
             p["A_log"] = jnp.log(jax.random.uniform(
                 next(ks), (h,), F32, 1.0, 16.0)).astype(dt)
-            p["dt_bias"] = _step_bias(next(ks), inner, dt)
+            p["dt_bias"] = _step_bias(
+                next(ks), h if rule.decay_per_head else inner, dt)
         elif self.mixer == "mla":
             h = self.n_heads
             q_out = h * (self.qk_nope_dim + self.qk_rope_dim)
@@ -359,6 +418,8 @@ class HybridDecoderBlock(Layer):
             inner, kv = self._inner, self.n_kv_heads * self.head_dim
             p.update(Wq=mat(hs, inner), Wk=mat(hs, kv), Wv=mat(hs, kv),
                      Wo=mat(inner, hs))
+            if self.qk_norm:
+                p.update(q_norm=one(inner), k_norm=one(kv))
         else:
             raise ValueError(f"unknown mixer {self.mixer!r}")
         f = self.ffn_size
@@ -379,14 +440,30 @@ class HybridDecoderBlock(Layer):
         return p, {}
 
     # -------------------------------------------------------- feed-forwards
+    def _normed_in(self, params, x, name: str):
+        """What a sublayer reads of the residual stream: ``x`` RMS-normed
+        by ``name`` (pre-norm), or ``x`` itself (post-norm)."""
+        if self.norm == "post":
+            return x.astype(F32)
+        return rms_norm(x, params[name], self.eps)
+
+    def _normed_out(self, params, y, name: str):
+        """What a sublayer adds to the residual stream: ``y`` (pre-norm),
+        or ``y`` RMS-normed by ``name`` (post-norm)."""
+        if self.norm == "post":
+            return rms_norm(y, params[name], self.eps)
+        return y
+
     def _ffn(self, params, x, live=None):
-        """x (B, T, H) float32 -> (ffn(RMSNorm(x)), stats or None); ``live``
-        (B, T) bool marks the tokens a router may count and send."""
-        h = rms_norm(x, params["norm2"], self.eps)
+        """x (B, T, H) float32 -> (what the feed-forward adds, stats or
+        None); ``live`` (B, T) bool marks the tokens a router may count and
+        send."""
+        h = self._normed_in(params, x, "norm2")
         gated = lambda g, u, d: _mm(jax.nn.silu(_mm(h, params[g]))
                                     * _mm(h, params[u]), params[d])
         if self.ffn == "dense":
-            return gated("Wgate", "Wup", "Wdown"), None
+            return self._normed_out(params, gated("Wgate", "Wup", "Wdown"),
+                                    "norm2"), None
         h2 = h.reshape(-1, self.hidden_size)
         with jax.named_scope("moe.route"):
             idx, w = moe.route_sigmoid_topk(
@@ -401,61 +478,77 @@ class HybridDecoderBlock(Layer):
         y = y.reshape(x.shape)
         if self.shared_size:
             y = y + gated("Sgate", "Sup", "Sdown")
-        return y, stats
+        return self._normed_out(params, y, "norm2"), stats
 
     def _finish(self, params, x, a, pool, live=None, phase: int = 0):
         """Both residual adds; a router's counts, and 1 for the call, go
         onto row ``phase`` (0 prefill, 1 decode) of the pool's ``moe``
         accumulator (wrapping int32: the host reads differences)."""
-        x = x + a
+        x = x + self._normed_out(params, a, "norm1")
         y, stats = self._ffn(params, x, live)
         if stats is not None and "moe" in pool:
             row = jnp.concatenate([stats, jnp.ones((1,), jnp.int32)])
             pool = dict(pool, moe=pool["moe"].at[phase].add(row))
         return x + y, pool
 
-    # ------------------------------------------------------------ KDA mixer
+    # ---------------------------------------------- delta-rule mixers (KDA)
     def _kda_inputs(self, params, h, conv):
-        """Normed input (B, T, H) and the short convolution to run, ``conv(raw,
-        w)``: ``kda.causal_conv`` over whole prompts, the decode step's in
-        the streams' slots -> q, k, v, g (B, T, heads, d), beta (B, T,
-        heads), the output gate, and the projections before the convolution
-        (the next tail)."""
+        """The mixer's input (B, T, H) and the short convolution to run,
+        ``conv(raw, w)``: ``kda.causal_conv`` over whole prompts, the decode
+        step's in the streams' slots -> q, k (B, T, heads, dk), v (B, T,
+        heads, dv), g (B, T, heads, dk or 1), beta (B, T, heads), the output
+        gate, and the projections before the convolution (the next tail).
+        What the mixer's :class:`DeltaRule` says decides the decay, the gate
+        and beta's range."""
+        rule = _DELTA_RULES[self.mixer]
         b, t, _ = h.shape
-        nh, d = self.n_heads, self.head_dim
-        raw = jnp.concatenate([_mm(h, params["W" + n]) for n in "qkv"], -1)
-        w = jnp.concatenate([params["conv_" + n] for n in "qkv"],
-                            -1).astype(F32)
-        q, k, v = jnp.split(jax.nn.silu(conv(raw, w)), 3, -1)
-        heads = lambda a: a.reshape(b, t, nh, d)
-        unit = lambda a: a * jax.lax.rsqrt(
-            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
-        q, k, v = unit(heads(q)) * d ** -0.5, unit(heads(k)), heads(v)
-        f = _mm(_mm(h, params["Wf1"]), params["Wf2"]) \
-            + params["dt_bias"].astype(F32)
-        g = -jnp.exp(params["A_log"].astype(F32))[:, None] \
-            * heads(jax.nn.softplus(f))
-        beta = jax.nn.sigmoid(_mm(h, params["Wb"]))
-        gate = heads(jax.nn.sigmoid(_mm(_mm(h, params["Wg1"]),
-                                        params["Wg2"])))
-        return q, k, v, g, beta, gate, raw
+        nh, dk, dv = self.n_heads, self.head_dim, self._dv
+        with jax.named_scope(self.mixer + ".inputs"):
+            raw = jnp.concatenate([_mm(h, params["W" + n]) for n in "qkv"],
+                                  -1)
+            w = jnp.concatenate([params["conv_" + n] for n in "qkv"],
+                                -1).astype(F32)
+            q, k, v = jnp.split(jax.nn.silu(conv(raw, w)),
+                                [nh * dk, 2 * nh * dk], -1)
+            heads = lambda a, d: a.reshape(b, t, nh, d)
+            unit = lambda a: a * jax.lax.rsqrt(
+                jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+            q, k = unit(heads(q, dk)) * dk ** -0.5, unit(heads(k, dk))
+            v = heads(v, dv)
+            rates = -jnp.exp(params["A_log"].astype(F32))
+            if rule.decay_per_head:
+                f = _mm(h, params["Wa"]) + params["dt_bias"].astype(F32)
+                g = (rates * jax.nn.softplus(f))[..., None]
+                gate = _mm(h, params["Wg"])
+            else:
+                f = _mm(_mm(h, params["Wf1"]), params["Wf2"]) \
+                    + params["dt_bias"].astype(F32)
+                g = rates[:, None] * heads(jax.nn.softplus(f), dk)
+                gate = _mm(_mm(h, params["Wg1"]), params["Wg2"])
+            beta = jax.nn.sigmoid(_mm(h, params["Wb"]))
+            if rule.beta_top != 1.0:
+                beta = rule.beta_top * beta
+            return q, k, v, g, beta, heads(rule.gate(gate), dv), raw
 
     def _kda_out(self, params, o, gate):
-        o = rms_norm(o, params["o_norm"], self.eps) * gate
-        return _mm(o.reshape(*o.shape[:2], self._inner), params["Wo"])
+        with jax.named_scope(self.mixer + ".out"):
+            o = rms_norm(o, params["o_norm"], self.eps) * gate
+            return _mm(o.reshape(*o.shape[:2], self.n_heads * self._dv),
+                       params["Wo"])
 
     def _kda_prefill(self, params, x, mask):
-        """Whole prompts from an empty state -> (mixer output, final state,
-        the convolutions' tail at each row's length)."""
+        """Whole prompts from an empty state -> (mixer output, final state
+        as a slot holds it, the convolutions' tail at each row's length)."""
         b, t, _ = x.shape
-        h = rms_norm(x, params["norm1"], self.eps)
+        h = self._normed_in(params, x, "norm1")
         q, k, v, g, beta, gate, raw = self._kda_inputs(params, h,
                                                        kda.causal_conv)
         lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
-        s0 = jnp.zeros((b, self.n_heads, self.head_dim, self.head_dim), F32)
+        s0 = jnp.zeros((b, self.n_heads, self.head_dim, self._dv), F32)
         # padding moves no state, and chunks behind a length are not visited
-        o, s = kda.kda_chunked(q, k, v, g, beta, s0, lengths)
-        return (self._kda_out(params, o, gate), s,
+        with jax.named_scope(self.mixer + ".chunked"):
+            o, s = kda.kda_chunked(q, k, v, g, beta, s0, lengths)
+        return (self._kda_out(params, o, gate), kda.pack_states(s, self._pack),
                 kda.conv_tail(raw, lengths, self.conv_size - 1))
 
     # ------------------------------------------------------------ MLA mixer
@@ -591,7 +684,7 @@ class HybridDecoderBlock(Layer):
         """Whole prompts from an empty state -> (mixer output, final state,
         the convolution's tail at each row's length, flat as a slot holds
         it)."""
-        h = rms_norm(x, params["norm1"], self.eps)
+        h = self._normed_in(params, x, "norm1")
         xc, dt, bm, cm, z, raw = self._ssm_inputs(params, h, kda.causal_conv)
         lengths = jnp.sum(mask.astype(jnp.int32), axis=1)
         s0 = jnp.zeros((x.shape[0], self.d_state, self._channels), F32)
@@ -605,13 +698,19 @@ class HybridDecoderBlock(Layer):
 
     # -------------------------------------------------- grouped-query mixer
     def _gqa_qkv(self, params, h):
-        """Normed input -> q (B, T, heads, d), k, v (B, T, kv heads, d) and
-        the cache rows ``n_kv_heads x [k | v]`` (B, T, row)."""
+        """The mixer's input -> q (B, T, heads, d), k, v (B, T, kv heads,
+        d) and the cache rows ``n_kv_heads x [k | v]`` (B, T, row); the
+        whole q and k projections RMS-normed first where ``qk_norm``
+        says."""
         b, t, _ = h.shape
-        heads = lambda name, n: _mm(h, params[name]).reshape(
-            b, t, n, self.head_dim)
-        q = heads("Wq", self.n_heads)
-        k, v = heads("Wk", self.n_kv_heads), heads("Wv", self.n_kv_heads)
+        heads = lambda a, n: a.reshape(b, t, n, self.head_dim)
+        q, k = _mm(h, params["Wq"]), _mm(h, params["Wk"])
+        if self.qk_norm:
+            with jax.named_scope("gqa.qk_norm"):
+                q = rms_norm(q, params["q_norm"], self.eps)
+                k = rms_norm(k, params["k_norm"], self.eps)
+        q, k = heads(q, self.n_heads), heads(k, self.n_kv_heads)
+        v = heads(_mm(h, params["Wv"]), self.n_kv_heads)
         rows = jnp.concatenate([k, v], -1).reshape(b, t, self._pool_row)
         return q, k, v, rows
 
@@ -655,16 +754,16 @@ class HybridDecoderBlock(Layer):
         else:
             attend = (self._gqa_expanded if self.mixer == "gqa"
                       else self._mla_expanded)
-            a, _ = attend(params, rms_norm(x, params["norm1"], self.eps),
-                          mask)
+            a, _ = attend(params, self._normed_in(params, x, "norm1"), mask)
         out, _ = self._finish(params, x.astype(F32), a, {},
                               mask.astype(bool))
         return out, state
 
     # ---------------------------------------------------------- paged cache
     def init_pool(self, num_slots: int):
-        """``cache_kind`` ``"state"``: ``num_slots`` stream slots of the KDA
-        or state-space state (float32) and the convolutions' tail;
+        """``cache_kind`` ``"state"``: ``num_slots`` stream slots of the
+        delta-rule or state-space state (float32; a delta rule's heads
+        side by side in whole lane tiles) and the convolutions' tail;
         ``"tokens"``: ``num_slots`` rows in the parameters' type, a
         grouped-query layer's ``n_kv_heads x [k | v]`` or a latent layer's
         of the
@@ -675,11 +774,14 @@ class HybridDecoderBlock(Layer):
         layout and back (the rule of nn/transformer.py's ``init_pool``,
         PERF.md PR 28; here PR 36). A routed feed-forward adds its
         counters."""
-        if self.mixer == "kda":
-            pool = {"state": jnp.zeros((num_slots, self.n_heads,
-                                        self.head_dim, self.head_dim), F32),
+        if self.mixer in _DELTA_RULES:
+            # a slot's heads ``pack`` to a row of whole lane tiles
+            pk = self._pack
+            pool = {"state": jnp.zeros((num_slots, self.n_heads // pk,
+                                        self.head_dim, pk * self._dv), F32),
                     "conv": jnp.zeros((num_slots, self.conv_size - 1,
-                                       3 * self._inner), F32)}
+                                       2 * self._inner
+                                       + self.n_heads * self._dv), F32)}
         elif self.mixer == "mamba":
             # the tail flat, a slot a row of whole lane tiles: with the 3
             # inputs as an axis of their own the device re-lays the whole
@@ -706,7 +808,7 @@ class HybridDecoderBlock(Layer):
         else:
             attend = (self._gqa_expanded if self.mixer == "gqa"
                       else self._mla_expanded)
-            a, rows = attend(params, rms_norm(x, params["norm1"], self.eps),
+            a, rows = attend(params, self._normed_in(params, x, "norm1"),
                              mask)
             pool = dict(pool, rows=pool["rows"].at[where.reshape(-1)].set(
                 rows.reshape(-1, self._pool_row).astype(pool["rows"].dtype)))
@@ -721,7 +823,7 @@ class HybridDecoderBlock(Layer):
         go onto row ``phase`` (:meth:`_finish`)."""
         live = jnp.ones(positions.shape, bool) if limits is None \
             else positions <= limits[:, None]
-        h = rms_norm(x_w, params["norm1"], self.eps)
+        h = self._normed_in(params, x_w, "norm1")
         stepped = {}    # a "state" block's two pools, each stepped in place
 
         def conv(raw, w):
@@ -730,11 +832,12 @@ class HybridDecoderBlock(Layer):
                                                      where, live)
             return y
 
-        if self.mixer == "kda":
+        if self.mixer in _DELTA_RULES:
             q, k, v, g, beta, gate, _ = self._kda_inputs(params, h, conv)
             # and its state, in the same slot of the state pool
-            o, stepped["state"] = kda.kda_step_paged(
-                q, k, v, g, beta, pool["state"], where, live)
+            with jax.named_scope(self.mixer + ".step"):
+                o, stepped["state"] = kda.kda_step_paged(
+                    q, k, v, g, beta, pool["state"], where, live)
             pool = dict(pool, **stepped)
             a = self._kda_out(params, o, gate)
         elif self.mixer == "mamba":

@@ -1,13 +1,24 @@
-"""Kimi Delta Attention (KDA): gated delta-rule linear attention with a
+"""Gated delta-rule linear attention: Kimi Delta Attention (KDA), with a
 per-channel decay (Kimi Linear report, arXiv:2510.26692, section 3;
-flash-linear-attention ``KimiDeltaAttention``).
+flash-linear-attention ``KimiDeltaAttention``), and the Gated DeltaNet,
+with one decay a head (Yang et al., arXiv:2412.06464; flash-linear-attention
+``GatedDeltaNet``).
 
 Per head, with a state ``S`` of (dk, dv) float32 numbers, a key ``k_t`` and
-query ``q_t`` of dk numbers, a value ``v_t`` of dv, a per-channel log decay
-``g_t <= 0`` (dk) and a write strength ``beta_t`` in (0, 1):
+query ``q_t`` of dk numbers, a value ``v_t`` of dv, a log decay ``g_t <= 0``
+(dk numbers, or one a head: the last axis 1, every channel alike) and a
+write strength ``beta_t`` in (0, 1), or in (0, 2) where negative eigenvalues
+are allowed (Grazzi et al., arXiv:2411.12537):
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
+
+The kernels take a head where the chip can tile it (:func:`on_kernels`):
+``dk`` whole sublane tiles, ``dv`` at least one lane tile, the short
+convolution's channels whole lane tiles. A state pool holds a stream's
+states in whole lane tiles with nothing padded: ``pack`` heads side by side
+on the lanes (:func:`state_pack`: 1 at 128-wide heads, 2 at 192-wide
+values), a slot ``(heads / pack, dk, pack x dv)``.
 
 Three forms of the same recurrence live here:
 
@@ -20,16 +31,15 @@ Three forms of the same recurrence live here:
   products and the state is touched once a chunk. Every ``exp`` is of a
   number <= 0: pairs in different 16-token sub-blocks split the decay about
   the row block's first token, pairs inside one sub-block take it as it is.
-  On a TPU, with heads in whole 128-lane tiles, it is one Pallas kernel
-  (``kda_prefill``): a chunk's intermediates and the running state stay on
-  the chip, and a row's walk ends with the chunk its length ends in; the XLA
-  form runs everywhere else and computes every chunk (docs/KERNELS.md).
+  On a TPU, at heads it can tile, it is one Pallas kernel (``kda_prefill``):
+  a chunk's intermediates and the running state stay on the chip, and a
+  row's walk ends with the chunk its length ends in; the XLA form runs
+  everywhere else and computes every chunk (docs/KERNELS.md).
 - :func:`kda_step` — the decode step, one token against the state.
   :func:`kda_step_paged` runs it on the streams' slots of a state pool: on
-  a TPU, with heads in whole 128-lane tiles and one token a row, one Pallas
-  kernel (``kda_decode``) whose state block is the row's slot, read and
-  written in place; everywhere else the rows are gathered, stepped and
-  scattered.
+  a TPU, at heads it can tile and one token a row, one Pallas kernel
+  (``kda_decode``) whose state block is the row's slot, read and written in
+  place; everywhere else the rows are gathered, stepped and scattered.
 
 A token with ``beta = 0`` and ``g = 0`` leaves the state as it was: that is
 how padded positions and finished rows are kept from moving a live state.
@@ -57,9 +67,49 @@ CHUNK = 64
 SUB = 16
 
 
+def state_pack(heads: int, dv: int) -> int:
+    """Heads a state slot lays side by side on the lanes: the fewest whose
+    values fill whole 128-lane tiles (1 at dv = 128, 2 at 192), where the
+    heads divide into such groups; 1 elsewhere."""
+    pack = 128 // math.gcd(dv, 128)
+    return pack if heads % pack == 0 else 1
+
+
+def pack_states(s, pack: int):
+    """(..., H, dk, dv) -> the slot's layout (..., H / pack, dk, pack x dv):
+    head ``pack j + m`` in lanes ``[m dv, (m + 1) dv)`` of row group j."""
+    if pack == 1:
+        return s
+    *lead, h, dk, dv = s.shape
+    s = s.reshape(*lead, h // pack, pack, dk, dv)
+    return jnp.swapaxes(s, -3, -2).reshape(*lead, h // pack, dk, pack * dv)
+
+
+def unpack_states(s, pack: int):
+    """The slot's layout back to (..., H, dk, dv)."""
+    if pack == 1:
+        return s
+    *lead, hp, dk, width = s.shape
+    s = s.reshape(*lead, hp, dk, pack, width // pack)
+    return jnp.swapaxes(s, -3, -2).reshape(*lead, hp * pack, dk,
+                                           width // pack)
+
+
+def on_kernels(h: int, dk: int, dv: int) -> bool:
+    """Whether the kernels take heads of this shape on a TPU: ``dk`` whole
+    sublane tiles, ``dv`` at least one lane tile with the slot's packed
+    rows whole lane tiles, and the short convolution's channels (q, k and
+    v: ``2 h dk + h dv``) whole lane tiles, so that the tail pool in front
+    of the state takes its kernel under the same condition: a decode step
+    visits a live row's slot in both pools or in neither."""
+    return (jax.default_backend() == "tpu" and dk % 8 == 0 and dv >= 128
+            and state_pack(h, dv) * dv % 128 == 0
+            and (2 * h * dk + h * dv) % 128 == 0)
+
+
 def kda_step(q, k, v, g, beta, state):
-    """One token: q, k, g (B, H, dk); v (B, H, dv); beta (B, H); state
-    (B, H, dk, dv) float32 -> (o (B, H, dv), state)."""
+    """One token: q, k (B, H, dk); g (B, H, dk) or (B, H, 1); v (B, H, dv);
+    beta (B, H); state (B, H, dk, dv) float32 -> (o (B, H, dv), state)."""
     f32 = jnp.float32
     q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
     s = state * jnp.exp(g)[..., None]
@@ -82,11 +132,12 @@ def kda_recurrent(q, k, v, g, beta, state):
 
 def _pair_products(x, k, gc):
     """``P[t, i] = sum_d x_t[d] k_i[d] exp(G_t[d] - G_i[d])`` for ``i <= t``
-    within a chunk (0 above the diagonal): x, k, gc (..., C, d), gc the
-    inclusive running sum of the log decay. No exponent is positive."""
-    *lead, c, d = x.shape
+    within a chunk (0 above the diagonal): x, k (..., C, d), gc (..., C, d)
+    or (..., C, 1) the inclusive running sum of the log decay. No exponent
+    is positive."""
+    *lead, c, _ = x.shape
     ns = c // SUB
-    blk = lambda a: a.reshape(*lead, ns, SUB, d)
+    blk = lambda a: a.reshape(*lead, ns, SUB, a.shape[-1])
     xb, kb, gb = blk(x), blk(k), blk(gc)
     # the reference point of row block I: the running sum just before it
     ref = jnp.concatenate([jnp.zeros_like(gb[..., :1, 0, :]),
@@ -214,13 +265,16 @@ def _kda_chunked_xla(q, k, v, g, beta, state, lengths=None,
 HEAD_GROUP = 8      # heads of one grid step (docs/KERNELS.md: measured)
 
 
-def _kda_chunk(q, k, v, g, beta, st, exact: bool):
+def _kda_chunk(q, k, v, g, beta, st, exact: bool, transposed: bool = True):
     """One chunk of one head, on values held on the chip: q, k, g (C, dk),
-    v (C, dv), beta (C, 1), ``st`` the state transposed (dv, dk), all
-    float32 -> (o (C, dv), the state after the chunk). The arithmetic of
-    :func:`_kda_chunked_xla`, product for product; ``exact`` keeps float32
-    operands where a TPU takes one bfloat16 pass (the interpreter on a
-    CPU, as XLA's default precision does there)."""
+    v (C, dv), beta (C, 1), ``st`` the state, all float32 -> (o (C, dv),
+    the state after the chunk). The arithmetic of :func:`_kda_chunked_xla`,
+    product for product; ``exact`` keeps float32 operands where a TPU takes
+    one bfloat16 pass (the interpreter on a CPU, as XLA's default precision
+    does there). ``transposed``: the state is held (dv, dk), and the two
+    right-hand sides of the solve are one array; at heads that are not
+    whole lane tiles it is held (dk, dv) and each side is solved alone, so
+    that nothing is transposed or joined off the tiles."""
     f32 = jnp.float32
     c, dk = k.shape
     ns = c // SUB
@@ -292,23 +346,43 @@ def _kda_chunk(q, k, v, g, beta, st, exact: bool):
             inv = inv - below * of_block(inv, j)
     aqk = jnp.where(col <= row, aqk_off + dqk, 0.0)
     decay = jnp.exp(gc)                                         # <= 1
-    rhs = jnp.concatenate([beta * k * decay, beta * v], axis=1)
-    y, m = mm_hi(inv, rhs), mm_hi(inv, beta * akk_off)
-    sol = y
-    for _ in range(ns - 1):
-        sol = y - mm_hi(m, sol)
-    w, uv = sol[:, :dk], sol[:, dk:]
-    u = uv - mm(w, st, ((1,), (1,)))
-    o = mm(q * decay, st, ((1,), (1,))) + mm(aqk, u)
+    m = mm_hi(inv, beta * akk_off)
+
+    def solve(rhs):         # block forward substitution, as the XLA form's
+        y = sol = mm_hi(inv, rhs)
+        for _ in range(ns - 1):
+            sol = y - mm_hi(m, sol)
+        return sol
+
     last = gc[c - 1:c]
     kdec = k * jnp.exp(last - gc)                               # <= 1
-    return o, st * jnp.exp(last) + mm(u, kdec, ((0,), (0,)))
+    if transposed:
+        sol = solve(jnp.concatenate([beta * k * decay, beta * v], axis=1))
+        w, uv = sol[:, :dk], sol[:, dk:]
+        u = uv - mm(w, st, ((1,), (1,)))
+        o = mm(q * decay, st, ((1,), (1,))) + mm(aqk, u)
+        return o, st * jnp.exp(last) + mm(u, kdec, ((0,), (0,)))
+    w, uv = solve(beta * k * decay), solve(beta * v)
+    u = uv - mm(w, st)
+    o = mm(q * decay, st) + mm(aqk, u)
+    # the chunk's decay of each key channel as a column: a row's lanes
+    # moved onto the sublanes by a select, not a transpose
+    ii = lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    down = jnp.sum(jnp.where(ii == lax.broadcasted_iota(jnp.int32, (dk, dk),
+                                                        1),
+                             jnp.exp(last), 0.0), axis=1, keepdims=True)
+    return o, st * down + mm(kdec, u, ((0,), (0,)))
 
 
 def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
-                o_ref, s_ref, *, chunk, heads, dk, dv, exact):
+                o_ref, s_ref, *, chunk, heads, dk, dv, exact, major):
     """Grid (row, head group, chunk), the chunk innermost and sequential:
-    the state's output block stays on the chip across it, transposed."""
+    the state's output block stays on the chip across it. At heads of
+    whole lane tiles the tokens are (1, chunk, heads x d) blocks, the decay
+    a channel, and the state is held transposed; ``major``: the tokens are
+    (1, heads, chunk, d) blocks, a head's own rows, the decay one a head
+    beside ``beta`` (or a channel, heads first as q), and the state is held
+    as it is."""
     from jax.experimental import pallas as pl
 
     b, hg, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -317,7 +391,7 @@ def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     @pl.when(c == 0)
     def _():
         for j in range(heads):
-            s_ref[0, j] = s0_ref[0, j].T
+            s_ref[0, j] = s0_ref[0, j] if major else s0_ref[0, j].T
 
     @pl.when(c * chunk >= n)
     def _():
@@ -328,21 +402,34 @@ def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
         at = c * chunk + lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
         live = at < n
         lane = lax.broadcasted_iota(jnp.int32, beta_ref.shape[1:], 1)
+        pick = lambda ref, j: jnp.sum(
+            jnp.where(lane == hg * heads + j, ref[0], 0.0), axis=1,
+            keepdims=True)
         for j in range(heads):
-            lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv, (j + 1) * dv)
-            beta = jnp.sum(jnp.where(lane == hg * heads + j, beta_ref[0], 0.0),
-                           axis=1, keepdims=True)
-            o, st = _kda_chunk(
-                q_ref[0, :, lk], k_ref[0, :, lk], v_ref[0, :, lv],
-                jnp.where(live, g_ref[0, :, lk], 0.0),
-                jnp.where(live, beta, 0.0), s_ref[0, j], exact)
-            o_ref[0, :, lv] = o
+            beta = pick(beta_ref, j)
+            if major:
+                qj, kj, vj = q_ref[0, j], k_ref[0, j], v_ref[0, j]
+                gj = g_ref[0, j] if len(g_ref.shape) == 4 else \
+                    jnp.broadcast_to(pick(g_ref, j), (chunk, dk))
+            else:
+                lk, lv = slice(j * dk, (j + 1) * dk), slice(j * dv,
+                                                            (j + 1) * dv)
+                qj, kj, vj = q_ref[0, :, lk], k_ref[0, :, lk], v_ref[0, :, lv]
+                gj = g_ref[0, :, lk]
+            o, st = _kda_chunk(qj, kj, vj, jnp.where(live, gj, 0.0),
+                               jnp.where(live, beta, 0.0), s_ref[0, j], exact,
+                               transposed=not major)
+            if major:
+                o_ref[0, j] = o
+            else:
+                o_ref[0, :, lv] = o
             s_ref[0, j] = st
 
-    @pl.when(c == pl.num_programs(2) - 1)
-    def _():
-        for j in range(heads):
-            s_ref[0, j] = s_ref[0, j].T
+    if not major:
+        @pl.when(c == pl.num_programs(2) - 1)
+        def _():
+            for j in range(heads):
+                s_ref[0, j] = s_ref[0, j].T
 
 
 @functools.partial(jax.jit,
@@ -351,17 +438,26 @@ def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
 def _kda_chunked_pallas(q, k, v, g, beta, state, lengths=None,
                         chunk: int = CHUNK, interpret: bool = False,
                         head_group: int = HEAD_GROUP, exact=None):
-    """:func:`kda_chunked` as one kernel (module doc): needs ``dk`` and
-    ``dv`` in whole 128-lane tiles. Jitted, so that a program of twenty such
-    layers traces and lowers the kernel once and calls it twenty times.
-    ``exact`` (float32 operands in every product) is the interpreter's way
-    unless said: a test runs the chip's bfloat16 passes on a CPU with it."""
+    """:func:`kda_chunked` as one kernel (module doc), at heads
+    :func:`on_kernels` takes. Heads of whole 128-lane tiles read the tokens
+    as they lie, ``heads x d`` wide (the heads-first form takes 16% longer
+    there: docs/KERNELS.md); any other head width is read a head at a
+    time, the tokens laid out heads first, because a block of ``heads x
+    96`` lanes is not whole tiles, and its decay is one a head (a decay a
+    channel is read as a channel's). Jitted, so that a program of twenty
+    such layers traces and lowers the kernel once and calls it twenty
+    times. ``exact`` (float32 operands in every product) is the
+    interpreter's way unless said: a test runs the chip's bfloat16 passes
+    on a CPU with it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     b, t, h, dk = q.shape
     dv = v.shape[-1]
+    major = bool(dk % 128 or dv % 128)
+    if not major or g.shape[-1] != 1:
+        g = jnp.broadcast_to(g, q.shape)
     if lengths is None:
         lengths = jnp.full((b,), t, jnp.int32)
     pad = -t % chunk
@@ -369,41 +465,52 @@ def _kda_chunked_pallas(q, k, v, g, beta, state, lengths=None,
                              ((0, 0), (0, pad), (0, 0)))
     n = (t + pad) // chunk
     hg = math.gcd(h, head_group)
+    # a chunk behind the row's last is not fetched: the block index stays
+    held = lambda i, c, n_ref: jnp.minimum(c, jnp.maximum(
+        live_chunks(n_ref[i], chunk) - 1, 0))
 
     def tokens(width, heads=True):
-        # a chunk behind the row's last is not fetched: the block index stays
-        return pl.BlockSpec(
-            (1, chunk, width),
-            lambda i, j, c, n_ref: (
-                i, jnp.minimum(c, jnp.maximum(
-                    live_chunks(n_ref[i], chunk) - 1, 0)), j if heads else 0))
+        return pl.BlockSpec((1, chunk, width), lambda i, j, c, n_ref: (
+            i, held(i, c, n_ref), j if heads else 0))
 
+    if major:
+        first = lambda a: jnp.pad(jnp.moveaxis(a.astype(f32), 2, 1),
+                                  ((0, 0), (0, 0), (0, pad), (0, 0)))
+        by_head = lambda width: pl.BlockSpec(
+            (1, hg, chunk, width),
+            lambda i, j, c, n_ref: (i, j, held(i, c, n_ref), 0))
+        per_head = g.shape[-1] == 1
+        ins = [first(q), first(k), first(v), flat(g) if per_head else first(g)]
+        specs = [by_head(dk), by_head(dk), by_head(dv),
+                 tokens(h, heads=False) if per_head else by_head(dk)]
+        o_spec = pl.BlockSpec((1, hg, chunk, dv),
+                              lambda i, j, c, n_ref: (i, j, c, 0))
+        o_shape = (b, h, t + pad, dv)
+    else:
+        ins = [flat(q), flat(k), flat(v), flat(g)]
+        specs = [tokens(hg * dk), tokens(hg * dk), tokens(hg * dv),
+                 tokens(hg * dk)]
+        o_spec = pl.BlockSpec((1, chunk, hg * dv),
+                              lambda i, j, c, n_ref: (i, c, j))
+        o_shape = (b, t + pad, h * dv)
     states = pl.BlockSpec((1, hg, dk, dv), lambda i, j, c, n_ref: (i, j, 0, 0))
     o, s = pl.pallas_call(
         functools.partial(_kda_kernel, chunk=chunk, heads=hg, dk=dk, dv=dv,
-                          exact=interpret if exact is None else exact),
+                          exact=interpret if exact is None else exact,
+                          major=major),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, h // hg, n),
-            in_specs=[tokens(hg * dk), tokens(hg * dk), tokens(hg * dv),
-                      tokens(hg * dk), tokens(h, heads=False), states],
-            out_specs=[pl.BlockSpec((1, chunk, hg * dv),
-                                    lambda i, j, c, n_ref: (i, c, j)),
-                       states]),
-        out_shape=[jax.ShapeDtypeStruct((b, t + pad, h * dv), f32),
+            in_specs=specs + [tokens(h, heads=False), states],
+            out_specs=[o_spec, states]),
+        out_shape=[jax.ShapeDtypeStruct(o_shape, f32),
                    jax.ShapeDtypeStruct((b, h, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="kda_prefill",
-    )(lengths.astype(jnp.int32), flat(q), flat(k), flat(v), flat(g),
-      flat(beta), state.astype(f32))
+    )(lengths.astype(jnp.int32), *ins, flat(beta), state.astype(f32))
+    if major:
+        return jnp.moveaxis(o[:, :, :t], 1, 2), s
     return o[:, :t].reshape(b, t, h, dv), s
-
-
-def _kernel_shapes(*arrays) -> bool:
-    """Where the Pallas kernels run: a TPU, every array's last dim (a head,
-    the convolution's channels) in whole 128-lane tiles."""
-    return (jax.default_backend() == "tpu"
-            and all(a.shape[-1] % 128 == 0 for a in arrays))
 
 
 def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
@@ -411,10 +518,10 @@ def kda_chunked(q, k, v, g, beta, state, lengths=None, chunk: int = CHUNK):
     :func:`kda_recurrent`, plus ``lengths`` (B,): a token at or behind its
     row's length leaves the state alone, and ``o`` is 0 in every chunk that
     starts there (a row of length 0 returns the state it was given). ``T``
-    is padded to whole chunks. On a TPU, with heads in whole 128-lane
-    tiles, one kernel walks the chunks the lengths cover
+    is padded to whole chunks. On a TPU, at heads :func:`on_kernels` takes,
+    one kernel walks the chunks the lengths cover
     (:func:`_kda_chunked_pallas`); everywhere else the XLA form runs."""
-    if _kernel_shapes(q, v):
+    if on_kernels(q.shape[2], q.shape[3], v.shape[3]):
         return _kda_chunked_pallas(q, k, v, g, beta, state, lengths, chunk)
     return _kda_chunked_xla(q, k, v, g, beta, state, lengths, chunk)
 
@@ -424,12 +531,14 @@ DECODE_HEAD_GROUP = 16  # heads of one grid step (docs/KERNELS.md: measured)
 
 
 def _kda_decode_kernel(slot_ref, x_ref, v_ref, s_in, o_ref, s_out, t_ref, *,
-                       heads):
+                       heads, pack):
     """Grid (row, head group). ``x_ref`` holds the group's q | k | exp(g) |
-    beta k, a head a row (4 x heads, dk); transposed once a grid step they
-    are the columns that meet the state's rows. The state block is the
-    row's slot of the pool, read and written in place; a dead row (slot 0)
-    keeps the trash slot's first block as it is and computes nothing."""
+    beta k, a head a row (4 x heads, dk on a whole lane tile); transposed
+    once a grid step they are the columns that meet the state's rows. The
+    state block is the row's slot of the pool, ``pack`` heads side by side
+    on its lanes (a column is the head's whose lanes it meets), read and
+    written in place; a dead row (slot 0) keeps the trash slot's first
+    block as it is and computes nothing."""
     from jax.experimental import pallas as pl
 
     live = slot_ref[pl.program_id(0)] != 0
@@ -441,10 +550,19 @@ def _kda_decode_kernel(slot_ref, x_ref, v_ref, s_in, o_ref, s_out, t_ref, *,
 
     @pl.when(live)
     def _():
+        _, _, dk, width = s_in.shape
         t_ref[0:4 * heads, :] = x_ref[0, 0]
-        cols = t_ref[...].T                       # (dk, 128): a head a lane
-        for j in range(heads):
-            col = lambda n: cols[:, n * heads + j:n * heads + j + 1]
+        cols = t_ref[...].T[:dk]                  # (dk, 128): a head a lane
+        lane = lax.broadcasted_iota(jnp.int32, (dk, width), 1)
+        for j in range(heads // pack):
+            def col(n):
+                at = n * heads + j * pack
+                c = cols[:, at:at + 1]
+                for m in range(1, pack):          # the lanes of head m
+                    c = jnp.where(lane >= m * (width // pack),
+                                  cols[:, at + m:at + m + 1], c)
+                return c
+
             s = s_in[0, j] * col(2)
             d = v_ref[0, 0, j:j + 1, :] \
                 - jnp.sum(s * col(1), axis=0, keepdims=True)
@@ -457,68 +575,80 @@ def _kda_decode_kernel(slot_ref, x_ref, v_ref, s_in, o_ref, s_out, t_ref, *,
 @functools.partial(jax.jit, static_argnames=("interpret", "head_group"))
 def _kda_decode_pallas(q, k, v, g, beta, pool, slots, interpret: bool = False,
                        head_group: int = DECODE_HEAD_GROUP):
-    """:func:`kda_step` on the slots of a pool, in place: q, k, g (B, H,
-    dk), v (B, H, dv), beta (B, H), ``pool`` (slots, H, dk, dv) float32,
-    ``slots`` (B,) with every dead row on the trash slot 0 -> (o (B, H,
-    dv), pool). The state's block index is ``slots[row]`` for input and
-    output alike and the pool is aliased input to output, so a live
-    stream's state moves once each way and no other slot is touched; dead
-    rows share one block of the trash slot, which is fetched once a run of
-    them. Float32 on the vector unit throughout. Jitted, so that a program
-    of twenty such layers lowers the kernel once."""
+    """:func:`kda_step` on the slots of a pool, in place: q, k (B, H, dk),
+    g (B, H, dk) or (B, H, 1), v (B, H, dv), beta (B, H), ``pool`` (slots,
+    H / pack, dk, pack x dv) float32 (:func:`pack_states`), ``slots`` (B,)
+    with every dead row on the trash slot 0 -> (o (B, H, dv), pool). The
+    state's block index is ``slots[row]`` for input and output alike and
+    the pool is aliased input to output, so a live stream's state moves
+    once each way and no other slot is touched; dead rows share one block
+    of the trash slot, which is fetched once a run of them. Float32 on the
+    vector unit throughout. Jitted, so that a program of twenty such
+    layers lowers the kernel once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
     b, h, dk = q.shape
     dv = v.shape[-1]
-    hg = math.gcd(h, min(head_group, 32))     # 4 x hg rows of a 128-row tile
+    pack = pool.shape[-1] // dv
+    lanes = -(-dk // 128) * 128
+    # whole packs, 4 x hg rows of a 128-row tile
+    hg = max(n for n in range(pack, min(head_group, 32) + 1, pack)
+             if h % n == 0)
     ng = h // hg
     grp = lambda a: a.astype(f32).reshape(b, ng, hg, -1)
-    x = jnp.concatenate([grp(q), grp(k), jnp.exp(grp(g)),
-                         grp(beta.astype(f32)[..., None] * k)], axis=2)
+    x = jnp.concatenate([grp(q), grp(k), jnp.exp(grp(jnp.broadcast_to(
+        g, q.shape))), grp(beta.astype(f32)[..., None] * k)], axis=2)
+    if lanes != dk:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, lanes - dk)))
+    vals = lambda a: a.astype(f32).reshape(b, ng, hg // pack, pack * dv)
     # a dead row's blocks are those of the dead step before it: not fetched
     group = lambda i, j, s: jnp.where(s[i] == 0, 0, j)
     rows = lambda i, j, s: (jnp.where(s[i] == 0, 0, i), group(i, j, s), 0, 0)
-    states = pl.BlockSpec((1, hg, dk, dv),
+    states = pl.BlockSpec((1, hg // pack, dk, pack * dv),
                           lambda i, j, s: (s[i], group(i, j, s), 0, 0))
     o, pool = pl.pallas_call(
-        functools.partial(_kda_decode_kernel, heads=hg),
+        functools.partial(_kda_decode_kernel, heads=hg, pack=pack),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, ng),
-            in_specs=[pl.BlockSpec((1, 1, 4 * hg, dk), rows),
-                      pl.BlockSpec((1, 1, hg, dv), rows), states],
-            out_specs=[pl.BlockSpec((1, 1, hg, dv),
+            in_specs=[pl.BlockSpec((1, 1, 4 * hg, lanes), rows),
+                      pl.BlockSpec((1, 1, hg // pack, pack * dv), rows),
+                      states],
+            out_specs=[pl.BlockSpec((1, 1, hg // pack, pack * dv),
                                     lambda i, j, s: (i, j, 0, 0)), states],
-            scratch_shapes=[pltpu.VMEM((128, dk), f32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, ng, hg, dv), f32),
+            scratch_shapes=[pltpu.VMEM((128, lanes), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, ng, hg // pack, pack * dv), f32),
                    jax.ShapeDtypeStruct(pool.shape, f32)],
         input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret, name="kda_decode",
-    )(slots.astype(jnp.int32), x, grp(v), pool)
+    )(slots.astype(jnp.int32), x, vals(v), pool)
     return o.reshape(b, h, dv), pool
 
 
 def kda_step_paged(q, k, v, g, beta, pool, slots, live):
-    """The decode window on the streams' slots of a state pool: q, k, g
-    (B, W, H, dk), v (B, W, H, dv), beta (B, W, H), ``pool`` (slots, H, dk,
-    dv) float32, ``slots`` (B,), ``live`` (B, W) bool -> (o (B, W, H, dv),
-    pool). A token that is not live moves no state. On a TPU, with heads in
-    whole 128-lane tiles and a window of one token, each live row's state
-    is read from its slot and written back there by one kernel
+    """The decode window on the streams' slots of a state pool: q, k (B, W,
+    H, dk), g (B, W, H, dk) or (B, W, H, 1), v (B, W, H, dv), beta (B, W,
+    H), ``pool`` (slots, H / pack, dk, pack x dv) float32
+    (:func:`pack_states`), ``slots`` (B,), ``live`` (B, W) bool -> (o (B, W,
+    H, dv), pool). A token that is not live moves no state. On a TPU, at
+    heads :func:`on_kernels` takes and a window of one token, each live
+    row's state is read from its slot and written back there by one kernel
     (:func:`_kda_decode_pallas`) and a dead row names the trash slot;
     everywhere else the rows are gathered, stepped and scattered."""
-    if q.shape[1] == 1 and _kernel_shapes(q, v):
+    if q.shape[1] == 1 and on_kernels(q.shape[2], q.shape[3], v.shape[3]):
         o, pool = _kda_decode_pallas(
             q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], pool,
             jnp.where(live[:, 0], slots, 0))
         return o[:, None], pool
+    pack = pool.shape[-1] // v.shape[-1]
     m = live.astype(jnp.float32)
     o, s = kda_recurrent(q, k, v, g * m[..., None, None],
-                         beta * m[..., None], pool[slots])
-    return o, pool.at[slots].set(s)
+                         beta * m[..., None],
+                         unpack_states(pool[slots], pack))
+    return o, pool.at[slots].set(pack_states(s, pack))
 
 
 def causal_conv(x, w, tail=None):
@@ -677,12 +807,14 @@ def conv_step_paged(raw, w, pool, slots, live):
     ``causal_conv(raw, w, tail)`` before any bias or activation, and a
     stream's new tail its last K-1 inputs with the live tokens of the
     window behind them. On a TPU, with a window of one token and the
-    channels in whole 128-lane tiles (the state kernels' predicate), each
-    live row's tail is read from its slot and written back there by one
-    kernel (:func:`_conv_step_pallas`) and a dead row names the trash slot;
+    channels in whole 128-lane tiles (which each state kernel's predicate
+    implies: :func:`on_kernels`, ops/ssm.py's), each live row's tail is
+    read from its slot and written back there by one kernel
+    (:func:`_conv_step_pallas`) and a dead row names the trash slot;
     everywhere else the rows are gathered, convolved and scattered
     (:func:`_conv_step_xla`)."""
-    if raw.shape[1] == 1 and _kernel_shapes(raw):
+    if (raw.shape[1] == 1 and jax.default_backend() == "tpu"
+            and raw.shape[2] % 128 == 0):
         y, pool = _conv_step_pallas(raw[:, 0], w, pool,
                                     jnp.where(live[:, 0], slots, 0))
         return y[:, None], pool

@@ -937,10 +937,10 @@ class TestPeakFlopsTable:
 PA_CELLS = [("per-head", 8, 512, 1), ("per-head", 8, 256, 1),
             ("per-head", 32, 256, 1), ("per-head", 8, 512, 4),
             ("glm-latent", 32, 1152, 1), ("kimi-latent", 16, 1120, 1),
-            ("grouped", 64, 384, 1)]
+            ("grouped", 64, 384, 1), ("grouped-30", 48, 512, 1)]
 PA_CELL_IDS = ["bertL-8x512", "bertL-8x256", "bertL-32x256",
                "bertL-verify-4", "glm47f-32x1152", "kimiL-16x1120",
-               "jamba2-64x384"]
+               "jamba2-64x384", "olmoh-48x512"]
 
 
 def _lower_for_tpu(fn, *avals):
@@ -1090,6 +1090,66 @@ class TestMosaicCrossLowering:
         text = export.export(jax.jit(again), platforms=["cpu"])(
             *avals).mlir_module()
         assert "custom_call" not in text
+
+    @staticmethod
+    def _gdn_cell(phase, sharding=None):
+        """One Gated DeltaNet layer of ``olmoh-chat-open``: the prefill
+        bucket (48 rows x 256 positions) or the decode bucket (48 rows of
+        one token against 49 slots), 30 heads of 96 keys and 192 values,
+        the decay one a head; the state pool two heads a 384-lane row."""
+        from deeplearning4j_tpu.ops import kda
+
+        aval = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa
+            s, dtype, sharding=sharding)
+        t = 256 if phase == "prefill" else 1
+        x = lambda d: aval(48, t, 30, d)                         # noqa
+        head = (x(96), x(96), x(192), x(1), aval(48, t, 30))
+        if phase == "prefill":
+            return kda.kda_chunked, head + (
+                aval(48, 30, 96, 192), aval(48, dtype=jnp.int32))
+        return kda.kda_step_paged, head + (
+            aval(49, 15, 96, 384), aval(48, dtype=jnp.int32),
+            aval(48, 1, dtype=jnp.bool_))
+
+    @pytest.mark.parametrize("phase", ["prefill", "decode"])
+    def test_gdn_kernels_at_the_cells_shape(self, monkeypatch, phase):
+        """On a TPU each phase is one Mosaic kernel at heads that are not
+        whole lane tiles; the decode step's pool result aliases its pool
+        operand and no gather or scatter of the pool is left beside it. The
+        CPU program of the same call holds no kernel."""
+        from jax import export
+
+        fn, avals = self._gdn_cell(phase)
+        text = _lower_for_tpu(fn, *avals)
+        assert text.count("tpu_custom_call") == 1
+        if phase == "decode":
+            assert "output_operand_aliases" in text
+            assert "stablehlo.gather" not in text
+            assert "stablehlo.scatter" not in text
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        again = lambda *a: fn(*a)                   # not the cached trace
+        text = export.export(jax.jit(again), platforms=["cpu"])(
+            *avals).mlir_module()
+        assert "custom_call" not in text
+
+    @pytest.mark.parametrize("phase", ["prefill", "decode"])
+    def test_gdn_kernels_compile_for_a_v5e(self, v5e, phase):
+        """Compiled, not only lowered: Mosaic takes (64, 96) and (96, 384)
+        blocks. In the compiled decode step nothing but the kernel touches
+        the state pool: no copy, gather or scatter of a whole pool."""
+        fn, avals = self._gdn_cell(phase, v5e)
+        donate = (5,) if phase == "decode" else ()
+        text = jax.jit(fn, donate_argnums=donate).lower(
+            *avals).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        if phase == "decode":
+            pool = "f32[49,15,96,384]"
+            ops = [line.strip() for line in text.splitlines()
+                   if line.strip().startswith(("%", "ROOT %"))]
+            touched = [op for op in ops if pool in op and not any(
+                w in op for w in ("parameter(", "custom-call(",
+                                  "get-tuple-element(", "tuple("))]
+            assert not touched, touched
 
     @pytest.mark.parametrize("backend,w,dk,dv", [
         ("tpu", 1, 16, 8), ("tpu", 1, 128, 64), ("tpu", 2, 128, 128),
@@ -1252,6 +1312,11 @@ class TestMosaicCrossLowering:
                 q, r, t, p, 16, 1)
             return fn, (aval(rows, 20, window, 128, dtype=f32),
                         aval(slots, 256, dtype=bf16), tables, pos)
+        if layout == "grouped-30":      # Olmo Hybrid: 30 heads, 30 [k | v]
+            fn = lambda q, r, t, p: attn_ops.grouped_paged_attention(  # noqa
+                q, r, t, p, 16, 30)
+            return fn, (aval(rows, 30, window, 128, dtype=f32),
+                        aval(slots, 30 * 256, dtype=bf16), tables, pos)
         heads = 20 if layout == "glm-latent" else 32  # over 640-wide rows
         fn = lambda q, r, t, p: attn_ops.latent_paged_attention(  # noqa
             q, r, t, p, 16, 512, 0.0884)
